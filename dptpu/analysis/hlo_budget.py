@@ -64,6 +64,14 @@ REPRESENTATIVE_CONFIGS = ("ddp", "zero1", "accum", "slices",
 # gates need at least two independent per-bucket reductions)
 _OVERLAP_BUCKET_BYTES = 2048
 
+# The budgets gate the collective structure THIS REPO emits, so the one
+# XLA:CPU pass that rewrites it is held off for these compiles: the
+# CPU backend's all-reduce combiner folds every per-leaf and per-bucket
+# all-reduce (and the metric psums) into a single instruction, which
+# hides both the instruction counts and the overlap evidence. Bytes
+# are unchanged either way; the TPU combiner has its own thresholds.
+COMPILER_OPTIONS = {"xla_disable_hlo_passes": "cpu-all-reduce-combiner"}
+
 # |parsed − analytic| / analytic tolerance: the formulas count gradient
 # + pmean payload; the compiled program adds a handful of scalar-sized
 # control collectives (same 2% bound tests/test_hierarchy.py locks)
@@ -153,6 +161,12 @@ def _leaf_counts(state) -> dict:
     }
 
 
+def _compiled_text(step, st, batch) -> str:
+    return step.lower(st, batch).compile(
+        compiler_options=COMPILER_OPTIONS
+    ).as_text()
+
+
 def _compile_config(name: str) -> Tuple[str, dict]:
     """Compiled HLO text + model facts for one representative config."""
     import jax
@@ -235,7 +249,7 @@ def _compile_config(name: str) -> Tuple[str, dict]:
             )
         st = shard_gspmd_state(st, mesh, specs)
         batch = shard_host_batch(_batch(), mesh)
-        return step.lower(st, batch).compile().as_text(), facts
+        return _compiled_text(step, st, batch), facts
     else:
         raise ValueError(
             f"unknown budget config {name!r} "
@@ -250,7 +264,7 @@ def _compile_config(name: str) -> Tuple[str, dict]:
             lambda x: jax.device_put(x, replicated_sharding(mesh)), st
         )
     batch = shard_host_batch(_batch(), mesh)
-    return step.lower(st, batch).compile().as_text(), facts
+    return _compiled_text(step, st, batch), facts
 
 
 def _serve_quant_hlo() -> str:

@@ -19,7 +19,7 @@ by convention and by whichever test happened to exercise it:
   ``create_named_segment`` with a prefix the conftest leak-guard census
   knows, so an abandoned segment is attributable and policed.
 * ``shard-map`` — step bodies go through ``shard_map_nocheck``
-  (collectives placed EXPLICITLY under ``check_rep=False``) and thread
+  (collectives placed EXPLICITLY under ``check_vma=False``) and thread
   ``axis_names`` through ``train_step_body`` so the hierarchical mesh
   cannot be silently dropped.
 
@@ -367,7 +367,7 @@ def shm_hygiene(ctx: FileContext) -> Iterator[Tuple[int, str]]:
 @register(
     "shard-map", _dptpu_only,
     "step bodies go through shard_map_nocheck (explicit collectives "
-    "under check_rep=False) and thread axis_names through "
+    "under check_vma=False) and thread axis_names through "
     "train_step_body",
 )
 def shard_map_discipline(ctx: FileContext) -> Iterator[Tuple[int, str]]:
@@ -379,10 +379,10 @@ def shard_map_discipline(ctx: FileContext) -> Iterator[Tuple[int, str]]:
             if "shard_map_nocheck" not in ctx.enclosing_functions(node):
                 yield node.lineno, (
                     "raw shard_map call — go through "
-                    "dptpu.train.step.shard_map_nocheck: this "
-                    "container's rep-checker cannot infer the steps' "
-                    "replicated outputs, so collectives are placed "
-                    "explicitly under check_rep=False"
+                    "dptpu.train.step.shard_map_nocheck: the steps "
+                    "place their collectives explicitly, and with the "
+                    "checker on its implicit psum would reduce the "
+                    "gradient a second time — check_vma=False"
                 )
         elif q == "train_step_body":
             if not any(kw.arg == "axis_names" for kw in node.keywords):

@@ -235,6 +235,11 @@ def main_serve(argv=None):
     specs = parse_model_specs(args.arch)
 
     from dptpu.serve import ModelRouter, build_served_model
+    from dptpu.utils.compile_cache import enable_compile_cache
+    from dptpu.utils.provenance import device_banner
+
+    enable_compile_cache()
+    print(device_banner())
 
     router = ModelRouter([
         build_served_model(
@@ -430,7 +435,9 @@ def main_quantize(argv=None):
         quantize_variables,
         save_calibration,
     )
+    from dptpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     # one fp32 engine, replicated (quantized serving is replicated-only)
     bucket = max(2, min(16, args.sample))
     engine = ServeEngine(

@@ -192,9 +192,7 @@ class Encoder(nn.Module):
 
             if self.seq_axis_name is None:
                 raise ValueError("seq_shard_tokens needs seq_axis_name")
-            from dptpu.ops.sequence_parallel import axis_size
-
-            n = axis_size(self.seq_axis_name)
+            n = lax.axis_size(self.seq_axis_name)
             s_tot = x.shape[1]
             chunk = -(-s_tot // n)  # ceil: pad S+1 up to a multiple of n
             x = jnp.pad(x, ((0, 0), (0, chunk * n - s_tot), (0, 0)))

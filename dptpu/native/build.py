@@ -1,45 +1,68 @@
 """Build + load the native image-ops shared library.
 
-Compiled lazily with g++ (no pybind11 — plain C ABI via ctypes), cached
-under ``_build/`` keyed by source mtime. Thread-safe; failure is cached so a
-missing toolchain costs one attempt per process and the pipeline silently
-stays on PIL.
+Compiled lazily with g++ (no pybind11 — plain C ABI via ctypes) into
+``_build/libdptpu_image-<key>.so``, where ``<key>`` hashes the source
+file and the compiler command — a binary built from other source or
+other flags has another name and can never load. Thread-safe; a failed
+build is cached (one attempt per process) and LOUD: one stderr line
+names the compiler error, then the pipeline stays on PIL and fit's
+``=> input pipeline:`` banner says ``native=False``.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import Optional
 
 _SRC = os.path.join(os.path.dirname(__file__), "src", "image_ops.cpp")
 _BUILD_DIR = os.path.join(os.path.dirname(__file__), "_build")
-_LIB = os.path.join(_BUILD_DIR, "libdptpu_image.so")
+_CXX = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17"]
+_LIBS = ["-ljpeg"]
 
 _lock = threading.Lock()
 _cached: Optional[ctypes.CDLL] = None
 _attempted = False
 
 
-def _compile() -> bool:
+def library_path() -> str:
+    """``_build/libdptpu_image-<sha256(source + compiler command)>.so``."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_CXX + _LIBS).encode())
+    return os.path.join(
+        _BUILD_DIR, f"libdptpu_image-{h.hexdigest()[:16]}.so"
+    )
+
+
+def _compile() -> Optional[str]:
+    """Path of the built library, or None after saying why on stderr."""
+    lib = library_path()
+    if os.path.exists(lib):
+        return lib
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    if os.path.exists(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
-        return True
-    # pid-unique temp: loader worker PROCESSES may race to rebuild after a
-    # source change; each compiles to its own file and the replace is atomic
-    tmp = f"{_LIB}.{os.getpid()}.tmp"
-    cmd = [
-        "g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-        "-o", tmp, _SRC, "-ljpeg",
-    ]
+    # pid-unique temp: loader worker PROCESSES may race to build; each
+    # compiles to its own file and the replace is atomic
+    tmp = f"{lib}.{os.getpid()}.tmp"
+    cmd = _CXX + ["-o", tmp, _SRC] + _LIBS
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    except (OSError, subprocess.SubprocessError):
-        return False
-    os.replace(tmp, _LIB)
-    return True
+    except (OSError, subprocess.SubprocessError) as e:
+        stderr = (getattr(e, "stderr", None) or b"").decode(errors="replace")
+        first = next((ln for ln in stderr.splitlines() if ln.strip()), "")
+        print(
+            f"dptpu.native: build failed, JPEG decode stays on PIL — "
+            f"{first or repr(e)}",
+            file=sys.stderr,
+        )
+        return None
+    os.replace(tmp, lib)
+    return lib
 
 
 def load_library() -> Optional[ctypes.CDLL]:
@@ -49,9 +72,10 @@ def load_library() -> Optional[ctypes.CDLL]:
         if _cached is not None or _attempted:
             return _cached
         _attempted = True
-        if not _compile():
+        path = _compile()
+        if path is None:
             return None
-        lib = ctypes.CDLL(_LIB)
+        lib = ctypes.CDLL(path)
         lib.dptpu_jpeg_dims.restype = ctypes.c_int
         lib.dptpu_jpeg_dims.argtypes = [
             ctypes.c_char_p, ctypes.c_size_t,

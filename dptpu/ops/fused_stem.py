@@ -37,18 +37,21 @@ re-reads the input plane beyond the one scan pass.
 Two implementations with identical semantics (parity-tested against
 ``nn.max_pool``'s select_and_scatter in tests/test_fused_stem.py):
 
-* ``_*_xla``: pure lax ops — runs anywhere, used on CPU and as the
-  reference.
+* ``_*_xla``: pure lax ops — runs anywhere, used off the TPU and as
+  the reference.
 * ``_*_pallas``: TPU Pallas kernels gridded over the batch, one VMEM-
   resident image per program — XLA's fusion emitter handles the 9 strided
   window views poorly (measured +4.7 ms), Mosaic does not.
 
-The op itself picks Pallas vs XLA automatically (Pallas on TPU for even
-square spatial dims, XLA elsewhere). Whether the resnet stem uses this op
-at all is **opt-in**: ``DPTPU_FUSED_STEM=1`` (handled in
-``dptpu.train.fit``) or ``create_model(..., fused_stem=True)`` — measured
-slower than XLA's native stem lowering on v5e Mosaic (PERF.md), so the
-default stem remains the unfused one.
+The op picks by backend: on the TPU it runs the Pallas kernels or raises
+(no quiet XLA path under the fused name), elsewhere it runs the lax
+reference. ``chip_smoke.py`` compiles both kernels at the ResNet-50 stem
+shape on the chip and checks them against the reference. Whether the
+resnet stem uses this op at all is **opt-in**: ``DPTPU_FUSED_STEM=1``
+(handled in ``dptpu.train.fit``) or ``create_model(..., fused_stem=True)``
+— it was measured slower than XLA's own stem lowering before PR 1 and has
+not been re-measured (PERF.md), so the default stem remains the unfused
+one.
 """
 
 from __future__ import annotations
@@ -60,15 +63,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-try:  # pallas is TPU-only at runtime but importable everywhere
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pl = pltpu = None
+import jax.experimental.pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 # ---------------------------------------------------------------------------
-# shared XLA forward (also the Pallas fallback / reference)
+# shared XLA forward (the off-TPU path and the reference)
 # ---------------------------------------------------------------------------
 
 def _fwd_xla(z, gamma_t, beta_t):
@@ -341,7 +341,19 @@ def _bwd_pallas(z, gamma_t, beta_t, g, interpret=False):
 # ---------------------------------------------------------------------------
 
 def _use_pallas(z):
-    return jax.default_backend() == "tpu" and _pallas_ok(z)
+    """Pallas on the TPU, the lax reference elsewhere. On the TPU there
+    is no second path: a shape the kernels cannot take raises instead of
+    quietly running the XLA composition under the fused-stem name."""
+    if jax.default_backend() != "tpu":
+        return False
+    if not _pallas_ok(z):
+        raise ValueError(
+            f"fused stem: the Pallas kernels need even square spatial "
+            f"dims >= 4 and a channel count divisible by 64, got "
+            f"{z.shape} — unset DPTPU_FUSED_STEM (or fused_stem=False) "
+            f"for this input size"
+        )
+    return True
 
 
 @partial(jax.custom_vjp)
@@ -371,14 +383,15 @@ _affine_relu_pool_even.defvjp(_arp_fwd, _arp_bwd)
 def affine_relu_pool(z, gamma_t, beta_t):
     """maxpool_3x3s2p1(relu(gamma_t * z + beta_t)) with a fused backward.
 
-    ``z``: NHWC; ``gamma_t``/``beta_t``: per-channel affine. Even spatial
-    dims run the custom-VJP region (Pallas kernels on TPU when the shape
-    qualifies, pure-XLA reference otherwise — identical semantics). Odd
-    dims fall back to the plain composition, whose backward is XLA's own
-    select_and_scatter: the fused backward's parity interleave only
-    reconstructs 2*oh x 2*ow planes.
+    ``z``: NHWC; ``gamma_t``/``beta_t``: per-channel affine. On the TPU
+    this runs the Pallas kernels or raises (``_use_pallas``). Elsewhere
+    even spatial dims run the custom-VJP region on the lax reference
+    (identical semantics) and odd dims take the plain composition, whose
+    backward is XLA's own select_and_scatter: the fused backward's
+    parity interleave only reconstructs 2*oh x 2*ow planes.
     """
-    if z.shape[1] % 2 or z.shape[2] % 2:
+    on_tpu = _use_pallas(z)  # raises there for a shape the kernels refuse
+    if not on_tpu and (z.shape[1] % 2 or z.shape[2] % 2):
         a = gamma_t.astype(jnp.float32) * z.astype(jnp.float32) \
             + beta_t.astype(jnp.float32)
         pooled = lax.reduce_window(

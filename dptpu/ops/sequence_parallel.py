@@ -55,14 +55,6 @@ import jax.numpy as jnp
 _MASKED = -1e30
 
 
-def axis_size(axis_name: str) -> int:
-    """Static mesh-axis size, portable across jax versions:
-    ``lax.axis_size`` only exists in newer jax; ``psum(1, axis)`` is the
-    classic spelling and constant-folds to the same static int."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:  # pragma: no cover - depends on jax version
-        return jax.lax.psum(1, axis_name)
 
 
 def full_attention(q, k, v, kv_mask=None):
@@ -90,7 +82,7 @@ def ulysses_attention(q, k, v, axis_name: str, kv_mask=None):
     sequence axis of q/k/v partitioned over ``axis_name``. ``kv_mask``
     (seq/N,) bool marks this shard's valid key positions.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     heads = q.shape[2]
     if heads % n:
         raise ValueError(
@@ -134,7 +126,7 @@ def ring_attention(q, k, v, axis_name: str, kv_mask=None):
     ``kv_mask`` (seq/N,) bool marks this shard's valid key positions;
     it rides the ring alongside its k/v block.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     hd = q.shape[-1]
     scale = 1.0 / math.sqrt(hd)
     qf = q.astype(jnp.float32) * scale

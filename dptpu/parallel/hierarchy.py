@@ -19,7 +19,7 @@ reduced-precision exchange on the slow hop):
 ICI bytes stay at the flat all-reduce's volume (the reduce-scatter +
 all-gather pair IS a decomposed all-reduce); only the slow hop shrinks.
 The engine is expressed with EXPLICIT collectives in the shard_map step
-bodies (``check_rep=False``, the repo-wide discipline), over the
+bodies (``check_vma=False``, the repo-wide discipline), over the
 ``{slice: S, data: N/S}`` mesh ``make_hierarchical_mesh`` builds.
 
 **bf16 DCN compression** (``DPTPU_DCN_DTYPE=bf16``, opt-in; default

@@ -16,7 +16,7 @@ Design (why this is NOT the shared ``train_step_body``):
   already contains cross-``seq`` collectives (all_to_all/ppermute/psum)
   whose VJPs route the cross-member cotangents;
 * Ulysses' all-to-all output sharding defeats shard_map's replication
-  checker, so the step runs ``check_rep=False`` — no automatic psum is
+  checker, so the step runs ``check_vma=False`` — no automatic psum is
   inserted for the replicated params, and the gradient reduction is
   therefore EXPLICIT: each (data, seq) member differentiates the global
   mean loss restricted to its local graph, and one

@@ -37,7 +37,7 @@ weight-update sharding, the PAPERS.md retrieval — falls out of the same
   single-device big-batch step;
 * the few leaves no dimension divides (tiny biases — a rounding error
   of the bytes) stay replicated; their gradients take an explicit
-  ``lax.psum`` (the steps run ``check_rep=False``, so no implicit
+  ``lax.psum`` (the steps run ``check_vma=False``, so no implicit
   collective exists to cover them — see
   dptpu.train.step.shard_map_nocheck).
 
@@ -455,7 +455,10 @@ def make_zero3_train_step(mesh: Mesh, state_template, param_specs,
         out_specs=(specs, P()),
     )
     return jax.jit(
-        sharded, donate_argnums=0, compiler_options=tpu_compiler_options()
+        sharded, donate_argnums=0,
+        compiler_options=tpu_compiler_options(
+            collectives_in_scan=accum_steps > 1
+        ),
     )
 
 
@@ -573,7 +576,7 @@ def make_zero1_train_step(mesh: Mesh, state_template, compute_dtype=jnp.float32,
         # and it runs once per UPDATE (reduce_grads sits after the
         # accumulation scan), never per microbatch. The replicated
         # remainder (no divisible dim) needs its explicit cross-replica
-        # sum — under check_rep=False nothing is implicit.
+        # sum — under check_vma=False nothing is implicit.
         def red(g, s):
             if _sharded_axis(s) >= 0:
                 return dcn_reduce_shard(g, SLICE_AXIS, dcn_dtype,
@@ -622,5 +625,8 @@ def make_zero1_train_step(mesh: Mesh, state_template, compute_dtype=jnp.float32,
         out_specs=(specs, P()),
     )
     return jax.jit(
-        sharded, donate_argnums=0, compiler_options=tpu_compiler_options()
+        sharded, donate_argnums=0,
+        compiler_options=tpu_compiler_options(
+            collectives_in_scan=accum_steps > 1
+        ),
     )

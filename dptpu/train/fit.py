@@ -47,6 +47,7 @@ from dptpu.parallel import (
     initialize_distributed,
     make_mesh,
     make_zero1_train_step,
+    replicated_sharding,
     shard_host_batch,
     shard_zero1_state,
 )
@@ -60,6 +61,8 @@ from dptpu.train.checkpoint import load_checkpoint, save_checkpoint
 from dptpu.train.loop import train_one_epoch, validate
 from dptpu.train.state import create_train_state, make_optimizer
 from dptpu.train.step import make_eval_step, make_train_step
+from dptpu.utils.compile_cache import enable_compile_cache
+from dptpu.utils.provenance import device_banner
 
 
 def _os_environ_flag(name: str) -> bool:
@@ -335,6 +338,7 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
                 f"the run ends at --epochs {cfg.epochs} — that phase "
                 f"would never train"
             )
+    enable_compile_cache()
     initialize_distributed(cfg)
     derived = derive(
         cfg,
@@ -344,6 +348,8 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
     )
     if verbose is None:
         verbose = derived.is_chief
+    if verbose:
+        print(device_banner())
     if not cfg.evaluate and derived.per_device_batch_size % accum_steps:
         raise ValueError(
             f"--accum-steps/DPTPU_ACCUM {accum_steps} does not divide the "
@@ -588,7 +594,9 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
     # (DPTPU_CACHE_SCOPE picks pooled-slab vs per-worker-sharded), and
     # DPTPU_LEASE keeps process-mode batches zero-copy end to end.
     workers_mode, cache_bytes, cache_scope, leased = _feed_knobs()
-    if verbose and (workers_mode != "thread" or cache_bytes):
+    if verbose:
+        from dptpu.data import native_image
+
         print(
             f"=> input pipeline: workers_mode={workers_mode}, "
             f"decode cache "
@@ -596,6 +604,9 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
                if cache_bytes else "off")
             + (", leased slots" if leased and workers_mode == "process"
                else "")
+            # which JPEG decoder is live: the native libjpeg ops, or PIL
+            # after a failed build (dptpu/native/build.py says why)
+            + f", native={native_image.available()}"
         )
     train_ds, val_ds, num_classes = _build_datasets(
         cfg, image_size, cache_bytes=cache_bytes, cache_scope=cache_scope
@@ -1409,6 +1420,16 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
         train_step = _build_train_step(schedule)
         eval_view = lambda s: s  # noqa: E731
         eval_view_gathers = False
+        if jax.process_count() == 1:
+            # commit the state to where the step will leave it (this
+            # device, or replicated over the mesh): the uncommitted
+            # state of the first call and the committed one the step
+            # returns are different jit cache keys, and the second would
+            # compile the whole step again at step 1 (chip run, PR 21:
+            # 44.8 s then 21.6 s for ResNet-50). One process only: a
+            # host-local state is not placed on a mesh that spans hosts.
+            state = (put(state) if single_device
+                     else jax.device_put(state, replicated_sharding(mesh)))
     eval_step = make_eval_step(mesh, compute_dtype)
 
     if cfg.evaluate:

@@ -59,7 +59,8 @@ def build_tune_parser():
                    help="modeled per-chip DCN bandwidth (GB/s)")
     p.add_argument("--dcn-latency-us", type=float, default=15.0)
     p.add_argument("--chip-img-per-s", type=float, default=2734.0,
-                   help="chip-equivalent compute anchor (BENCH_r04)")
+                   help="chip-equivalent compute anchor (the pre-PR-1 one-chip "
+                        "rate; not re-measured on the current code)")
     p.add_argument("--probe", choices=("none", "quick", "full"),
                    default="quick",
                    help="measured fit() probes: none = cost model "
@@ -93,7 +94,10 @@ def main_tune(argv=None):
         search_serve_buckets,
     )
 
+    from dptpu.utils.compile_cache import enable_compile_cache
+
     args = build_tune_parser().parse_args(argv)
+    enable_compile_cache()
     if args.smoke:
         args.probe = "quick" if args.probe != "none" else "none"
         args.serve_probe = False

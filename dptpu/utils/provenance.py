@@ -41,3 +41,25 @@ def host_provenance() -> dict:
         "python": sys.version.split()[0],
         "jax": jax_version,
     }
+
+
+def device_summary() -> dict:
+    """Where this process's jax runs, as jax reports it — the ``device``
+    object of every chip result and the one-line banner ``fit()`` and
+    ``dptpu serve`` print, so a silent CPU fallback (jax with libtpu
+    installed drops to CPU with only a warning when it finds no chip)
+    is visible in every log. Initializes the backend."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
+def device_banner() -> str:
+    d = device_summary()
+    return (f"=> devices: platform={d['platform']} "
+            f"device_kind={d['kind']} count={d['count']}")

@@ -5,8 +5,10 @@ CLI-compatible.
 
 The reference's 5-way device-placement ladder (CPU → pinned GPU → DDP →
 DataParallel, nd_imagenet.py:140-169) collapses on TPU: ``--gpu N`` pins one
-local chip, otherwise all visible devices join a mesh; a CPU-only machine
-just runs the same program on the CPU backend. ``--seed`` gives end-to-end
+local chip, otherwise all visible devices join a mesh. The same program
+runs on the CPU backend (the tests do) — jax drops there with only a
+warning when it finds no chip, so every run prints one ``=> devices:``
+line saying where it is. ``--seed`` gives end-to-end
 reproducibility (XLA is deterministic by default — no cudnn.deterministic
 trade-off, nd_imagenet.py:84-92).
 """

@@ -9,10 +9,10 @@ exact) x HBM bytes (fusion operands + outputs) x its OWN roofline
 max(MXU time, traffic time) — so "the residual is emitter-bound" is
 either demonstrated per layer or refuted by specific outliers.
 
-Machine constants are the round-3 measured ones (in-program chains):
-bf16 peak 197 TFLOP/s, sustained HBM 635 GB/s. Methodology cautions
-from PERF.md apply: wall clock lies on this relay; only the trace's
-per-op durations are trustworthy.
+Machine constants: bf16 peak 197 TFLOP/s (published), sustained HBM
+635 GB/s (an in-program chain measured before PR 1 on a set-up that no
+longer exists — re-measure before trusting a roofline share, PERF.md).
+Per-op times come from the device trace, not the host clock.
 
 Writes CONV_ROOFLINE.json (repo root) and prints the table.
 

@@ -4,7 +4,7 @@
 1-D f32 leaves (BN scale/bias/stats, fc bias) go into one flat vector;
 >=2-D leaves are grouped by shape and stacked along a new leading dim
 (leading-dim slices are layout-preserving, unlike flattening, which
-forced a relayout per kernel — exp_packed2 measured that at +13 ms).
+forced a layout change per kernel — exp_packed2 measured that at +13 ms).
 Boundary tensor count drops ~430 -> ~40. Interleaved A/B vs stock.
 """
 

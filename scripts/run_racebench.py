@@ -212,9 +212,10 @@ def main():
                          "first is the headline gate's")
     ap.add_argument("--dcn-latency-us", type=float, default=15.0)
     ap.add_argument("--chip-img-per-s", type=float, default=2734.0,
-                    help="measured real-chip step rate anchoring the "
-                         "chip-equivalent compute rows (BENCH_r04: "
-                         "2734 img/s/chip, roofline-pinned v5e)")
+                    help="one-chip step rate anchoring the "
+                         "chip-equivalent compute rows (2734 img/s/chip "
+                         "was measured before PR 1; not re-measured on "
+                         "the current code, PERF.md)")
     ap.add_argument("--smoke", action="store_true",
                     help="gates only: one bucket size, no ZeRO-1 arm, "
                          "no recipe run (the tier-1 preset)")
@@ -376,8 +377,8 @@ def main():
     # two compute anchors: this host's measured step (compute ~50-100x
     # a real chip's, so the comm/compute ratio — and with it the
     # overlap win — is badly UNDERSTATED), and the chip-equivalent
-    # step time from the repo's roofline-measured device rate
-    # (BENCH_r04), which is the regime the race actually runs in
+    # step time from --chip-img-per-s, which is the regime the race
+    # actually runs in
     t_chip = args.per_chip_batch / args.chip_img_per_s
     perleaf_sizes = [int(np.prod(l.shape)) * 4 if l.shape else 4
                      for l in reversed(leaves)]

@@ -30,10 +30,11 @@ Gates (exit non-zero on failure unless ``--no-gate``):
   operating regime), ``p99 <= max(--tail-floor-ms, --tail-factor x
   p50)``: a no-pathological-tail claim that self-calibrates to the
   host instead of hard-coding a ms budget a 2-core box cannot meet.
-* **parity** — padded-bucket serving is logit-IDENTICAL to the
-  single-request path (3 real rows through the largest bucket vs three
-  bucket-1 calls, max|dlogit| must be exactly 0) — the engine's
-  batch-invariant-numerics contract, re-proven on the bench engine.
+* **parity** — padded-bucket serving agrees with the single-request
+  path (3 real rows through the largest bucket vs three bucket-1
+  calls, max|dlogit| <= ``BUCKET_PARITY_ATOL``: fp32 rounding, exactly
+  0 on a one-core host) — the engine's bucket-invariant-numerics
+  contract, re-proven on the bench engine.
 
 Plus the ISSUE 18 arms, both gated: the **quantized** arm rolls an
 int8 calibration artifact out through the canary's artifact-armed
@@ -173,8 +174,8 @@ def open_loop_point(engine, knobs, pool, offered_qps, n_requests, seed=0):
 
 
 def parity_check(engine, pool):
-    """The engine's = 0 contract on THIS bench configuration: 3 real
-    rows through the largest bucket vs three bucket-1 calls."""
+    """The engine's bucket-parity contract on THIS bench configuration:
+    3 real rows through the largest bucket vs three bucket-1 calls."""
     x = np.stack(pool[:3])
     solo = np.concatenate([engine.infer(x[i:i + 1]) for i in range(3)])
     nexec = engine.exec_batch(engine.max_bucket)
@@ -803,13 +804,15 @@ def fleet_arm(engine, knobs, pool, n_requests, workdir):
     for t in threads:
         t.join()
     wall = time.perf_counter() - t0
-    stop_sampler.set()
 
     # the staleness verdict needs one more beat-deadline to land if the
-    # load finished fast; wait it out, then read the route table
+    # load finished fast; wait it out (the sampler keeps running, so the
+    # drain curve records the drop either way), then read the route table
     deadline = time.time() + stale_s + 0.5
     while "host-a" in router.members() and time.time() < deadline:
         time.sleep(0.05)
+    time.sleep(0.1)  # two sampler periods: the drained state is on record
+    stop_sampler.set()
     alive = sorted(router.members())
     drained_after_s = None
     if killed[0] is not None:
@@ -897,6 +900,7 @@ def main():
     import jax
 
     from dptpu.serve import ServeEngine, serve_knobs
+    from dptpu.serve.engine import BUCKET_PARITY_ATOL
 
     knobs = serve_knobs(buckets=buckets, max_delay_ms=args.max_delay_ms,
                         slots=args.slots)
@@ -942,7 +946,7 @@ def main():
                          args.tail_factor * gp["p50_ms"])
     gates = {
         "tail_ok": gp["p99_ms"] <= tail_budget_ms,
-        "parity_ok": max_dlogit == 0.0,
+        "parity_ok": max_dlogit <= BUCKET_PARITY_ATOL,
     }
 
     # robustness arms (ISSUE 17): overload shedding, co-resident

@@ -299,7 +299,7 @@ def test_shard_map_raw_call_flagged_nocheck_wrapper_clean():
 
     def shard_map_nocheck(f, mesh, in_specs, out_specs):
         return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+                         out_specs=out_specs, check_vma=False)
 
     def make_step(mesh):
         return shard_map(lambda s: s, mesh=mesh, in_specs=(),
@@ -309,7 +309,7 @@ def test_shard_map_raw_call_flagged_nocheck_wrapper_clean():
                         only=["shard-map"])
     assert len(findings) == 1
     assert findings[0].line == 8
-    assert "check_rep=False" in findings[0].message
+    assert "check_vma=False" in findings[0].message
 
 
 def test_shard_map_axis_names_threading():

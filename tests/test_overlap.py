@@ -293,9 +293,15 @@ def test_overlap_metrics_match_unbucketed():
 
 
 def _compiled_text(mesh, **kw):
+    # compiled the way `dptpu check` compiles its budget configs: with
+    # XLA:CPU's all-reduce combiner off, so the schedule shows the
+    # reductions the engine emitted, not one combined instruction
+    from dptpu.analysis.hlo_budget import COMPILER_OPTIONS
+
     st = _replicate(_state(), mesh)
     step = make_train_step(mesh, **kw)
     return step.lower(st, shard_host_batch(_batch(), mesh)).compile(
+        compiler_options=COMPILER_OPTIONS
     ).as_text()
 
 

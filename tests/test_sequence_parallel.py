@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from dptpu.ops.sequence_parallel import (
     full_attention,
@@ -104,7 +104,7 @@ def test_masked_padding_matches_unpadded(eight_devices, fn):
     sharded = shard_map(
         lambda q, k, v, m: fn(q, k, v, axis_name="seq", kv_mask=m),
         mesh=mesh, in_specs=(spec, spec, spec, P("seq")),
-        out_specs=spec, check_rep=False,
+        out_specs=spec, check_vma=False,
     )
     got = jax.jit(sharded)(qp, kp, vp, mask)
     np.testing.assert_allclose(np.asarray(got[:, :s_real]),
@@ -129,7 +129,7 @@ def test_masked_gradients_finite_and_match(eight_devices):
     sharded = shard_map(
         lambda q, k, v, m: ring_attention(q, k, v, "seq", kv_mask=m),
         mesh=mesh, in_specs=(spec, spec, spec, P("seq")),
-        out_specs=spec, check_rep=False,
+        out_specs=spec, check_vma=False,
     )
 
     def loss(t):
@@ -185,7 +185,7 @@ def test_vit_full_encoder_sequence_parallel(eight_devices, mode):
         mesh=_mesh(eight_devices),
         in_specs=(pspecs, P(None, "seq", None)),
         out_specs=P(None, "seq", None),
-        check_rep=False,
+        check_vma=False,
     )
     got = jax.jit(fn)(params, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -214,7 +214,7 @@ def test_vit_encoder_layer_sequence_parallel(eight_devices):
         mesh=mesh,
         in_specs=(P(), P(None, "seq", None)),
         out_specs=P(None, "seq", None),
-        check_rep=False,
+        check_vma=False,
     )
     got = jax.jit(fn)(params, x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),

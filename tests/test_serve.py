@@ -25,6 +25,7 @@ import jax
 
 from dptpu.serve import DynamicBatcher, ServeEngine, preprocess_bytes
 from dptpu.serve import staging as serve_staging
+from dptpu.serve.engine import BUCKET_PARITY_ATOL
 
 
 def _rand_images(n, size, seed=0):
@@ -62,9 +63,17 @@ def vit_engine():
 # ---------------------------------------------------------------- parity ----
 
 
+def _assert_bucket_parity(got, want):
+    """Same row through two DIFFERENT exec sizes: fp32-rounding close
+    (``BUCKET_PARITY_ATOL``; XLA:CPU's thread pool splits reductions by
+    shape, so it is bit-exact only on a one-core host). Same-exec-size
+    comparisons elsewhere in this module stay ``assert_array_equal``."""
+    np.testing.assert_allclose(got, want, rtol=0, atol=BUCKET_PARITY_ATOL)
+
+
 @pytest.mark.parametrize("fixture", ["cnn_engine", "vit_engine"])
 def test_padded_bucket_logit_identity(fixture, request):
-    """Bucket 16 with 3 real rows ≡ bucket-1 answers, max|Δlogit| = 0."""
+    """Bucket 16 with 3 real rows agrees with the bucket-1 answers."""
     engine = request.getfixturevalue(fixture)
     x = _rand_images(3, engine.image_size)
     solo = np.concatenate(
@@ -72,7 +81,7 @@ def test_padded_bucket_logit_identity(fixture, request):
     )  # three bucket-1 answers
     via16 = engine.infer(x)  # coalesced: bucket 16, 13 pad rows
     assert engine.bucket_for(3) in (4, 16)
-    np.testing.assert_array_equal(via16, solo)  # max|Δlogit| = 0, exactly
+    _assert_bucket_parity(via16, solo)
 
 
 def test_pad_content_cannot_perturb_real_rows(cnn_engine):
@@ -148,9 +157,7 @@ def test_batcher_parity_and_coalescing(cnn_engine):
     try:
         futs = [b.submit_array(x[i % 8]) for i in range(32)]
         for i, f in enumerate(futs):
-            np.testing.assert_array_equal(
-                f.result(timeout=60), solo[i % 8]
-            )
+            _assert_bucket_parity(f.result(timeout=60), solo[i % 8])
         st = b.stats()
         assert st["completed"] == 32 and st["failed"] == 0
         # coalescing happened: fewer batches than requests, and some
@@ -186,8 +193,8 @@ def test_bad_request_fails_alone_not_the_batch(cnn_engine):
         solo = np.concatenate(
             [cnn_engine.infer(x[i:i + 1]) for i in range(2)]
         )
-        np.testing.assert_array_equal(good1.result(timeout=60), solo[0])
-        np.testing.assert_array_equal(good2.result(timeout=60), solo[1])
+        _assert_bucket_parity(good1.result(timeout=60), solo[0])
+        _assert_bucket_parity(good2.result(timeout=60), solo[1])
     finally:
         b.close()
 
@@ -402,8 +409,8 @@ def test_cancel_pre_dispatch_frees_rows(cnn_engine):
             want5 = b2.submit_array(imgs[5]).result(timeout=30)
         finally:
             b2.close()
-        np.testing.assert_array_equal(r0, want0)
-        np.testing.assert_array_equal(r5, want5)
+        _assert_bucket_parity(r0, want0)
+        _assert_bucket_parity(r5, want5)
         s = b.stats()
         assert s["cancelled"] == 4
         assert s["dead_rows"] == 4

@@ -14,7 +14,7 @@ import os
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CHIP_IMG_PER_S = 2734.0  # BENCH_r04 anchor (run_racebench default)
+CHIP_IMG_PER_S = 2734.0  # run_racebench default anchor
 
 
 @pytest.fixture(scope="module")
