@@ -1,0 +1,593 @@
+"""Drive one cell once: seeded weights, ``main_apex`` -> ``fit()``, the
+window, the fence, the comparison, the result line.
+
+The trainer runs as a user runs it: ``dptpu.cli.main_apex`` in this
+process, ``WORLD_SIZE=1``, ``--opt-level O2``, ``synthetic:<N>``, the
+program's defaults for whatever the configuration and traffic files do
+not state. The harness adds three things around it and edits nothing:
+
+* **weights from the seed.** The apex CLI has no ``--seed``; what a user
+  can hand it is weights (``--pretrained`` with ``DPTPU_PRETRAINED_DIR``).
+  The reference module makes a torchvision-layout state dict from
+  ``--seed`` on the device in one jitted call; the program's own
+  converter (``convert_state_dict`` / ``save_npz``, what
+  ``python -m dptpu.tools.convert_torchvision`` calls) writes it where
+  ``--pretrained`` looks. ``--seed`` also picks ``N`` of ``synthetic:<N>``
+  (``dataset_images + seed % 128``), so each seed sees its own rows in
+  its own order.
+* **a tap on the step**, at the seam between ``fit()`` and its loop
+  (``train_one_epoch(state, train_step, batches, ...)``): the wrapped
+  ``train_step`` copies out, during the first ``check_steps`` calls, the
+  delivered batch, the step's loss, the momentum buffers after the first
+  step and the parameters after the last, then counts calls. The same
+  step, state and feed go on into the window.
+* **a clock thread**: once ``warmup_iters`` calls have returned it waits
+  ``--seconds``, then sends this process SIGTERM. That is the trainer's
+  documented preemption path: the loop finishes the step in flight,
+  leaves, and fetches the pending metrics (the fence). In a traced run the
+  thread also holds a ``jax.profiler`` trace open over a short stretch.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import functools
+import importlib
+import json
+import math
+import os
+import shutil
+import signal
+import socket
+import sys
+import tempfile
+import threading
+import time
+from typing import Dict, List, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..reference import common as reference_common
+from . import cells, check, spans as spans_mod, synthetic, tracered
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+SETUP_LIMIT_S = 1100.0  # a cold first run may compile for many minutes
+
+
+class CompileMeter:
+    """Backend-compile seconds (a cache load counts) with the wall time of
+    each, from jax's own monitoring events — as ``chip_smoke.py`` meters
+    them."""
+
+    def __init__(self):
+        import jax.monitoring as monitoring
+
+        self.events: List[tuple] = []  # (wall time at end, seconds)
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+
+    def _on_duration(self, event, seconds, **_):
+        if event == _COMPILE_EVENT:
+            self.events.append((time.time(), float(seconds)))
+
+    def seconds_before(self, wall: float) -> float:
+        return sum(s for t, s in self.events if t <= wall)
+
+    def count_between(self, lo: float, hi: float) -> int:
+        return sum(1 for t, _ in self.events if lo < t <= hi)
+
+
+def _flat(tree) -> Dict[str, np.ndarray]:
+    """A params-shaped pytree as ``{"a/b/c": array}``."""
+    return {"/".join(str(getattr(k, "key", k)) for k in path): np.asarray(leaf)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def _momentum(opt_state):
+    """The SGD momentum buffers of an optax chain state."""
+    import optax
+
+    is_trace = lambda n: isinstance(n, optax.TraceState)  # noqa: E731
+    found = [n for n in jax.tree_util.tree_leaves(opt_state, is_leaf=is_trace)
+             if is_trace(n)]
+    if len(found) != 1:
+        raise RuntimeError(f"expected one momentum trace in the optimizer "
+                           f"state, found {len(found)}")
+    return found[0].trace
+
+
+class StepTap:
+    """Wraps the loop's ``train_step``; see the module docstring."""
+
+    def __init__(self, check_steps: int, warmup_iters: int):
+        if warmup_iters < check_steps:
+            raise ValueError("warm-up must cover the checked steps")
+        self.check_steps = check_steps
+        self.warmup_iters = warmup_iters
+        self.calls = 0
+        self.batches: List[tuple] = []
+        self.losses: List[float] = []
+        self.trace1: Optional[Dict[str, np.ndarray]] = None
+        self.params_after: Optional[Dict[str, np.ndarray]] = None
+        self.warm = threading.Event()
+        self.step_fn = None    # the loop's own jitted step
+        self.step_avals = None  # shapes and placements of its arguments
+
+    def wrap(self, train_step):
+        self.step_fn = train_step
+
+        def step(state, batch):
+            i = self.calls
+            checked = i < self.check_steps
+            if i == 0:
+                self.step_avals = jax.tree_util.tree_map(
+                    lambda x: jax.ShapeDtypeStruct(
+                        x.shape, x.dtype, sharding=x.sharding),
+                    (state, batch))
+            if checked:
+                host = jax.device_get(batch)
+                self.batches.append((np.asarray(host["images"]),
+                                     np.asarray(host["labels"])))
+            out = train_step(state, batch)
+            if checked:
+                new_state, metrics = out
+                self.losses.append(float(jax.device_get(metrics["loss"])))
+                if i == 0:
+                    self.trace1 = _flat(jax.device_get(
+                        _momentum(new_state.opt_state)))
+                if i == self.check_steps - 1:
+                    self.params_after = _flat(
+                        jax.device_get(new_state.params))
+            self.calls = i + 1
+            if self.calls == self.warmup_iters:
+                self.warm.set()
+            return out
+
+        return step
+
+
+@contextlib.contextmanager
+def _environ(**overrides):
+    saved = {k: os.environ.get(k) for k in overrides}
+    os.environ.update(overrides)
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+
+
+@contextlib.contextmanager
+def _tapped_loop(tap: StepTap):
+    """``fit()`` looks ``train_one_epoch`` up in its own module: stand a
+    wrapper there for the length of one run."""
+    # import_module gives the module; the attribute dptpu.train.fit is the
+    # function that dptpu.train re-exports under the same name
+    fit_module = importlib.import_module("dptpu.train.fit")
+    original = fit_module.train_one_epoch
+
+    def train_one_epoch(state, train_step, batches, **kwargs):
+        return original(state, tap.wrap(train_step), batches, **kwargs)
+
+    fit_module.train_one_epoch = train_one_epoch
+    try:
+        yield
+    finally:
+        fit_module.train_one_epoch = original
+
+
+class _Clock(threading.Thread):
+    """Closes the window from outside; traces the last steps of it if
+    asked."""
+
+    def __init__(self, tap: StepTap, seconds: float, trace_dir: str = None,
+                 trace_skip_s: float = 0.0, trace_read_s: float = 0.0):
+        super().__init__(name="bench-clock", daemon=True)
+        self.tap, self.seconds = tap, seconds
+        self.trace_dir = trace_dir
+        self.trace_skip_s, self.trace_read_s = trace_skip_s, trace_read_s
+        self.done = threading.Event()
+        self.anchors: List[tuple] = []  # (wall, which)
+        self.trace_open_wall: Optional[float] = None
+        self.signalled = False
+        self.error: Optional[BaseException] = None
+
+    def _sleep_until(self, t: float) -> bool:
+        """False if the run ended first."""
+        return not self.done.wait(max(t - time.perf_counter(), 0.0))
+
+    def _anchor(self, which: str):
+        wall = time.time()
+        with jax.profiler.TraceAnnotation(tracered.ANCHOR):
+            self.anchors.append((wall, which))
+            time.sleep(0.001)
+
+    def _signal(self):
+        self.signalled = True
+        os.kill(os.getpid(), signal.SIGTERM)
+
+    def _resumed(self, calls_at_start: int, t_started: float) -> bool:
+        """Starting the TPU profiler stalls the next dispatch (one to three
+        seconds) and, once the steps queued before it have drained, the
+        device (about a second). The stretch that is read opens once
+        ``trace_skip_s`` have passed, two more calls of the step have
+        returned AND a marker program enqueued after them has run: the
+        device executes in order, so the stall lies behind it. False if
+        the run ended first."""
+        if not self._sleep_until(t_started + self.trace_skip_s):
+            return False
+        limit = time.perf_counter() + 30.0
+        while self.tap.calls < calls_at_start + 2:
+            if self.done.wait(0.002) or time.perf_counter() > limit:
+                return False
+        self._marker(self._marker_arg).block_until_ready()
+        return not self.done.is_set()
+
+    def _traced_end(self, t_end: float):
+        """The profiler over the window's last seconds. The two anchors
+        mark the stretch that is read, ``trace_read_s`` long, after the
+        profiler's start-up stall and before ``stop_trace``, which is slow
+        (half a minute per traced second and chip) and runs after the
+        window is closed. A traced window ends when the stretch does."""
+        if not self._sleep_until(
+                t_end - self.trace_skip_s - self.trace_read_s):
+            return
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0  # no per-call Python events
+        opts.host_tracer_level = 1    # annotations only
+        opts.enable_hlo_proto = False
+        self.trace_open_wall = time.time()
+        calls = self.tap.calls
+        t = time.perf_counter()
+        jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
+        t_started = time.perf_counter()
+        try:
+            if self._resumed(calls, t_started):
+                self._anchor("open")
+                self._sleep_until(time.perf_counter() + self.trace_read_s)
+                self._anchor("close")
+            if not self.done.is_set():
+                self._signal()  # the window closes; the trace is written after
+        finally:
+            t_stop = time.perf_counter()
+            jax.profiler.stop_trace()
+        opened = (f"{self.anchors[0][0] - self.trace_open_wall:.2f} s after "
+                  f"it" if self.anchors else "never")
+        print(f"benchmark: start_trace {t_started - t:.2f} s, stretch opened "
+              f"{opened}, stop_trace {time.perf_counter() - t_stop:.2f} s",
+              file=sys.stderr)
+
+    def run(self):
+        try:
+            if self.trace_dir:
+                # compiled and placed during set-up, never in the window
+                self._marker = jax.jit(lambda x: x + 1)
+                self._marker_arg = jnp.zeros((), jnp.int32)
+                self._marker(self._marker_arg).block_until_ready()
+            if not self.tap.warm.wait(SETUP_LIMIT_S) or self.done.is_set():
+                return
+            t_end = time.perf_counter() + self.seconds
+            if self.trace_dir:
+                # what comes before the profiler opens is untouched by it:
+                # a traced run's host-span metrics are read from that part
+                self._traced_end(t_end)
+            elif self._sleep_until(t_end):
+                self._signal()
+        except BaseException as exc:  # surfaced by run_fit, never lost
+            self.error = exc
+            os.kill(os.getpid(), signal.SIGTERM)
+
+
+def program_template(config: dict):
+    """Shapes of the program's ``{"params", "batch_stats"}`` for
+    ``config`` — no weights are made."""
+    from dptpu.models import create_model
+
+    size = config["model"]["image_size"]
+    model = create_model(config["arch"],
+                         num_classes=config["model"]["num_classes"])
+    return jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, size, size, 3), jnp.float32),
+        train=False))
+
+
+def to_program_layout(config: dict, template, named: Dict[str, np.ndarray],
+                      fill: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+    """Torch-named leaves through the program's public import path, as
+    ``{"a/b/c": array}`` of its ``params``. ``fill`` supplies the leaves
+    (buffers) that ``named`` lacks, which the converter insists on."""
+    from dptpu.models.pretrained import convert_state_dict
+
+    full = {k: np.zeros_like(v) for k, v in fill.items()}
+    full.update(named)
+    return _flat(convert_state_dict(config["arch"], full, template)["params"])
+
+
+def write_pretrained(config: dict, template, weights: Dict[str, np.ndarray],
+                     directory: str) -> None:
+    """``weights`` where ``--pretrained`` looks, by the program's own
+    converter (what ``dptpu.tools.convert_torchvision`` calls)."""
+    from dptpu.models.pretrained import convert_state_dict, save_npz
+
+    os.makedirs(directory, exist_ok=True)
+    save_npz(os.path.join(directory, f"{config['arch']}.npz"),
+             convert_state_dict(config["arch"], weights, template))
+
+
+def dataset_images(traffic: dict, seed: int) -> int:
+    """``N`` of ``synthetic:<N>``: up to 127 rows more than the traffic
+    file's, by the seed. The epoch's order depends on N; the steps per
+    epoch, which the learning-rate schedule bakes into the step program,
+    do not (the base is whole steps of 128 and of 512 rows less 384)."""
+    return int(traffic["dataset_images"]) + int(seed) % 128
+
+
+def fit_argv(cell, n_images: int) -> List[str]:
+    cfg, traffic = cell.config, cell.traffic
+    # the apex CLI scales --lr by global_batch / 256
+    lr = float(traffic["effective_lr"]) * 256.0 / cell.global_batch
+    argv = [f"synthetic:{n_images}", "-a", cfg["arch"],
+            "--opt-level", cfg["precision"]["opt_level"],
+            "-b", str(cfg["per_chip_batch"]), "--lr", repr(lr),
+            "--momentum", repr(cfg["optimizer"]["momentum"]),
+            "--wd", repr(cfg["optimizer"]["weight_decay"]),
+            "--start-epoch", str(traffic["start_epoch"]), "--pretrained"]
+    return argv + [str(a) for a in traffic.get("extra_argv", [])]
+
+
+def run_fit(cell, seed: int, seconds: float, trace: bool, work: str,
+            weights: Dict[str, np.ndarray], template, tap: StepTap):
+    """One ``main_apex`` call with the tap and the clock; returns
+    ``(result, clock, obs_log_path)``."""
+    from dptpu.cli import main_apex
+
+    obs_dir = os.path.join(work, "obs")
+    trace_dir = os.path.join(work, "trace") if trace else None
+    write_pretrained(cell.config, template, weights,
+                     os.path.join(work, "pretrained"))
+    traffic = cell.traffic
+    clock = _Clock(tap, seconds, trace_dir, float(traffic["trace_skip_s"]),
+                   float(traffic["trace_read_s"]))
+    argv = fit_argv(cell, dataset_images(traffic, seed))
+    cwd = os.getcwd()
+    os.chdir(work)  # checkpoints and runs/ land in the scratch directory
+    clock.start()
+    try:
+        with _environ(WORLD_SIZE="1", DPTPU_OBS_DIR=obs_dir,
+                      DPTPU_PRETRAINED_DIR=os.path.join(work, "pretrained")), \
+                _tapped_loop(tap), contextlib.redirect_stdout(sys.stderr):
+            result = main_apex(argv)
+    finally:
+        clock.done.set()
+        clock.join(timeout=300.0)  # a traced run is still writing its trace
+        os.chdir(cwd)
+    if clock.is_alive():
+        raise RuntimeError("the clock thread did not end")
+    if clock.error is not None:
+        raise RuntimeError(f"the clock thread failed: {clock.error!r}")
+    if not clock.signalled or not result.get("preempted"):
+        raise RuntimeError(
+            "fit() returned before the window was closed from outside "
+            f"(signalled={clock.signalled}, "
+            f"preempted={result.get('preempted')}): the epoch is shorter "
+            f"than the window, or set-up outlasted {SETUP_LIMIT_S:.0f} s")
+    log = os.path.join(obs_dir, f"obs-{socket.gethostname()}.jsonl")
+    return result, clock, log
+
+
+def reference_run(cell, weights, batches, mode: str = "f32") -> dict:
+    """The plain reference over ``batches`` (the rows the benchmark
+    regenerated itself), from ``weights``. Torch names."""
+    cfg = cell.config
+    ref = cells.reference(cfg)
+    return reference_common.train_steps(
+        functools.partial(ref.forward, cfg["model"]),
+        ref.trainable(cfg["model"]), weights, batches,
+        lr=float(cell.traffic["effective_lr"]),
+        momentum=cfg["optimizer"]["momentum"],
+        weight_decay=cfg["optimizer"]["weight_decay"],
+        block_rows=int(cfg["reference_block_rows"]), mode=mode)
+
+
+def regenerate_batches(cell, seed: int):
+    cfg, traffic = cell.config, cell.traffic
+    model = cfg["model"]
+    order = synthetic.epoch_order(dataset_images(traffic, seed),
+                                  int(traffic["sampler_seed"]),
+                                  int(traffic["start_epoch"]))
+    return [synthetic.batch(order, k, cell.global_batch, model["image_size"],
+                            model["num_classes"])
+            for k in range(int(traffic["check_steps"]))]
+
+
+def make_weights(cell, seed: int) -> Dict[str, np.ndarray]:
+    ref = cells.reference(cell.config)
+    made = reference_common.make_weights(
+        ref.weight_spec(cell.config["model"]), seed)
+    return {k: np.asarray(v) for k, v in jax.device_get(made).items()}
+
+
+def count_nonfinite(params) -> int:
+    flags = jax.jit(lambda t: [jnp.logical_not(jnp.all(jnp.isfinite(x)))
+                               for x in jax.tree_util.tree_leaves(t)])(params)
+    return int(sum(bool(f) for f in jax.device_get(flags)))
+
+
+def allocator_peak_bytes() -> int:
+    """``peak_bytes_in_use`` of the fullest chip: arrays the process held
+    (state, batches in flight). On this runtime it leaves out the
+    temporaries a running program takes (a ResNet-50 step at 128 rows
+    reads 0.37 GB; chip run, PR 24)."""
+    peaks = []
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        peaks.append(int(stats.get("peak_bytes_in_use", 0)))
+    return max(peaks) if peaks else 0
+
+
+def step_temp_bytes(tap: StepTap) -> int:
+    """Per-chip temporaries of the very step the window drove, from the
+    compiler's own account of it (``memory_analysis`` of the loop's jitted
+    step at the shapes and placements it was called with; the program is
+    served from the compile cache)."""
+    if tap.step_fn is None or tap.step_avals is None \
+            or not hasattr(tap.step_fn, "lower"):
+        return 0
+    analysis = tap.step_fn.lower(*tap.step_avals).compile().memory_analysis()
+    return int(getattr(analysis, "temp_size_in_bytes", 0) or 0)
+
+
+def device_info() -> dict:
+    devices = jax.devices()
+    return {"platform": devices[0].platform, "kind": devices[0].device_kind,
+            "count": len(devices)}
+
+
+def program_record(cell, tap: StepTap, weights, template, nonfinite: int,
+                   regenerated) -> dict:
+    """What the timed path produced, as ``check.compare`` wants it."""
+    if tap.trace1 is None or tap.params_after is None \
+            or len(tap.losses) != tap.check_steps:
+        raise RuntimeError("the tap did not see the checked steps")
+    start = to_program_layout(cell.config, template, weights, weights)
+    delta = {k: tap.params_after[k].astype(np.float64) - start[k]
+             for k in start}
+    return {"loss": tap.losses, "trace1": tap.trace1, "delta": delta,
+            "feed_mismatch": check.feed_mismatch(tap.batches, regenerated),
+            "nonfinite": nonfinite}
+
+
+def host_metrics(cell, win, meter: CompileMeter, started_wall: float) -> dict:
+    """Every number the host's clock and the span log give, by name; the
+    readers pick theirs."""
+    compile_s = meter.seconds_before(win.t_start)
+    loop_entry_compile = meter.seconds_before(win.t_first_iter)
+    return {
+        "train_img_s_chip": spans_mod.images_per_second_per_chip(
+            win, cell.global_batch, cell.chips),
+        "step_ms_p95": spans_mod.step_ms_p95(win),
+        "setup_s": win.t_start - started_wall,
+        "compile_s": compile_s,
+        # process start to loop entry, less the compile seconds in it
+        "init_s": (win.t_first_iter - started_wall) - loop_entry_compile,
+        "window_s": win.seconds,
+        "window_steps": win.steps,
+        "compiles_in_window": meter.count_between(win.t_start, win.t_end),
+    }
+
+
+def run_cell(cell, seed: int, seconds: float, trace: bool,
+             started_wall: float, tap_factory=StepTap) -> dict:
+    """Everything of one run but the look for a chip; returns the result
+    object (its ``numbers`` come last)."""
+    traffic = cell.traffic
+    meter = CompileMeter()
+    work = tempfile.mkdtemp(prefix="dptpu_bench_")
+    try:
+        template = program_template(cell.config)
+        weights = make_weights(cell, seed)
+        tap = tap_factory(int(traffic["check_steps"]),
+                          int(traffic["warmup_iters"]))
+        result, clock, log = run_fit(cell, seed, seconds, trace, work,
+                                     weights, template, tap)
+        all_spans = spans_mod.read_log(log)
+        win = spans_mod.window(all_spans, int(traffic["warmup_iters"]))
+        host = host_metrics(cell, win, meter, started_wall)
+        held = allocator_peak_bytes()
+        nonfinite = count_nonfinite(result["state"].params)
+        temps = step_temp_bytes(tap) if held else 0
+        device = dict(device_info(), memory_peak_bytes=held + temps)
+        host["allocator_peak_bytes"], host["step_temp_bytes"] = held, temps
+        reduced = None
+        if trace:
+            xplane = tracered.find_xplane(os.path.join(work, "trace"))
+            if xplane is not None:
+                reduced = tracered.reduce_trace(tracered.load_xplane(xplane),
+                                                [w for w, _ in clock.anchors])
+        # the program's state goes before the reference comes: the peak is
+        # read, and float32 at the timed batch wants the room
+        del result
+        jax.clear_caches()
+        regenerated = regenerate_batches(cell, seed)
+        program = program_record(cell, tap, weights, template, nonfinite,
+                                 regenerated)
+        t_ref = time.perf_counter()
+        ref_named = reference_run(cell, weights, regenerated)
+        reference = {
+            "loss": ref_named["loss"],
+            "trace1": to_program_layout(cell.config, template,
+                                        ref_named["trace1"], weights),
+            "delta": to_program_layout(cell.config, template,
+                                       ref_named["delta"], weights),
+        }
+        verdict = check.compare(program, reference, cell.config["limits"])
+        host["reference_s"] = time.perf_counter() - t_ref
+    finally:
+        shutil.rmtree(work, ignore_errors=True)
+    untraced = win
+    if trace and clock.trace_open_wall is not None:
+        untraced = spans_mod.before(win, clock.trace_open_wall)
+    return assemble(cell, trace, host, device, reduced, win, untraced, verdict)
+
+
+def assemble(cell, trace: bool, host: dict, device: dict, reduced, win,
+             untraced, verdict: dict) -> dict:
+    """The result object: ``end_to_end`` metrics untraced, ``per_layer``
+    metrics traced (each from its own reader; one that finds nothing to
+    read is left out). ``untraced`` is the part of the window ``win`` that
+    the profiler did not touch: the readers' spans."""
+    metrics = {}
+    if not trace:
+        for m in cell.end_to_end:
+            metrics[m["name"]] = {"value": host[m["name"]], "unit": m["unit"]}
+    else:
+        context = {"cell": cell, "host": host, "device": device,
+                   "trace": reduced, "window": untraced,
+                   "peaks": cells.peaks(device["kind"])
+                   if device["platform"] == "tpu" else None}
+        for m in cell.per_layer:
+            value = cells.reader(m["name"]).read(context)
+            if value is not None and math.isfinite(value):
+                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    out = {
+        "correct": bool(verdict["correct"]),
+        "attempted": host["window_steps"],
+        "failed": host["window_steps"]
+        if verdict["numbers"]["nonfinite"]["value"] else 0,
+        "metrics": metrics,
+        "device": device,
+    }
+    if trace:
+        device["busy_s"] = reduced["busy_s"] if reduced else 0.0
+        device["window_s"] = reduced["window_s"] if reduced else 0.0
+        if reduced:
+            device["trace_stretch"] = reduced["stretch"]
+            out["breakdown"] = {
+                "device_ops": reduced["top_ops"],
+                "idle_gaps": tracered.idle_gaps(
+                    reduced["busiest"], list(win.spans),
+                    reduced.get("offset_s")),
+            }
+    out["window"] = {k: host[k] for k in (
+        "window_s", "window_steps", "compiles_in_window", "reference_s",
+        "allocator_peak_bytes", "step_temp_bytes")}
+    out["worst_leaf"] = verdict["worst_leaf"]
+    out["numbers"] = verdict["numbers"]
+    return out
+
+
+def print_numbers(numbers: dict, stream=sys.stderr):
+    """Each number compared beside its limit, as the last lines."""
+    for name, n in numbers.items():
+        mark = "ok" if n["value"] <= n["limit"] else "OVER"
+        print(f"compared {name}: value={n['value']!r} limit={n['limit']!r} "
+              f"{mark}", file=stream)
+    stream.flush()
+
+
+def dumps(result: dict) -> str:
+    return json.dumps(result, separators=(", ", ": "))
