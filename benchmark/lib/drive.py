@@ -25,7 +25,9 @@ not state. The harness adds three things around it and edits nothing:
   ``--seconds``, then sends this process SIGTERM. That is the trainer's
   documented preemption path: the loop finishes the step in flight,
   leaves, and fetches the pending metrics (the fence). In a traced run the
-  thread also holds a ``jax.profiler`` trace open over a short stretch.
+  thread also holds a ``jax.profiler`` trace open over the window's last
+  seconds: device planes only, the stretch that is read bounded by two
+  marker programs, every wait cut from one limit (``TailBudget``).
 """
 
 from __future__ import annotations
@@ -54,6 +56,20 @@ from . import cells, check, spans as spans_mod, synthetic, tracered
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 SETUP_LIMIT_S = 1100.0  # a cold first run may compile for many minutes
+# The driver stops a run 360 s after its process started (ledger, PR 25:
+# ``run_timed_out``). Every wait of a traced run is cut from this one
+# number: ``TailBudget``.
+RUN_LIMIT_S = 360.0
+# What a traced run still has to do once it has told the profiler to stop,
+# in a run that compiles its reference (chip runs, PR 25 and PR 26):
+# stop_trace and the reduction (seconds, device planes only), the read-out
+# (5-10 s), the reference (77-94 s where it compiles, 9-30 s warm).
+AFTER_TRACE_S = 120.0
+# stop_trace cannot be interrupted: the join waits this long at least
+STOP_TRACE_FLOOR_S = 30.0
+# The trace stays open this much longer than the stretch that is read, so
+# that the reduction has a second to move the stretch off a stall.
+TRACE_MARGIN_S = 1.0
 
 
 class CompileMeter:
@@ -180,19 +196,85 @@ def _tapped_loop(tap: StepTap):
         fit_module.train_one_epoch = original
 
 
+class TailBudget:
+    """The seconds a traced run may still wait before it has to tell the
+    profiler to stop: ``limit_s`` after the process started, less what
+    the run still has to do after that (``after_s``). Every wait of the
+    clock thread goes through ``wait``, cut by its own cap AND by what is
+    left here; the join on the clock thread waits ``to_limit``. So a
+    profiler that stalls for longer than in any run so far, or a set-up
+    that already used the time up, costs the run device metrics and never
+    its result line. ``clock`` and ``sleep`` are the host's; a test hands
+    in its own."""
+
+    def __init__(self, started_wall: float, limit_s: float = RUN_LIMIT_S,
+                 after_s: float = AFTER_TRACE_S, clock=time.time,
+                 sleep=time.sleep):
+        self.started_wall = started_wall
+        self.limit_s, self.after_s = limit_s, after_s
+        self.clock, self.sleep = clock, sleep
+
+    def left(self) -> float:
+        """Until ``stop_trace`` has to have been called."""
+        return self.to_limit() - self.after_s
+
+    def to_limit(self) -> float:
+        return self.started_wall + self.limit_s - self.clock()
+
+    def wait(self, ready, cap_s: float, poll_s: float = 0.001) -> bool:
+        """Poll ``ready`` until it says yes (True), or ``cap_s`` have
+        passed, or the budget is spent (False): never longer."""
+        deadline = self.clock() + min(cap_s, self.left())
+        while not ready():
+            if self.clock() >= deadline:
+                return False
+            self.sleep(poll_s)
+        return True
+
+
+def trace_options():
+    """Device planes only. At ``host_tracer_level`` 1 the runtime's own
+    threads (``pjrt-tpu-tasks/<tid>``) put a million events a second on
+    the host plane, 98% of the trace, and ``stop_trace`` pays ~29 us for
+    each (PERF.md, Findings, PR 26); the reduction reads none of them."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 0
+    opts.enable_hlo_proto = False
+    return opts
+
+
+def _marker_program(name: str, constant: int):
+    """A program of one scalar operation whose module the trace shows as
+    ``jit_<name>``: the reduction finds it by that name. Each marker adds
+    a ``constant`` of its own, an odd one: the runtime knows a program by
+    what it computes, and two that compute the same show under the name
+    of the first (both markers read ``jit_bench_marker_open`` when both
+    added 1; chip run, PR 26)."""
+    def marker(x):
+        return x + constant
+
+    marker.__name__ = marker.__qualname__ = name
+    return jax.jit(marker)
+
+
 class _Clock(threading.Thread):
-    """Closes the window from outside; traces the last steps of it if
+    """Closes the window from outside; traces the last seconds of it if
     asked."""
 
-    def __init__(self, tap: StepTap, seconds: float, trace_dir: str = None,
-                 trace_skip_s: float = 0.0, trace_read_s: float = 0.0):
+    def __init__(self, tap: StepTap, seconds: float, budget: TailBudget,
+                 trace_dir: str = None, traffic: dict = None):
         super().__init__(name="bench-clock", daemon=True)
-        self.tap, self.seconds = tap, seconds
+        self.tap, self.seconds, self.budget = tap, seconds, budget
         self.trace_dir = trace_dir
-        self.trace_skip_s, self.trace_read_s = trace_skip_s, trace_read_s
+        if trace_dir:
+            self.keep_s = float(traffic["trace_read_s"]) + TRACE_MARGIN_S
+            self.stall_cap_s = float(traffic["trace_stall_cap_s"])
         self.done = threading.Event()
-        self.anchors: List[tuple] = []  # (wall, which)
-        self.trace_open_wall: Optional[float] = None
+        # time.time() of: trace_open (before start_trace), stretch_open
+        # (the open marker seen), stop_begin, stop_end
+        self.marks: Dict[str, float] = {}
+        self.marker_walls: Dict[str, float] = {}  # marker -> seen ready
         self.signalled = False
         self.error: Optional[BaseException] = None
 
@@ -200,74 +282,95 @@ class _Clock(threading.Thread):
         """False if the run ended first."""
         return not self.done.wait(max(t - time.perf_counter(), 0.0))
 
-    def _anchor(self, which: str):
-        wall = time.time()
-        with jax.profiler.TraceAnnotation(tracered.ANCHOR):
-            self.anchors.append((wall, which))
-            time.sleep(0.001)
-
     def _signal(self):
         self.signalled = True
         os.kill(os.getpid(), signal.SIGTERM)
 
-    def _resumed(self, calls_at_start: int, t_started: float) -> bool:
-        """Starting the TPU profiler stalls the next dispatch (one to three
-        seconds) and, once the steps queued before it have drained, the
-        device (about a second). The stretch that is read opens once
-        ``trace_skip_s`` have passed, two more calls of the step have
-        returned AND a marker program enqueued after them has run: the
-        device executes in order, so the stall lies behind it. False if
-        the run ended first."""
-        if not self._sleep_until(t_started + self.trace_skip_s):
+    def _marker_ran(self, name: str, cap_s: float) -> bool:
+        """Enqueue marker ``name`` and watch for its result, ``cap_s`` at
+        most. The wall time at which it is seen ready is the end of its
+        module event in the trace, plus the poll."""
+        out = self._markers[name](self._marker_arg)
+
+        def ready():
+            if out.is_ready():
+                self.marker_walls.setdefault(name, time.time())
+                return True
+            return self.done.is_set()
+
+        self.budget.wait(ready, cap_s, poll_s=0.0002)
+        return name in self.marker_walls
+
+    def _behind_the_stall(self, calls_at_start: int) -> bool:
+        """Starting the TPU profiler stalls the next dispatch and, once
+        the steps queued before it have drained, the device (one to four
+        seconds together). The stretch opens once two more calls of the
+        step have returned AND the open marker, enqueued after them, has
+        run: the device executes in order, so the stall lies behind it.
+        False if that takes longer than ``trace_stall_cap_s`` or than the
+        budget leaves, or the run ended first: the caller then closes
+        the trace at once."""
+        t = self.budget.clock()
+        resumed = self.budget.wait(
+            lambda: self.done.is_set()
+            or self.tap.calls >= calls_at_start + 2, self.stall_cap_s)
+        if not resumed or self.done.is_set():
             return False
-        limit = time.perf_counter() + 30.0
-        while self.tap.calls < calls_at_start + 2:
-            if self.done.wait(0.002) or time.perf_counter() > limit:
-                return False
-        self._marker(self._marker_arg).block_until_ready()
-        return not self.done.is_set()
+        spent = self.budget.clock() - t
+        return self._marker_ran(tracered.MARKER_OPEN,
+                                self.stall_cap_s - spent) \
+            and not self.done.is_set()
 
     def _traced_end(self, t_end: float):
-        """The profiler over the window's last seconds. The two anchors
-        mark the stretch that is read, ``trace_read_s`` long, after the
-        profiler's start-up stall and before ``stop_trace``, which is slow
-        (half a minute per traced second and chip) and runs after the
-        window is closed. A traced window ends when the stretch does."""
-        if not self._sleep_until(
-                t_end - self.trace_skip_s - self.trace_read_s):
+        """The profiler over the window's last seconds: opened
+        ``trace_read_s`` and a margin before the window's end, kept open
+        that long behind the open marker (or as long as the budget
+        leaves), closed behind the close marker. The reduction takes the last clean
+        ``trace_read_s`` between the two. A traced window ends when the
+        trace does; ``stop_trace`` runs after the window is closed."""
+        if not self._sleep_until(t_end - self.keep_s):
             return
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0  # no per-call Python events
-        opts.host_tracer_level = 1    # annotations only
-        opts.enable_hlo_proto = False
-        self.trace_open_wall = time.time()
+        self.marks["trace_open"] = time.time()
         calls = self.tap.calls
-        t = time.perf_counter()
-        jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
-        t_started = time.perf_counter()
+        jax.profiler.start_trace(self.trace_dir,
+                                 profiler_options=trace_options())
+        started = time.time()
         try:
-            if self._resumed(calls, t_started):
-                self._anchor("open")
-                self._sleep_until(time.perf_counter() + self.trace_read_s)
-                self._anchor("close")
+            if self._behind_the_stall(calls):
+                self.marks["stretch_open"] = \
+                    self.marker_walls[tracered.MARKER_OPEN]
+                self.done.wait(max(min(self.keep_s, self.budget.left()), 0.0))
+                if not self.done.is_set():
+                    self._marker_ran(tracered.MARKER_CLOSE, self.stall_cap_s)
             if not self.done.is_set():
                 self._signal()  # the window closes; the trace is written after
         finally:
-            t_stop = time.perf_counter()
+            self.marks["stop_begin"] = time.time()
             jax.profiler.stop_trace()
-        opened = (f"{self.anchors[0][0] - self.trace_open_wall:.2f} s after "
-                  f"it" if self.anchors else "never")
-        print(f"benchmark: start_trace {t_started - t:.2f} s, stretch opened "
-              f"{opened}, stop_trace {time.perf_counter() - t_stop:.2f} s",
-              file=sys.stderr)
+            self.marks["stop_end"] = time.time()
+        m = self.marks
+        if "stretch_open" in m:
+            opened = (f"opened {m['stretch_open'] - started:.2f} s after it, "
+                      f"kept {m['stop_begin'] - m['stretch_open']:.2f} s")
+        else:
+            opened = (f"never opened (no marker within "
+                      f"{min(self.stall_cap_s, m['stop_begin'] - started):.1f}"
+                      f" s)")
+        print(f"benchmark: start_trace {started - m['trace_open']:.2f} s, "
+              f"stretch {opened}, stop_trace "
+              f"{m['stop_end'] - m['stop_begin']:.2f} s", file=sys.stderr)
 
     def run(self):
         try:
             if self.trace_dir:
-                # compiled and placed during set-up, never in the window
-                self._marker = jax.jit(lambda x: x + 1)
+                # compiled and run once during set-up, never in the window
+                self._markers = {
+                    name: _marker_program(name, 26001 + 2 * k)
+                    for k, name in enumerate((tracered.MARKER_OPEN,
+                                              tracered.MARKER_CLOSE))}
                 self._marker_arg = jnp.zeros((), jnp.int32)
-                self._marker(self._marker_arg).block_until_ready()
+                for marker in self._markers.values():
+                    marker(self._marker_arg).block_until_ready()
             if not self.tap.warm.wait(SETUP_LIMIT_S) or self.done.is_set():
                 return
             t_end = time.perf_counter() + self.seconds
@@ -339,20 +442,16 @@ def fit_argv(cell, n_images: int) -> List[str]:
     return argv + [str(a) for a in traffic.get("extra_argv", [])]
 
 
-def run_fit(cell, seed: int, seconds: float, trace: bool, work: str,
-            weights: Dict[str, np.ndarray], template, tap: StepTap):
+def run_fit(cell, seed: int, work: str, weights: Dict[str, np.ndarray],
+            template, tap: StepTap, clock: _Clock):
     """One ``main_apex`` call with the tap and the clock; returns
-    ``(result, clock, obs_log_path)``."""
+    ``(result, obs_log_path)``."""
     from dptpu.cli import main_apex
 
     obs_dir = os.path.join(work, "obs")
-    trace_dir = os.path.join(work, "trace") if trace else None
     write_pretrained(cell.config, template, weights,
                      os.path.join(work, "pretrained"))
-    traffic = cell.traffic
-    clock = _Clock(tap, seconds, trace_dir, float(traffic["trace_skip_s"]),
-                   float(traffic["trace_read_s"]))
-    argv = fit_argv(cell, dataset_images(traffic, seed))
+    argv = fit_argv(cell, dataset_images(cell.traffic, seed))
     cwd = os.getcwd()
     os.chdir(work)  # checkpoints and runs/ land in the scratch directory
     clock.start()
@@ -363,10 +462,13 @@ def run_fit(cell, seed: int, seconds: float, trace: bool, work: str,
             result = main_apex(argv)
     finally:
         clock.done.set()
-        clock.join(timeout=300.0)  # a traced run is still writing its trace
+        # a traced run is still writing its trace: to the run's limit, no
+        # longer (stop_trace cannot be cut short; it gets its floor)
+        clock.join(timeout=max(clock.budget.to_limit(), STOP_TRACE_FLOOR_S))
         os.chdir(cwd)
     if clock.is_alive():
-        raise RuntimeError("the clock thread did not end")
+        raise RuntimeError("the clock thread did not end: stop_trace "
+                           "outlasted the run's limit")
     if clock.error is not None:
         raise RuntimeError(f"the clock thread failed: {clock.error!r}")
     if not clock.signalled or not result.get("preempted"):
@@ -375,8 +477,7 @@ def run_fit(cell, seed: int, seconds: float, trace: bool, work: str,
             f"(signalled={clock.signalled}, "
             f"preempted={result.get('preempted')}): the epoch is shorter "
             f"than the window, or set-up outlasted {SETUP_LIMIT_S:.0f} s")
-    log = os.path.join(obs_dir, f"obs-{socket.gethostname()}.jsonl")
-    return result, clock, log
+    return result, os.path.join(obs_dir, f"obs-{socket.gethostname()}.jsonl")
 
 
 def reference_run(cell, weights, batches, mode: str = "f32") -> dict:
@@ -480,6 +581,49 @@ def host_metrics(cell, win, meter: CompileMeter, started_wall: float) -> dict:
     }
 
 
+def read_trace(work: str, clock: _Clock, read_s: float, max_gap_s: float,
+               host: dict):
+    """The reduced trace of a traced run (None where the profiler wrote
+    nothing to read), with what the trace cost into ``host``."""
+    xplane = tracered.find_xplane(os.path.join(work, "trace"))
+    if xplane is None:
+        return None
+    host["xplane_bytes"] = os.path.getsize(xplane)
+    reduced = tracered.reduce_trace(tracered.load_xplane(xplane),
+                                    clock.marker_walls, read_s, max_gap_s)
+    if reduced:
+        host["trace_events"] = reduced["events_read"]
+        host["trace_modules"] = reduced["modules"]
+    return reduced
+
+
+def phases(started_wall: float, win, clock: _Clock, t_reference: float,
+           host: dict) -> dict:
+    """Where the run's seconds went, in order, without overlap: they add
+    up to ``total`` (process start to the result) but for the comparison
+    and the printing. ``window`` is the untraced part of a traced run's
+    window; ``stop_trace`` runs beside the fence and ``fit()``'s return;
+    ``readout`` is the rest between the window (or ``stop_trace``) and
+    the reference: ``fit()``'s return, span log, memory, the count of
+    non-finite leaves, the regenerated rows."""
+    m = clock.marks
+    out = {"setup": win.t_start - started_wall}
+    if "trace_open" in m:
+        opened = m.get("stretch_open", m["stop_begin"])
+        out.update(window=m["trace_open"] - win.t_start,
+                   trace_open_to_stretch=opened - m["trace_open"],
+                   traced=m["stop_begin"] - opened,
+                   stop_trace=m["stop_end"] - m["stop_begin"],
+                   load_reduce=host.get("load_reduce_s", 0.0))
+        after = m["stop_end"] + out["load_reduce"]
+    else:
+        out["window"] = win.t_end - win.t_start
+        after = win.t_end
+    out.update(readout=t_reference - after, reference=host["reference_s"],
+               total=time.time() - started_wall)
+    return out
+
+
 def run_cell(cell, seed: int, seconds: float, trace: bool,
              started_wall: float, tap_factory=StepTap) -> dict:
     """Everything of one run but the look for a chip; returns the result
@@ -492,10 +636,15 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
         weights = make_weights(cell, seed)
         tap = tap_factory(int(traffic["check_steps"]),
                           int(traffic["warmup_iters"]))
-        result, clock, log = run_fit(cell, seed, seconds, trace, work,
-                                     weights, template, tap)
+        clock = _Clock(tap, seconds, TailBudget(started_wall),
+                       os.path.join(work, "trace") if trace else None,
+                       traffic)
+        result, log = run_fit(cell, seed, work, weights, template, tap, clock)
         all_spans = spans_mod.read_log(log)
         win = spans_mod.window(all_spans, int(traffic["warmup_iters"]))
+        untraced = win
+        if "trace_open" in clock.marks:
+            untraced = spans_mod.before(win, clock.marks["trace_open"])
         host = host_metrics(cell, win, meter, started_wall)
         held = allocator_peak_bytes()
         nonfinite = count_nonfinite(result["state"].params)
@@ -504,10 +653,14 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
         host["allocator_peak_bytes"], host["step_temp_bytes"] = held, temps
         reduced = None
         if trace:
-            xplane = tracered.find_xplane(os.path.join(work, "trace"))
-            if xplane is not None:
-                reduced = tracered.reduce_trace(tracered.load_xplane(xplane),
-                                                [w for w, _ in clock.anchors])
+            t_load = time.perf_counter()
+            # a stall: a device idle for longer than two ordinary
+            # iterations of the untraced window at a time
+            iter_p50_s = spans_mod.percentile(
+                [s["dur_s"] for s in untraced.iters], 50.0)
+            reduced = read_trace(work, clock, float(traffic["trace_read_s"]),
+                                 2.0 * iter_p50_s, host)
+            host["load_reduce_s"] = time.perf_counter() - t_load
         # the program's state goes before the reference comes: the peak is
         # read, and float32 at the timed batch wants the room
         del result
@@ -515,7 +668,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
         regenerated = regenerate_batches(cell, seed)
         program = program_record(cell, tap, weights, template, nonfinite,
                                  regenerated)
-        t_ref = time.perf_counter()
+        t_reference = time.time()
         ref_named = reference_run(cell, weights, regenerated)
         reference = {
             "loss": ref_named["loss"],
@@ -525,12 +678,10 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
                                        ref_named["delta"], weights),
         }
         verdict = check.compare(program, reference, cell.config["limits"])
-        host["reference_s"] = time.perf_counter() - t_ref
+        host["reference_s"] = time.time() - t_reference
     finally:
         shutil.rmtree(work, ignore_errors=True)
-    untraced = win
-    if trace and clock.trace_open_wall is not None:
-        untraced = spans_mod.before(win, clock.trace_open_wall)
+    host["phases_s"] = phases(started_wall, win, clock, t_reference, host)
     return assemble(cell, trace, host, device, reduced, win, untraced, verdict)
 
 
@@ -574,7 +725,8 @@ def assemble(cell, trace: bool, host: dict, device: dict, reduced, win,
             }
     out["window"] = {k: host[k] for k in (
         "window_s", "window_steps", "compiles_in_window", "reference_s",
-        "allocator_peak_bytes", "step_temp_bytes")}
+        "allocator_peak_bytes", "step_temp_bytes", "xplane_bytes",
+        "trace_events", "trace_modules", "phases_s") if k in host}
     out["worst_leaf"] = verdict["worst_leaf"]
     out["numbers"] = verdict["numbers"]
     return out

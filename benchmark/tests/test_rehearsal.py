@@ -31,8 +31,8 @@ def tiny_cell():
     with open(os.path.join(cells.BENCH_DIR, "traffic",
                            "fit-synthetic.json")) as f:
         traffic = json.load(f)
-    traffic.update(dataset_images=2000, warmup_iters=5, trace_skip_s=0.2,
-                   trace_read_s=0.5)
+    traffic.update(dataset_images=2000, warmup_iters=5, trace_read_s=0.5,
+                   trace_stall_cap_s=4.0)
     reported = lambda m: name in m.get("workloads", [name])  # noqa: E731
     return cells.Cell(
         name=name, chips=1, config=config, traffic=traffic,
@@ -65,6 +65,15 @@ def test_a_run_prints_the_contracts_last_line(cell, trace):
     for name, metric in line["metrics"].items():
         assert metric["unit"] == units[name]
         assert isinstance(metric["value"], float)
+    # where the run's seconds went: the phases follow each other without
+    # overlap and leave out only the comparison and the printing
+    phases = dict(line["window"]["phases_s"])
+    total = phases.pop("total")
+    traced = {"trace_open_to_stretch", "traced", "stop_trace", "load_reduce"}
+    assert set(phases) == {"setup", "window", "readout", "reference"} | (
+        traced if trace else set())
+    assert all(v >= 0 for v in phases.values()), phases
+    assert sum(phases.values()) == pytest.approx(total, abs=1.0)
     if trace:
         assert {"busy_s", "window_s"} <= set(line["device"])
         # the device metrics find nothing to read on a CPU and are left
