@@ -58,6 +58,7 @@ imagenet_ddp_apex.py:26-39,304-351), rebuilt for the TPU host model:
 
 from __future__ import annotations
 
+import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from typing import Iterator, Optional
@@ -201,14 +202,20 @@ class DataLoader:
         rng = np.random.default_rng([self.seed, epoch, index])
         return self._get(index, rng)
 
-    def _load_span(self, idxs, epoch, imgs, labels, offset, skip=()):
+    def _load_span(self, idxs, epoch, imgs, labels, offset, skip=(),
+                   timed=False):
         """Decode a span of samples directly into rows
         ``offset..offset+len(idxs)`` of the shared batch arrays — the
         per-worker unit of a chunked submission (disjoint rows, so
         concurrent spans never race). ``skip`` rows were already filled
-        by the caller (the shape probe's reused decode)."""
+        by the caller (the shape probe's reused decode). ``timed``
+        (tracing on) returns this worker's own ``(cpu_s, wall_s)`` for
+        the span through the future: the ``collect`` span sums them, the
+        worker records no span of its own."""
         from dptpu.data.dataset import _copy_checked
 
+        if timed:
+            c0, t0 = time.thread_time(), time.perf_counter()
         get_into = self._get_into
         for j, index in enumerate(idxs):
             index = int(index)
@@ -221,6 +228,9 @@ class DataLoader:
                 img, label = self._load_one(index, epoch)
                 _copy_checked(imgs[offset + j], img, index)
                 labels[offset + j] = label
+        if timed:
+            return time.thread_time() - c0, time.perf_counter() - t0
+        return None
 
     def _submit_batch(self, batch_indices, epoch):
         """Preallocate one batch and fan its samples out as ONE future
@@ -250,21 +260,35 @@ class DataLoader:
                     skip = (j,)
                     break
         span = -(-n_valid // self.num_workers)
+        timed = obs.get_tracer().enabled
         futs = [
             self._pool.submit(
                 self._load_span, batch_indices[o:o + span], epoch,
-                imgs, labels, o, skip,
+                imgs, labels, o, skip, timed,
             )
             for o in range(0, n_valid, span)
         ]
         return futs, imgs, labels, n_valid
 
-    def _finalize(self, futs, imgs, labels, n_valid, valid=None):
+    def _finalize(self, futs, imgs, labels, n_valid, valid=None, step=-1):
         # the parent-blocked-on-decode moment, thread edition (the
-        # process path's equivalent wait is spanned around collect)
-        with obs.get_tracer().span("collect"):
+        # process path's equivalent wait is spanned around collect).
+        # ``step`` is the batch's index in the epoch: the step that will
+        # consume it. The span says whether the workers were done when
+        # the loop came for the batch, and what the batch cost them.
+        tracer = obs.get_tracer()
+        if not tracer.enabled:
             for f in futs:
                 f.result()  # wait + propagate decode errors
+            return self._assemble(imgs, labels, n_valid, valid)
+        attrs = {"ready": all(f.done() for f in futs), "rows": n_valid}
+        t0 = time.perf_counter()
+        spent = [f.result() for f in futs]
+        if all(r is not None for r in spent):
+            attrs["cpu_s"] = sum(r[0] for r in spent)
+            attrs["wall_s"] = sum(r[1] for r in spent)
+        tracer.record("collect", t0, time.perf_counter() - t0, step=step,
+                      attrs=attrs)
         return self._assemble(imgs, labels, n_valid, valid)
 
     def _assemble(self, imgs, labels, n_valid, valid=None):
@@ -327,13 +351,15 @@ class DataLoader:
 
         ahead = 1 + max(0, prefetch_batches)
         if self.workers_mode == "process":
-            yield from self._epoch_process(chunks, epoch, ahead)
+            yield from self._epoch_process(chunks, epoch, ahead,
+                                           start_batch)
             return
-        yield from self._epoch_thread(chunks, epoch, ahead)
+        yield from self._epoch_thread(chunks, epoch, ahead, start_batch)
 
-    def _epoch_thread(self, chunks, epoch, ahead):
+    def _epoch_thread(self, chunks, epoch, ahead, first=0):
         """Thread-pool epoch over an explicit chunk list (also the landing
-        path when a broken process pool degrades mid-epoch)."""
+        path when a broken process pool degrades mid-epoch). ``first`` is
+        the epoch index of ``chunks[0]`` (the label of its spans)."""
         nb = len(chunks)
         pending = deque()
         for chunk, _ in chunks[:ahead]:
@@ -344,9 +370,9 @@ class DataLoader:
             if next_idx < nb:
                 pending.append(self._submit_batch(chunks[next_idx][0], epoch))
                 next_idx += 1
-            yield self._finalize(*item, valid=chunks[b][1])
+            yield self._finalize(*item, valid=chunks[b][1], step=first + b)
 
-    def _epoch_process(self, chunks, epoch, ahead):
+    def _epoch_process(self, chunks, epoch, ahead, first=0):
         """Process-mode epoch: drive the shared-memory slot ring
         (dptpu/data/shm.py) as a DECODE-AHEAD pipeline — a pump keeps up
         to ``issue window`` batches' spans pre-issued into the per-worker
@@ -418,9 +444,18 @@ class DataLoader:
                 # the parent-blocked-on-spans moment (the ring's own
                 # io_wait_s counter measures the same wait cumulatively;
                 # the span places each wait on the step timeline)
-                with obs.get_tracer().span("collect"):
-                    imgs, labels, lease = pipe.collect(
-                        slot, out_size, leased=self.leased
+                tracer = obs.get_tracer()
+                t_collect = time.perf_counter()
+                imgs, labels, lease = pipe.collect(
+                    slot, out_size, leased=self.leased
+                )
+                if tracer.enabled:
+                    # no CPU seconds here: the worker's ack carries its
+                    # wall time only, and no IPC field is added for it
+                    tracer.record(
+                        "collect", t_collect,
+                        time.perf_counter() - t_collect, step=first + b,
+                        attrs={"rows": n_valid, **pipe.last_collect},
                     )
                 batch = self._assemble(imgs, labels, n_valid,
                                        valid=chunks[b][1])
@@ -440,7 +475,8 @@ class DataLoader:
             # batch b was never yielded; re-decode from it on threads
             # (pre-issued batches beyond b die with the pool — the
             # thread path re-earns them)
-            yield from self._epoch_thread(chunks[b:], epoch, ahead)
+            yield from self._epoch_thread(chunks[b:], epoch, ahead,
+                                          first + b)
 
     def _retire_pipeline(self, forgive_leases: bool = False):
         """Close the pipeline, folding its supervision counters into the
@@ -729,10 +765,14 @@ class DevicePrefetcher:
     """
 
     def __init__(self, batches: Iterator[dict], put=jax.device_put,
-                 copy_before_put: Optional[bool] = None):
+                 copy_before_put: Optional[bool] = None,
+                 first_step: int = 0):
         self._it = iter(batches)
         self._put = put
         self._copy = copy_before_put
+        # epoch index of the next batch: its ``h2d`` span carries the
+        # step that will consume it (``first_step`` = the resume point)
+        self._step = first_step
         self._next = self._advance()
 
     def _advance(self):
@@ -741,27 +781,34 @@ class DevicePrefetcher:
             batch = next(self._it)
         except StopIteration:
             return None
+        step = self._step
+        self._step += 1
         lease = batch.pop("_lease", None)
+        t0 = time.perf_counter()
         if lease is None:
-            with tracer.span("h2d"):
-                return self._put(batch)
-        if self._copy is None:
-            # CPU PJRT zero-copies suitably-shaped numpy buffers — the
-            # device array then aliases the ring slot for its lifetime
-            self._copy = jax.default_backend() == "cpu"
-        if self._copy:
-            with tracer.span("h2d"):
+            out = self._put(batch)
+        else:
+            if self._copy is None:
+                # CPU PJRT zero-copies suitably-shaped numpy buffers — the
+                # device array then aliases the ring slot for its lifetime
+                self._copy = jax.default_backend() == "cpu"
+            if self._copy:
                 batch = {k: np.array(v) for k, v in batch.items()}  # dptpu: allow-host-sync(the documented CPU-backend defense: device_put zero-copy-aliases host buffers there, so recycling the slot would corrupt the in-flight batch — copy once, host to host)
                 out = self._put(batch)
+            else:
+                out = self._put(batch)
+                # the H2D read must finish before the slot may be
+                # overwritten; this wait overlaps the previous step's
+                # device compute
+                jax.block_until_ready(out)  # dptpu: allow-host-sync(H2D completion gate before the leased slot may be recycled; the wait overlaps the PREVIOUS step's device compute)
+        if tracer.enabled:
+            tracer.record(
+                "h2d", t0, time.perf_counter() - t0, step=step,
+                attrs={"bytes": sum(getattr(v, "nbytes", 0)
+                                    for v in batch.values())},
+            )
+        if lease is not None:
             lease.release()
-            return out
-        with tracer.span("h2d"):
-            out = self._put(batch)
-            # the H2D read must finish before the slot may be
-            # overwritten; this wait overlaps the previous step's device
-            # compute
-            jax.block_until_ready(out)  # dptpu: allow-host-sync(H2D completion gate before the leased slot may be recycled; the wait overlaps the PREVIOUS step's device compute)
-        lease.release()
         return out
 
     def __iter__(self):

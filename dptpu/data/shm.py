@@ -420,6 +420,10 @@ class ShmBatchPipeline:
             (self.slots, batch_size), np.int32, buffer=self._shm_labels.buf
         )
         self._outstanding = [0] * self.slots  # span acks still in flight
+        # workers' own decode seconds of each slot's batch, from the acks
+        self._slot_wall_s = [0.0] * self.slots
+        # what the last collect() found (the loader's collect span)
+        self.last_collect = {"ready": True, "wall_s": 0.0}
         self._pending = {s: {} for s in range(self.slots)}  # task_id -> task
         self._retries = {}  # (slot, task_id) -> attempts so far
         self._free = list(range(self.slots))
@@ -574,6 +578,7 @@ class ShmBatchPipeline:
             self._task_qs[wid].put(task[:5])
             self._worker_load[wid] += 1
         self._outstanding[slot] = len(self._pending[slot])
+        self._slot_wall_s[slot] = 0.0
         if self._readahead:
             self._issue_readahead(batch_indices)
         return slot, len(batch_indices)
@@ -619,6 +624,15 @@ class ShmBatchPipeline:
         lasted ``speculate_after_s``, the remaining spans of THIS slot
         are re-issued to idle workers (straggler speculation)."""
         t0 = time.monotonic()
+        # acks that are already here count as done: the batch was ready
+        # iff nothing is left to wait for once they are read
+        while self._outstanding[slot] > 0:
+            try:
+                msg = self._res_q.get_nowait()
+            except _queue.Empty:
+                break
+            self._handle(msg, mode="normal")
+        ready = self._outstanding[slot] <= 0
 
         def _tick():
             # re-checked every poll (a no-op pass is a few comparisons):
@@ -632,6 +646,8 @@ class ShmBatchPipeline:
         while self._outstanding[slot] > 0:
             self._handle(self._next_result(tick=_tick), mode="normal")
         self._io_wait_s += time.monotonic() - t0
+        self.last_collect = {"ready": ready,
+                             "wall_s": self._slot_wall_s[slot]}
         self._occ_sum += self.slots - len(self._free)
         self._occ_n += 1
         self._collects += 1
@@ -970,6 +986,8 @@ class ShmBatchPipeline:
                 self._ghost_ack(slot)
                 return
             self._outstanding[slot] -= 1
+            if len(msg) > 6:
+                self._slot_wall_s[slot] += float(msg[6])
             self._retries.pop((slot, task_id), None)
             return
         # kind == "error"

@@ -60,7 +60,9 @@ from dptpu.obs.report import (
     attribute_spans,
     exclusive_durations,
     format_report,
+    format_setup,
     merge_pod_timeline,
+    setup_report,
 )
 from dptpu.obs.trace import (
     NullTracer,
@@ -77,6 +79,7 @@ __all__ = [
     "ProfileTrigger",
     "attribute_epoch", "attribute_spans", "exclusive_durations",
     "format_report", "SPAN_CATEGORY", "P2Quantile", "merge_pod_timeline",
+    "setup_report", "format_setup",
     "get_tracer", "set_tracer", "get_registry", "set_registry",
     "reset", "obs_knobs",
 ]

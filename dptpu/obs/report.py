@@ -14,6 +14,10 @@ and input-starvation diagnosis — Mikami et al. 1811.05233, Ying et al.
   replace);
 * ``ckpt`` — checkpoint submits/flushes on the step thread (async
   writer time off-thread is reported separately, it overlaps compute);
+* ``compile`` — tracing, lowering and backend compiles (a cache load
+  counts) that jax reported while the epoch ran
+  (dptpu/utils/compile_cache.py): a compile inside a ``step`` call is
+  taken out of ``device``;
 * ``other`` — the residual against epoch wall time (loop bookkeeping,
   pipeline construction). A healthy tracer keeps coverage >= 95%.
 
@@ -24,6 +28,13 @@ double-counting. Per-step totals come from the loop's ``iter`` spans:
 p50/p90/max step time plus an anomalous-step log (steps slower than
 ``anomaly_x`` × p50, with their own phase breakdown) — the "why is step
 41k slow" first answer without a profiler session.
+
+The spans' attributes (``attrs``, measured where the work happens) give
+the report's third line, ``step_call`` and ``feed``: how long the
+dispatch call took and how much of that the loop thread was on the CPU,
+how many earlier steps were still in flight and whether the batch had
+landed when it was made; whether the feed's workers were done when the
+loop came for a batch, and the CPU and wall time a row cost them.
 """
 
 from __future__ import annotations
@@ -41,15 +52,15 @@ from dptpu.obs.metrics import _quantile
 SPAN_CATEGORY = {
     "data_wait": "data_wait",
     "collect": "data_wait",
-    "lease_wait": "data_wait",
     "h2d": "h2d",
     "step": "device",
     "fetch": "device",
     "eval_step": "device",
     "ckpt": "ckpt",
     "ckpt_flush": "ckpt",
+    "compile": "compile",
 }
-CATEGORIES = ("data_wait", "h2d", "device", "ckpt")
+CATEGORIES = ("data_wait", "h2d", "device", "ckpt", "compile")
 # spans that run on helper threads by design and therefore OVERLAP the
 # step timeline: reported separately, never part of the wall budget
 ASYNC_SPANS = ("ckpt_write",)
@@ -104,6 +115,47 @@ def attribute_spans(spans: List[dict]) -> Dict[str, float]:
     return sums
 
 
+def _mean(values) -> float:
+    values = list(values)
+    return sum(values) / len(values) if values else 0.0
+
+
+def summarize_attrs(spans: List[dict]) -> dict:
+    """The loop's and the feed's own account of an epoch, from the
+    attributes on their spans; a key is absent where no span carried
+    its attribute (tracing written by an older run, process-mode feed)."""
+    out: Dict[str, dict] = {}
+    calls = [s for s in spans
+             if s["name"] == "step" and "cpu_s" in s.get("attrs", ())]
+    if calls:
+        durs = sorted(s["dur_s"] for s in calls)
+        cpu = _mean(s["attrs"]["cpu_s"] for s in calls)
+        out["step_call"] = {
+            "p50_ms": round(_quantile(durs, 0.50) * 1e3, 3),
+            "cpu_ms": round(cpu * 1e3, 3),
+            "blocked_ms": round((_mean(durs) - cpu) * 1e3, 3),
+            "inflight_p50": _quantile(
+                sorted(s["attrs"]["inflight"] for s in calls), 0.50),
+            "input_ready_pct": round(100.0 * _mean(
+                s["attrs"]["input_ready"] for s in calls), 1),
+        }
+    collects = [s for s in spans
+                if s["name"] == "collect" and "ready" in s.get("attrs", ())]
+    if collects:
+        feed = {"ready_pct": round(100.0 * _mean(
+            s["attrs"]["ready"] for s in collects), 1)}
+        for key, per_row in (("cpu_s", "row_cpu_us"),
+                             ("wall_s", "row_wall_us")):
+            timed = [s["attrs"] for s in collects
+                     if key in s["attrs"] and s["attrs"].get("rows")]
+            rows = sum(a["rows"] for a in timed)
+            if rows:
+                feed[per_row] = round(
+                    1e6 * sum(a[key] for a in timed) / rows, 2)
+        out["feed"] = feed
+    return out
+
+
 def attribute_epoch(spans: List[dict], wall_s: float,
                     anomaly_x: float = 3.0,
                     max_anomalies: int = 10) -> dict:
@@ -131,14 +183,39 @@ def attribute_epoch(spans: List[dict], wall_s: float,
             if s["step"] >= 0:
                 d = by_step.setdefault(s["step"], {})
                 d[cat] = d.get(cat, 0.0) + excl
+        step_attrs = {s["step"]: s["attrs"] for s in spans
+                      if s["name"] == "step" and "attrs" in s}
+        # backend compiles only (a cache load counts): the tracing and
+        # lowering events around each would drown the line
+        compiles = [s for s in spans if s["name"] == "compile"
+                    and s.get("attrs", {}).get("event") == "backend_compile"]
         for s in slow:
-            anomalies.append({
+            a = {
                 "step": s["step"],
                 "dur_s": round(s["dur_s"], 4),
                 "x_p50": round(s["dur_s"] / p50, 2),
                 "phases": {k: round(v, 4)
                            for k, v in by_step.get(s["step"], {}).items()},
-            })
+            }
+            # what the loop saw when it dispatched this step, and any
+            # compile that ran inside the iteration (on any thread)
+            if s["step"] in step_attrs:
+                a["step_call"] = {
+                    k: round(v, 4) if isinstance(v, float) else v
+                    for k, v in step_attrs[s["step"]].items()}
+            inside = sorted(
+                (c for c in compiles
+                 if s["t0"] <= c["t0"] + c["dur_s"]
+                 and c["t0"] <= s["t0"] + s["dur_s"]),
+                key=lambda c: -c["dur_s"])
+            if inside:
+                a["compiles"] = {
+                    "count": len(inside),
+                    "longest": [{"dur_s": round(c["dur_s"], 4),
+                                 **c.get("attrs", {})}
+                                for c in inside[:3]],
+                }
+            anomalies.append(a)
     async_ckpt = sum(
         s["dur_s"] for s in spans if s["name"] in ASYNC_SPANS
     )
@@ -148,6 +225,7 @@ def attribute_epoch(spans: List[dict], wall_s: float,
         "h2d_s": round(sums["h2d"], 4),
         "device_s": round(sums["device"], 4),
         "ckpt_s": round(sums["ckpt"], 4),
+        "compile_s": round(sums["compile"], 4),
         "other_s": round(other, 4),
         "coverage": round(accounted / wall_s, 4) if wall_s > 0 else 0.0,
         "ckpt_async_s": round(async_ckpt, 4),  # overlapped, not in budget
@@ -157,7 +235,61 @@ def attribute_epoch(spans: List[dict], wall_s: float,
         "step_max_s": round(durs[-1] if durs else 0.0, 4),
         "anomalous_steps": anomalies,
         "span_count": len(spans),
+        **summarize_attrs(spans),
     }
+
+
+def setup_report(spans: List[dict]) -> dict:
+    """``fit()``'s set-up, from its ``setup.*`` spans (consecutive, from
+    its first line to loop entry; a phase entered twice is one row) and
+    the ``compile`` spans inside them: per phase its seconds, the backend compiles in it (a cache load
+    counts) and the compile seconds (tracing and lowering included,
+    nested events counted once), and how many of those compiles took
+    under a second, the ones jax's persistent cache does not store."""
+    compiles = exclusive_durations(
+        [s for s in spans if s["name"] == "compile"])
+    phases: Dict[str, dict] = {}  # by name, in order of first entry
+    for p in sorted((s for s in spans if s["name"].startswith("setup.")),
+                    key=lambda s: s["t0"]):
+        lo, hi = p["t0"], p["t0"] + p["dur_s"]
+        inside = [(c, excl) for c, excl in compiles
+                  if lo <= c["t0"] + c["dur_s"] / 2.0 < hi]
+        backend = [c for c, _ in inside
+                   if c.get("attrs", {}).get("event") == "backend_compile"]
+        small = [c for c in backend if c["dur_s"] < 1.0]
+        name = p["name"][len("setup."):]
+        row = phases.setdefault(name, dict.fromkeys(
+            ("s", "compile_s", "compiles", "compiles_under_1s",
+             "compiles_under_1s_s", "cache_hits"), 0))
+        row["s"] += p["dur_s"]
+        row["compile_s"] += sum(excl for _, excl in inside)
+        row["compiles"] += len(backend)
+        row["compiles_under_1s"] += len(small)
+        row["compiles_under_1s_s"] += sum(c["dur_s"] for c in small)
+        row["cache_hits"] += sum(
+            1 for c in backend if c["attrs"].get("cache_hit"))
+    rows = [{"phase": name,
+             **{k: round(v, 3) if isinstance(v, float) else v
+                for k, v in row.items()}}
+            for name, row in phases.items()]
+    return {"total_s": round(sum(r["s"] for r in rows), 3), "phases": rows}
+
+
+def format_setup(report: dict) -> str:
+    """The one ``=> set-up:`` line ``fit()`` prints at loop entry."""
+    parts = []
+    for p in report["phases"]:
+        text = f"{p['phase']} {p['s']:.1f}s"
+        if p["compiles"] or p["compile_s"] >= 0.05:
+            text += (f" (compile {p['compile_s']:.1f}s in {p['compiles']}"
+                     + (f", {p['cache_hits']} from the cache"
+                        if p["cache_hits"] else "")
+                     + (f", {p['compiles_under_1s']} under 1s: "
+                        f"{p['compiles_under_1s_s']:.1f}s"
+                        if p["compiles_under_1s"] else "") + ")")
+        parts.append(text)
+    return (f"=> set-up: {report['total_s']:.1f}s to loop entry | "
+            + " | ".join(parts))
 
 
 class P2Quantile:
@@ -388,7 +520,8 @@ def format_report(report: dict, epoch: Optional[int] = None) -> str:
             f"{k[:-2]} {report[k]:.2f}s "
             f"({100.0 * report[k] / wall:.1f}%)"
             for k in ("data_wait_s", "h2d_s", "device_s", "ckpt_s",
-                      "other_s")
+                      "compile_s", "other_s")
+            if k != "compile_s" or report.get(k)
         )
         + f" | coverage {100.0 * report['coverage']:.1f}%"
     ]
@@ -400,8 +533,36 @@ def format_report(report: dict, epoch: Optional[int] = None) -> str:
         + (f" | async ckpt {report['ckpt_async_s']:.2f}s overlapped"
            if report["ckpt_async_s"] else "")
     )
+    calls, feed = report.get("step_call"), report.get("feed")
+    if calls or feed:
+        bits = []
+        if calls:
+            bits.append(
+                f"step call p50 {calls['p50_ms']:.1f}ms (mean: cpu "
+                f"{calls['cpu_ms']:.1f}ms + blocked "
+                f"{calls['blocked_ms']:.1f}ms), in flight p50 "
+                f"{calls['inflight_p50']:g}, input ready "
+                f"{calls['input_ready_pct']:.0f}%"
+            )
+        if feed:
+            bits.append(
+                f"feed ready {feed['ready_pct']:.0f}%"
+                + "".join(f", {what} {feed[k]:.0f}us/row"
+                          for k, what in (("row_cpu_us", "cpu"),
+                                          ("row_wall_us", "wall"))
+                          if k in feed)
+            )
+        parts.append("   " + " | ".join(bits))
     for a in report["anomalous_steps"]:
         phases = " ".join(f"{k}={v:.3f}s" for k, v in a["phases"].items())
+        call = a.get("step_call")
+        if call:
+            phases += " | " + " ".join(f"{k}={v}" for k, v in call.items())
+        if "compiles" in a:
+            phases += (
+                f" | {a['compiles']['count']} compile(s), longest: "
+                + ", ".join(f"{c.get('fun', '?')} {c['dur_s']:.3f}s"
+                            for c in a["compiles"]["longest"]))
         parts.append(
             f"   anomalous step {a['step']}: {a['dur_s']:.3f}s "
             f"({a['x_p50']}x p50) {phases}"

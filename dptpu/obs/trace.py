@@ -77,7 +77,11 @@ class NullTracer:
     def span(self, name: str, step: int = -1):
         return _NULL_CM
 
-    def record(self, name: str, t0: float, dur_s: float, step: int = -1):
+    def record(self, name: str, t0: float, dur_s: float, step: int = -1,
+               attrs: Optional[dict] = None):
+        pass
+
+    def reanchor(self):
         pass
 
     def snapshot(self) -> List[dict]:
@@ -90,10 +94,15 @@ class NullTracer:
 class Tracer:
     """Span recorder over a preallocated ring buffer.
 
-    * ``record(name, t0, dur_s, step=)`` — hot path: one tuple + one
-      locked ring store (~1 µs). ``t0`` is in the ``time.perf_counter``
-      domain; the tracer anchors that to wall time once at construction
-      so exports carry real timestamps.
+    * ``record(name, t0, dur_s, step=, attrs=)`` — hot path: one tuple +
+      one locked ring store (~1 µs). ``t0`` is in the
+      ``time.perf_counter`` domain; the tracer anchors that to wall time
+      at construction and at every ``reanchor()`` (the train loop calls
+      it at each epoch's entry, so ``ts`` never extrapolates over more
+      than an epoch), and a span keeps the anchor it was recorded under.
+      ``attrs`` is an optional small dict of counts and states measured
+      where the work happens (``cpu_s``, ``inflight``, ``rows``, ...):
+      exported under ``attrs``, left out altogether when None.
     * ``span(name)`` — context-manager sugar over ``record``.
     * ``drain()`` — spans since the last drain, oldest first, and
       resets the ring (the per-epoch consumption pattern);
@@ -117,15 +126,24 @@ class Tracer:
         # prefetcher, writer), often while the caller holds its own
         # lock: the ring lock is the innermost rank by design
         self._lock = OrderedLock("obs.trace_ring")
-        # anchor: wall = anchor_wall + (t_perf - anchor_perf)
-        self.anchor_wall = time.time()
-        self.anchor_perf = time.perf_counter()
+        # anchor: wall = anchor_wall + (t_perf - anchor_perf); ONE tuple,
+        # swapped whole, so a record racing a reanchor() reads a pair
+        self._anchor = (time.time(), time.perf_counter())  # dptpu: allow-guarded-by(one immutable tuple replaced by a single attribute store: a record call on another thread reads the old pair or the new one, never a torn one, and taking the ring lock here would put a second acquisition on the hot path for nothing)
+
+    def reanchor(self):
+        """Retake the (wall, perf_counter) anchor. Spans already in the
+        ring keep the ``ts`` computed under the anchor they were
+        recorded with."""
+        self._anchor = (time.time(), time.perf_counter())
 
     def span(self, name: str, step: int = -1) -> _SpanCM:
         return _SpanCM(self, name, step)
 
-    def record(self, name: str, t0: float, dur_s: float, step: int = -1):
-        rec = (name, t0, dur_s, step, threading.get_ident())
+    def record(self, name: str, t0: float, dur_s: float, step: int = -1,
+               attrs: Optional[dict] = None):
+        wall, perf = self._anchor
+        rec = (name, t0, dur_s, step, threading.get_ident(),
+               wall + (t0 - perf), attrs)
         with self._lock:
             self._buf[self._head] = rec
             self._head = (self._head + 1) % self.capacity
@@ -155,16 +173,20 @@ class Tracer:
             self._count = 0
         return [self._to_dict(r) for r in recs]
 
-    def _to_dict(self, rec: tuple) -> dict:
-        name, t0, dur_s, step, tid = rec
-        return {
+    @staticmethod
+    def _to_dict(rec: tuple) -> dict:
+        name, t0, dur_s, step, tid, ts, attrs = rec
+        out = {
             "name": name,
-            "ts": self.anchor_wall + (t0 - self.anchor_perf),
+            "ts": ts,
             "t0": t0,  # perf_counter domain, for window filtering
             "dur_s": dur_s,
             "step": step,
             "tid": tid,
         }
+        if attrs is not None:
+            out["attrs"] = attrs
+        return out
 
 
 # ------------------------------------------------------------- exporters ----
@@ -191,7 +213,7 @@ def spans_to_chrome_events(spans, pid: Optional[int] = None) -> List[dict]:
             "tid": s["tid"] % (1 << 31),  # chrome wants small-ish ints
             "ts": s["ts"] * 1e6,
             "dur": s["dur_s"] * 1e6,
-            "args": {"step": s["step"]},
+            "args": {"step": s["step"], **s.get("attrs", {})},
         })
     return events
 
@@ -200,7 +222,8 @@ class TraceSink:
     """Per-host span persistence under one directory.
 
     * ``<dir>/obs-<host>.jsonl`` — appended per ``add_spans`` call (one
-      span per line) plus any structured events (``log_event``): the
+      span per line: ``name, ts, dur_s, step, tid, kind`` and, where the
+      span carries any, an ``attrs`` object) plus any structured events (``log_event``): the
       greppable log.
     * ``<dir>/obs-<host>.trace.json`` — Chrome trace_event JSON,
       STREAMED: events are appended as they arrive (no per-run buffer —
@@ -244,6 +267,8 @@ class TraceSink:
         for s in spans:
             rec = {k: s[k] for k in ("name", "ts", "dur_s", "step", "tid")}
             rec["kind"] = "span"
+            if "attrs" in s:
+                rec["attrs"] = s["attrs"]
             self._jsonl.write(json.dumps(rec) + "\n")
         self._jsonl.flush()
         for e in spans_to_chrome_events(spans):

@@ -155,10 +155,16 @@ class ProfileTrigger:
     def _start_window(self, step: int):
         import jax
 
+        from dptpu.utils.profiling import device_profile_options
+
         path = self._active_dir = self._trace_dir()
         os.makedirs(path, exist_ok=True)
         try:
-            jax.profiler.start_trace(path)
+            # device planes only: the host half of the report is the
+            # tracer's own spans (_build_report), and the host tracer
+            # would stall the live run it is meant to observe
+            jax.profiler.start_trace(
+                path, profiler_options=device_profile_options())
         except Exception as e:
             # e.g. another trace is already running (DPTPU_PROFILE epoch
             # trace): stand down for this run instead of crashing a live

@@ -283,8 +283,7 @@ class ServeEngine:
             nexec = self.exec_batch(b)
             if (precision, nexec) in self._compiled:
                 continue
-            with obs.get_tracer().span("serve_compile"):
-                exe = self._compile_at(nexec, var_structs, precision)
+            exe = self._compile_at(nexec, var_structs, precision)
             self._compiled[(precision, nexec)] = exe
             if self._verbose:
                 print(f"=> serve: AOT-compiled {self.arch} bucket {b} "
@@ -350,8 +349,7 @@ class ServeEngine:
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                 placed,
             )
-            with obs.get_tracer().span("serve_compile"):
-                exe = self._compile_at(nexec, var_structs, precision)
+            exe = self._compile_at(nexec, var_structs, precision)
             self._compiled[(precision, nexec)] = exe
         # one GIL-atomic tuple store publishes the grown ladder to the
         # dispatch thread's bucket_for/max_bucket reads; every exec size

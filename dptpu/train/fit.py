@@ -254,6 +254,17 @@ def _opt_knobs(cfg: Config) -> tuple:
 
 def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
     """Train (or evaluate) per the config; returns a result dict."""
+    try:
+        return _fit(cfg, image_size=image_size, verbose=verbose)
+    finally:
+        # the tracer is installed on _fit's first lines, so that set-up
+        # can be spanned: every way out of it (a knob that fails fast,
+        # --evaluate's early return) puts the inert defaults back
+        obs.reset()
+
+
+def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
+    t_fit0 = time.perf_counter()
     # self-tuning control plane (ISSUE 19): the offline artifact applies
     # FIRST — it env-injects ONLY knobs nothing else set, so every
     # fail-fast parse below sees the tuned values while explicit
@@ -277,6 +288,26 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
         raise ValueError(f"--ckpt-keep {cfg.ckpt_keep} must be >= 1")
     fault_plan = FaultPlan.from_env()  # raises on a typo'd DPTPU_FAULT
     obs_conf = obs.obs_knobs()  # DPTPU_OBS_* knobs fail fast too
+    # --- observability (dptpu/obs): one tracer, one metrics registry,
+    # one sink fan-out, installed HERE so that set-up (two thirds of a
+    # short run) is spanned too: ``setup.*`` phases, consecutive from
+    # this function's first line to loop entry, with the ``compile``
+    # spans of dptpu/utils/compile_cache.py's listener inside them.
+    tracer = obs.set_tracer(
+        obs.Tracer(capacity=obs_conf["ring"])
+        if obs_conf["enabled"] else obs.NullTracer()
+    )
+    registry = obs.set_registry(obs.Registry())
+    setup_open = ["knobs_mesh", t_fit0]
+
+    def _setup_phase(name):
+        # close the set-up phase that is running as a ``setup.<name>``
+        # span and open the next (None: set-up is over)
+        now = time.perf_counter()
+        tracer.record("setup." + setup_open[0], setup_open[1],
+                      now - setup_open[1])
+        setup_open[:] = [name, now]
+
     # elastic-lifecycle knobs (DPTPU_ELASTIC / DPTPU_QUORUM_DEADLINE_S /
     # DPTPU_STRAGGLER_*) fail fast pre-compile under the same contract
     from dptpu.resilience.elastic import elastic_knobs
@@ -593,6 +624,7 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
     # budgets a decoded-pixel cache so epoch 1+ skips JPEG Huffman decode
     # (DPTPU_CACHE_SCOPE picks pooled-slab vs per-worker-sharded), and
     # DPTPU_LEASE keeps process-mode batches zero-copy end to end.
+    _setup_phase("data")
     workers_mode, cache_bytes, cache_scope, leased = _feed_knobs()
     if verbose:
         from dptpu.data import native_image
@@ -904,6 +936,7 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
         from dptpu.parallel.mesh import data_axis_names, squeeze_axes
 
         _bn_axis = squeeze_axes(data_axis_names(mesh))
+    _setup_phase("model_init")
     model = create_model(
         cfg.arch,
         pretrained=cfg.pretrained,
@@ -979,11 +1012,16 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
         # dptpu/models/pretrained.py for the offline conversion workflow
         from dptpu.models.pretrained import load_pretrained_variables
 
+        # the loader makes its own ``model.init`` template to check the
+        # weights against: under --pretrained the init's many small
+        # compiles show inside this phase
+        _setup_phase("pretrained")
         pretrained_vars = load_pretrained_variables(
             cfg.arch, model, input_shape=(1, image_size, image_size, 3)
         )
         if verbose:
             print(f"=> using pre-trained model '{cfg.arch}'")
+        _setup_phase("state_commit")
     state = create_train_state(
         rng,
         model,
@@ -998,6 +1036,9 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
                       else cfg.start_epoch * steps_per_epoch),
         variables=pretrained_vars,
     )
+    if pretrained_vars is None:
+        # create_train_state ran ``model.init``: that was model_init
+        _setup_phase("state_commit")
 
     import os
 
@@ -1227,6 +1268,7 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
     elif want_zero1 and cfg.evaluate and not want_zero3 and verbose:
         print("=> DPTPU_ZERO1 ignored: --evaluate does not train")
     opt_shard_bytes = None
+    _setup_phase("step_build")
     if use_zero3:
         # ZeRO-3/FSDP: params, gradients AND optimizer state live
         # sharded over the (intra-slice) data axis — placement comes
@@ -1421,6 +1463,7 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
         eval_view = lambda s: s  # noqa: E731
         eval_view_gathers = False
         if jax.process_count() == 1:
+            _setup_phase("state_commit")
             # commit the state to where the step will leave it (this
             # device, or replicated over the mesh): the uncommitted
             # state of the first call and the committed one the step
@@ -1430,6 +1473,7 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
             # host-local state is not placed on a mesh that spans hosts.
             state = (put(state) if single_device
                      else jax.device_put(state, replicated_sharding(mesh)))
+            _setup_phase("step_build")
     eval_step = make_eval_step(mesh, compute_dtype)
 
     if cfg.evaluate:
@@ -1480,19 +1524,17 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
 
     profile_dir = env_str("DPTPU_PROFILE")
     if profile_dir and derived.is_chief:
-        jax.profiler.start_trace(profile_dir)
+        from dptpu.utils.profiling import device_profile_options
 
-    # --- observability (dptpu/obs): one tracer, one metrics registry,
-    # one sink fan-out. Step phases (data_wait/h2d/step/ckpt) record
-    # into the span ring; every per-epoch scalar publishes into the
-    # registry and flushes once to console + TB + JSONL; SIGUSR2 (or
+        jax.profiler.start_trace(
+            profile_dir, profiler_options=device_profile_options())
+
+    # --- observability sinks (the tracer and registry exist since this
+    # function's first lines). Step phases (data_wait/h2d/step/ckpt)
+    # record into the span ring; every per-epoch scalar publishes into
+    # the registry and flushes once to console + TB + JSONL; SIGUSR2 (or
     # the DPTPU_OBS_TRIGGER sentinel) arms an in-flight device trace of
     # the next DPTPU_OBS_TRACE_STEPS steps — no restart required.
-    tracer = obs.set_tracer(
-        obs.Tracer(capacity=obs_conf["ring"])
-        if obs_conf["enabled"] else obs.NullTracer()
-    )
-    registry = obs.set_registry(obs.Registry())
     trace_sink = None
     if obs_conf["dir"]:
         # deliberately PER-HOST, not chief-only: the files are named
@@ -1827,6 +1869,18 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
     # their exact position through train_one_epoch's emergency_cb)
     current_pos = {"epoch": start_epoch, "step": resume_step}
     emergency = {"saved": False}
+    # loop entry: set-up is over. Its spans leave the ring here (into
+    # the sink and the one ``=> set-up:`` line), so that the first
+    # epoch's attribution sees its own spans only.
+    _setup_phase(None)
+    if tracer.enabled:
+        setup_spans = tracer.drain()
+        setup_report = obs.setup_report(setup_spans)
+        if verbose:
+            print(obs.format_setup(setup_report))
+        if trace_sink is not None:
+            trace_sink.add_spans(setup_spans)
+            trace_sink.log_event("setup_report", setup_report)
     try:
       # liveness beats ride a dedicated thread (StopToken teardown), so
       # a host parked inside a blocking device fetch keeps beating and
@@ -1890,7 +1944,8 @@ def fit(cfg: Config, *, image_size: int = 224, verbose: Optional[bool] = None):
                 state,
                 train_step,
                 DevicePrefetcher(
-                    train_loader.epoch(epoch, start_batch=start_step), put
+                    train_loader.epoch(epoch, start_batch=start_step), put,
+                    first_step=start_step,
                 ),
                 epoch=epoch,
                 num_batches=steps_per_epoch,

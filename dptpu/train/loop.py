@@ -25,6 +25,12 @@ from dptpu import obs
 from dptpu.utils.meters import AverageMeter, ProgressMeter, Summary
 
 
+def _landed(x) -> bool:
+    """Whether a device array has landed (host values always have)."""
+    is_ready = getattr(x, "is_ready", None)
+    return True if is_ready is None else bool(is_ready())
+
+
 def train_one_epoch(
     state,
     train_step: Callable,
@@ -89,14 +95,26 @@ def train_one_epoch(
     # step-phase spans (dptpu/obs): data_wait / step / fetch / ckpt plus
     # a per-step "iter" envelope — the host half of the epoch
     # attribution report. A NullTracer makes every record a no-op.
+    # With a real tracer the step and iter spans carry what only this
+    # thread can see at this moment (a few microseconds an iteration):
+    # its own CPU seconds, how many earlier steps are still in flight on
+    # the device, and whether the batch had landed when the step was
+    # dispatched. A NullTracer asks for none of it.
     tracer = obs.get_tracer()
+    traced = tracer.enabled
+    if traced:
+        tracer.reanchor()  # ts never extrapolates over more than an epoch
     pc = time.perf_counter
+    tt = time.thread_time
+    c_iter0 = 0.0
     end = time.time()
     it = iter(batches)
     i = -1
     try:
         while True:
             t_iter0 = pc()
+            if traced:
+                c_iter0 = tt()
             try:
                 batch = next(it)
             except StopIteration:
@@ -105,10 +123,25 @@ def train_one_epoch(
             t_data = pc()
             tracer.record("data_wait", t_iter0, t_data - t_iter0,
                           step=steps_done)
+            if traced:
+                c_data = tt()
+                # steps finish in order: count back from the newest to
+                # the first whose loss has landed
+                inflight = 0
+                for m, _ in reversed(pending):
+                    if _landed(m["loss"]):
+                        break
+                    inflight += 1
+                input_ready = _landed(batch["images"])
             data_time.update(time.time() - end)
             n = int(np.prod(batch["labels"].shape))
             state, metrics = train_step(state, batch)
-            tracer.record("step", t_data, pc() - t_data, step=steps_done)
+            if traced:
+                tracer.record(
+                    "step", t_data, pc() - t_data, step=steps_done,
+                    attrs={"cpu_s": tt() - c_data, "inflight": inflight,
+                           "input_ready": input_ready},
+                )
             steps_done += 1
             pending.append((metrics, n))
             if i % print_freq == 0:
@@ -153,8 +186,10 @@ def train_one_epoch(
             # the iter envelope closes BEFORE the on_step hook: a
             # profile-trigger window that ends on this tick must see
             # this step's iter span (the hook itself is microseconds)
-            tracer.record("iter", t_iter0, pc() - t_iter0,
-                          step=steps_done - 1)
+            if traced:
+                tracer.record("iter", t_iter0, pc() - t_iter0,
+                              step=steps_done - 1,
+                              attrs={"cpu_s": tt() - c_iter0})
             if on_step is not None:
                 on_step()
             if should_stop is not None and should_stop():

@@ -29,6 +29,25 @@ import tempfile
 from typing import Callable, Dict, Tuple
 
 
+def device_profile_options():
+    """``jax.profiler.ProfileOptions`` for every profiler session the
+    program opens: host and Python tracers off, device planes only.
+
+    jax's default (host tracer level 2) records the runtime's own threads:
+    on a live ResNet-50 run about 1.8 M host events a second, a stall of
+    dispatch of 0.8-1.35 s at ``start_trace`` and a ``stop_trace`` of
+    150-206 s for two seconds traced (PERF.md section 6, chip runs of PR
+    26). Nothing here reads a host event: the device's operations are on
+    the device planes, and the host half of every report comes from the
+    program's own spans (``dptpu/obs``)."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = 0
+    options.python_tracer_level = 0
+    return options
+
+
 def parse_perfetto_trace(trace: dict, iters: int = 1) -> Tuple[float, Dict[str, float]]:
     """Sum device-side op durations from a loaded perfetto trace.
 
@@ -40,29 +59,17 @@ def parse_perfetto_trace(trace: dict, iters: int = 1) -> Tuple[float, Dict[str, 
     of silently reporting ``(0, {})`` — a zero would read as "the device
     did no work" when the real cause is almost always that no device
     tracks matched: a host-only trace (backend whose PJRT plugin exports
-    no device timeline), a traced region that dispatched nothing, or a
-    track-naming scheme this parser doesn't know.
-
-    CPU-PJRT fallback: the CPU backend has no ``/device:*`` track — its
-    XLA ops execute on the ``tf_XLAEigen`` threadpool of the
-    ``/host:CPU`` process track, interleaved with Python tracemes and
-    compiler passes on OTHER threads of the same pid. When (and only
-    when) no real device track matched, op events from those Eigen
-    threads are used instead, so CPU-only runs still get a per-op table
-    (approximate: thread-parallel op time max-collapses to the busiest
-    thread, like the multi-replica rule).
+    no device timeline: the CPU backend is one, its operations run on
+    host threads and every session this program opens has the host
+    tracer off, ``device_profile_options``), a traced region that
+    dispatched nothing, or a track-naming scheme this parser doesn't
+    know.
     """
     events = trace.get("traceEvents", [])
-    pid_names, thread_names = {}, {}
-    for e in events:
-        if e.get("ph") != "M":
-            continue
-        if e.get("name") == "process_name":
-            pid_names[e["pid"]] = e.get("args", {}).get("name", "")
-        elif e.get("name") == "thread_name":
-            thread_names[(e.get("pid"), e.get("tid"))] = (
-                e.get("args", {}).get("name", "")
-            )
+    pid_names = {
+        e["pid"]: e.get("args", {}).get("name", "") for e in events
+        if e.get("ph") == "M" and e.get("name") == "process_name"
+    }
     dev_pids = {
         p for p, n in pid_names.items()
         if ("TPU" in n or "/device" in n or "Device" in n) and "Host" not in n
@@ -78,15 +85,6 @@ def parse_perfetto_trace(trace: dict, iters: int = 1) -> Tuple[float, Dict[str, 
         return tracks
 
     per_track = _collect(lambda e: e.get("pid") in dev_pids)
-    if not per_track:
-        xla_cpu = {
-            (p, t) for (p, t), n in thread_names.items()
-            if str(pid_names.get(p, "")).startswith("/host:")
-            and str(n).startswith("tf_XLAEigen")
-        }
-        per_track = _collect(
-            lambda e: (e.get("pid"), e.get("tid")) in xla_cpu
-        )
     if not per_track:
         tracks = sorted(set(pid_names.values())) or ["<no process_name metadata>"]
         raise RuntimeError(
@@ -161,7 +159,8 @@ def profile_device_time(fn: Callable, *args, iters: int = 6,
     fence(out)  # warm / compile outside the trace
     tmp = tempfile.mkdtemp(prefix="dptpu_prof_")
     try:
-        with jax.profiler.trace(tmp):
+        with jax.profiler.trace(
+                tmp, profiler_options=device_profile_options()):
             for _ in range(iters):
                 out = fn(*args)
             fence(out)

@@ -50,6 +50,10 @@ _FAST_MODULES = {
     # the overhead/coverage/trigger gates must hold in tier 1, and they
     # can only be asserted through fit() (one subprocess, tiny preset)
     "test_obs", "test_obs_knobs", "test_profiling", "test_obsbench_smoke",
+    # span attributes (ISSUE 27): fake-step loop, row-set feed and report
+    # units are pure-fast; ONE module fixture runs fit() twice at the
+    # test_fault_resume size (resnet18@32, 4 steps) for the set-up spans
+    "test_obs_attrs",
     # large-batch engine (PR 6): knob validation is pure; the recipe-math
     # module is pure optax math plus TinyNet-sized jits (the
     # test_fault_resume precedent) — the accumulation/trust-ratio locks

@@ -51,7 +51,7 @@ def test_obsbench_smoke_gates(tmp_path):
     assert bench["attribution_coverage"] >= 0.95
     attr = bench["attribution"]
     accounted = (attr["data_wait_s"] + attr["h2d_s"] + attr["device_s"]
-                 + attr["ckpt_s"])
+                 + attr["ckpt_s"] + attr["compile_s"])
     assert accounted + attr["other_s"] == \
         __import__("pytest").approx(attr["wall_s"], rel=0.02)
     # overhead gate: the drift-hardened form — overhead is the MEDIAN

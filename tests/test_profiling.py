@@ -65,48 +65,6 @@ def test_multi_module_jit_spans_sum_as_total():
     assert not any(k.startswith("jit_") for k in per_op)
 
 
-def _thread(pid, tid, name):
-    return {"ph": "M", "pid": pid, "tid": tid, "name": "thread_name",
-            "args": {"name": name}}
-
-
-def _op_t(pid, tid, name, dur_us):
-    return {"ph": "X", "pid": pid, "tid": tid, "name": name, "dur": dur_us}
-
-
-def test_cpu_pjrt_fallback_uses_eigen_threads_only():
-    """No /device track at all (CPU backend): ops on the tf_XLAEigen
-    threadpool of /host:CPU count; Python tracemes and compiler passes
-    on the SAME pid's other threads do not."""
-    trace = {"traceEvents": [
-        _meta(7, "/host:CPU"),
-        _thread(7, 100, "tf_XLAEigen/100"),
-        _thread(7, 200, "python"),
-        _thread(7, 300, "tf_xla-cpu-llvm-codegen/300"),
-        _op_t(7, 100, "fusion.3", 2000),
-        _op_t(7, 100, "copy.1", 500),
-        _op_t(7, 200, "$builtins isinstance", 900000),
-        _op_t(7, 300, "algsimp", 700000),
-    ]}
-    total, per_op = parse_perfetto_trace(trace, iters=1)
-    assert per_op == {"fusion.3": 2.0, "copy.1": 0.5}
-    assert total == pytest.approx(2.5)
-
-
-def test_cpu_fallback_never_fires_when_device_track_present():
-    # a real TPU trace that ALSO carries /host:CPU Eigen threads must
-    # attribute from the device track alone
-    trace = {"traceEvents": [
-        _meta(1, "/device:TPU:0"),
-        _meta(7, "/host:CPU"),
-        _thread(7, 100, "tf_XLAEigen/100"),
-        _op(1, 10, "fusion.1", 4000),
-        _op_t(7, 100, "host_side_fusion.9", 999000),
-    ]}
-    total, per_op = parse_perfetto_trace(trace, iters=1)
-    assert per_op == {"fusion.1": 4.0}
-
-
 def _write_gz(path, events):
     os.makedirs(os.path.dirname(path), exist_ok=True)
     with gzip.open(path, "wt") as f:
