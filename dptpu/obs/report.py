@@ -8,8 +8,9 @@ and input-starvation diagnosis — Mikami et al. 1811.05233, Ying et al.
 * ``data_wait`` — host blocked waiting for the loader (collect/lease
   included);
 * ``h2d`` — host-to-device transfer (the DevicePrefetcher's put/block);
-* ``device`` — step dispatch + the lagged metric fetch (host time spent
-  feeding/syncing the device; the DEVICE-side truth lives in XLA traces
+* ``device`` — step dispatch, the loop's wait for the device where the
+  run-ahead is bounded (``pace``) and the lagged metric fetch (host time
+  spent feeding/syncing the device; the DEVICE-side truth lives in XLA traces
   — dptpu/utils/profiling.py — which these host spans complement, never
   replace);
 * ``ckpt`` — checkpoint submits/flushes on the step thread (async
@@ -32,8 +33,9 @@ p50/p90/max step time plus an anomalous-step log (steps slower than
 The spans' attributes (``attrs``, measured where the work happens) give
 the report's third line, ``step_call`` and ``feed``: how long the
 dispatch call took and how much of that the loop thread was on the CPU,
-how many earlier steps were still in flight and whether the batch had
-landed when it was made; whether the feed's workers were done when the
+how many earlier steps were still in flight, how often the loop had to
+wait for the device first (``paced``) and whether the batch had landed
+when it was made; whether the feed's workers were done when the
 loop came for a batch, and the CPU and wall time a row cost them.
 """
 
@@ -53,6 +55,7 @@ SPAN_CATEGORY = {
     "data_wait": "data_wait",
     "collect": "data_wait",
     "h2d": "h2d",
+    "pace": "device",
     "step": "device",
     "fetch": "device",
     "eval_step": "device",
@@ -139,6 +142,9 @@ def summarize_attrs(spans: List[dict]) -> dict:
             "input_ready_pct": round(100.0 * _mean(
                 s["attrs"]["input_ready"] for s in calls), 1),
         }
+        paced = [s["attrs"]["paced"] for s in calls if "paced" in s["attrs"]]
+        if paced:
+            out["step_call"]["paced_pct"] = round(100.0 * _mean(paced), 1)
     collects = [s for s in spans
                 if s["name"] == "collect" and "ready" in s.get("attrs", ())]
     if collects:
@@ -541,8 +547,10 @@ def format_report(report: dict, epoch: Optional[int] = None) -> str:
                 f"step call p50 {calls['p50_ms']:.1f}ms (mean: cpu "
                 f"{calls['cpu_ms']:.1f}ms + blocked "
                 f"{calls['blocked_ms']:.1f}ms), in flight p50 "
-                f"{calls['inflight_p50']:g}, input ready "
-                f"{calls['input_ready_pct']:.0f}%"
+                f"{calls['inflight_p50']:g}"
+                + (f", paced {calls['paced_pct']:.0f}%"
+                   if "paced_pct" in calls else "")
+                + f", input ready {calls['input_ready_pct']:.0f}%"
             )
         if feed:
             bits.append(

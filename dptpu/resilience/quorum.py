@@ -40,8 +40,9 @@ parked inside a blocking device fetch (a metric sync of a step the
 holding host has not dispatched, a synchronous checkpoint gather)
 cannot post READY until that fetch resolves — if it never does, the
 holder degrades at the deadline and the parked peer stays inside its
-fetch. The train loop's lagged metric fetches make the window small
-(it only syncs steps every host has already dispatched, except the
+fetch. The train loop's lagged metric fetches and its bounded
+run-ahead make the window small (both wait only for steps two behind
+the newest, which every host has already dispatched, except the
 epoch-opening display), but closing it fully needs a tick source off
 the host thread — real multi-host hardware work.
 

@@ -7,10 +7,20 @@ The reference's L6 (imagenet_ddp.py:200-324) with its exact console surface
 ``training_time``, imagenet_ddp.py:224-236).
 
 One deliberate performance change: metric scalars are NOT pulled from device
-every step — device values are buffered and fetched once per print interval,
-so the hot loop never blocks on a D2H sync (the reference's own optimization,
-imagenet_ddp_apex.py:385-388, applied to all paths; its non-Apex path paid a
-``.item()`` sync per batch, imagenet_ddp.py:267).
+every step — device values are buffered and fetched once per print interval
+(the reference's own optimization, imagenet_ddp_apex.py:385-388, applied to
+all paths; its non-Apex path paid a ``.item()`` sync per batch,
+imagenet_ddp.py:267).
+
+The loop's run-ahead is bounded: before it dispatches step *i* it makes sure
+that step *i* − ``MAX_IN_FLIGHT`` has landed, and waits for that one loss if
+it has not. So the device always has a step running and the next queued
+behind it, a device-bound run spends one device step in every iteration
+(and not the whole queue's wait in the one iteration in ``--print-freq``
+that fetches), and a preemption signal is answered within that many steps.
+The display fetch lags by the same depth, so it reads only steps that have
+landed. A run whose host sets the pace never has that many steps in flight,
+finds the step landed and never waits.
 """
 
 from __future__ import annotations
@@ -23,6 +33,16 @@ import numpy as np
 
 from dptpu import obs
 from dptpu.utils.meters import AverageMeter, ProgressMeter, Summary
+
+
+# At most this many steps are in flight on the device at any time (the one
+# running and those queued behind it). 2 is the depth the lagged display
+# fetch drained the queue to before the run-ahead was bounded, so no worst
+# case got worse; a constant, not a knob: the step time does not depend on
+# it (one step lands per iteration at any depth), only the slack against a
+# slow host iteration does (MAX_IN_FLIGHT - 1 device steps less one host
+# iteration). PERF.md section 6, PR 28, has the readings that chose it.
+MAX_IN_FLIGHT = 2
 
 
 def _landed(x) -> bool:
@@ -98,8 +118,10 @@ def train_one_epoch(
     # With a real tracer the step and iter spans carry what only this
     # thread can see at this moment (a few microseconds an iteration):
     # its own CPU seconds, how many earlier steps are still in flight on
-    # the device, and whether the batch had landed when the step was
-    # dispatched. A NullTracer asks for none of it.
+    # the device, whether the loop had to wait for the device before it
+    # could dispatch, and whether the batch had landed when the step was
+    # dispatched. A NullTracer asks for none of it but the one probe
+    # that bounds the run-ahead.
     tracer = obs.get_tracer()
     traced = tracer.enabled
     if traced:
@@ -123,41 +145,60 @@ def train_one_epoch(
             t_data = pc()
             tracer.record("data_wait", t_iter0, t_data - t_iter0,
                           step=steps_done)
+            data_time.update(time.time() - end)
+            # bounded run-ahead: step i - MAX_IN_FLIGHT has to have
+            # landed before step i goes out. Steps finish in order, so
+            # one probe of that step's loss says it; where the device
+            # sets the pace the loop waits here, one device step in
+            # every iteration. The step span starts after the wait, as
+            # data_wait ends before it.
+            t_step, paced = t_data, False
+            if len(pending) >= MAX_IN_FLIGHT:
+                oldest = pending[-MAX_IN_FLIGHT][0]["loss"]
+                if not _landed(oldest):
+                    jax.block_until_ready(oldest)  # dptpu: allow-host-sync(bounded run-ahead: waits for step i - MAX_IN_FLIGHT alone, so the step running and the one queued behind it stay in flight and the device never idles)
+                    t_step, paced = pc(), True
+                    tracer.record("pace", t_data, t_step - t_data,
+                                  step=steps_done)
             if traced:
                 c_data = tt()
-                # steps finish in order: count back from the newest to
-                # the first whose loss has landed
+                # what the dispatch finds: count back from the newest
+                # to the first whose loss has landed, which is the step
+                # probed above at the latest
                 inflight = 0
                 for m, _ in reversed(pending):
                     if _landed(m["loss"]):
                         break
                     inflight += 1
                 input_ready = _landed(batch["images"])
-            data_time.update(time.time() - end)
             n = int(np.prod(batch["labels"].shape))
             state, metrics = train_step(state, batch)
             if traced:
                 tracer.record(
-                    "step", t_data, pc() - t_data, step=steps_done,
+                    "step", t_step, pc() - t_step, step=steps_done,
                     attrs={"cpu_s": tt() - c_data, "inflight": inflight,
+                           "paced": paced,
                            "input_ready": input_ready},
                 )
             steps_done += 1
             pending.append((metrics, n))
             if i % print_freq == 0:
-                # one sync per interval — but lag it: blocking on the newest
-                # (still in-flight) step would drain the dispatch queue and pay
-                # the ~100ms refill documented in PERF.md, so keep the last two
-                # steps un-fetched and in flight. The first display (i == 0)
-                # fetches everything so the epoch's opening line shows real
-                # values (the queue is cold there anyway).
+                # one fetch per interval, lagged by the run-ahead's depth:
+                # every step older than the newest MAX_IN_FLIGHT landed
+                # before this iteration's dispatch, so the fetch reads
+                # them without waiting and leaves the queue as it is. The
+                # first display (i == 0) fetches everything so the epoch's
+                # opening line shows real values (the queue is cold there
+                # anyway).
                 # (capped below print_freq so short intervals still advance the
-                # display every interval instead of repeating stale values)
-                lag = 0 if i == 0 else min(2, max(print_freq - 1, 0))
+                # display every interval instead of repeating stale values;
+                # such a fetch waits for the steps it reads)
+                lag = 0 if i == 0 else min(MAX_IN_FLIGHT,
+                                           max(print_freq - 1, 0))
                 cut = max(len(pending) - lag, 0)
                 ready, pending = pending[:cut], pending[cut:]
                 t_fetch = pc()
-                for m, nb in jax.device_get(  # dptpu: allow-host-sync(the ONE lagged sync per print interval — the documented buffered-fetch design; the newest 2 steps stay in flight)
+                for m, nb in jax.device_get(  # dptpu: allow-host-sync(the ONE lagged fetch per print interval — it reads steps the bounded run-ahead has already seen land; the newest MAX_IN_FLIGHT stay in flight)
                         [(p[0], p[1]) for p in ready]):
                     losses.update(float(m["loss"]), nb)
                     top1.update(float(m["top1"]), nb)

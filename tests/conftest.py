@@ -54,6 +54,9 @@ _FAST_MODULES = {
     # units are pure-fast; ONE module fixture runs fit() twice at the
     # test_fault_resume size (resnet18@32, 4 steps) for the set-up spans
     "test_obs_attrs",
+    # bounded run-ahead (ISSUE 28): the real loop on a stand-in device,
+    # no jit, milliseconds a case
+    "test_loop_pacing",
     # large-batch engine (PR 6): knob validation is pure; the recipe-math
     # module is pure optax math plus TinyNet-sized jits (the
     # test_fault_resume precedent) — the accumulation/trust-ratio locks

@@ -90,17 +90,23 @@ def test_null_tracer_takes_and_drops_attrs():
 
 
 class FakeArray:
-    """A device value whose landing the test decides."""
+    """A device value whose landing the test decides; the loop's wait for
+    it (the bounded run-ahead) makes it land."""
 
     calls = 0
 
     def __init__(self, landed, value=1.0):
         self._landed = landed
         self._value = value
+        self._waited_for = False
 
     def is_ready(self):
         FakeArray.calls += 1
-        return bool(self._landed())
+        return self._waited_for or bool(self._landed())
+
+    def block_until_ready(self):
+        self._waited_for = True
+        return self
 
     def __float__(self):
         return self._value
@@ -129,17 +135,23 @@ def _run_loop(steps, lag, input_ready=True, step_sleep=0.0):
                            num_batches=steps, print_freq=100, verbose=False)
 
 
-@pytest.mark.parametrize("lag,expected", [
-    (0, [0, 0, 0, 0, 0, 0]),      # every earlier step has landed
-    (2, [0, 0, 1, 2, 2, 2]),      # the device runs two behind
-    (10**6, [0, 0, 1, 2, 3, 4]),  # nothing lands: the whole queue
+@pytest.mark.parametrize("lag,expected,paced_from", [
+    (0, [0, 0, 0, 0, 0, 0], None),   # every earlier step has landed
+    (1, [0, 0, 1, 1, 1, 1], None),   # the device runs one behind
+    # two behind, or nothing lands unless waited for: the loop waits for
+    # step i - 2 from step 3 on (MAX_IN_FLIGHT = 2) and the dispatch
+    # finds one step in flight, never the whole queue
+    (2, [0, 0, 1, 1, 1, 1], 3),
+    (10**6, [0, 0, 1, 1, 1, 1], 3),
 ])
-def test_step_span_counts_steps_in_flight(tracer, lag, expected):
+def test_step_span_counts_steps_in_flight(tracer, lag, expected, paced_from):
     # print_freq 100: the first display fetches step 0, later steps queue
     _run_loop(6, lag)
     steps = [s for s in tracer.drain() if s["name"] == "step"]
     assert [s["step"] for s in steps] == list(range(6))
     assert [s["attrs"]["inflight"] for s in steps] == expected
+    assert [s["attrs"]["paced"] for s in steps] == [
+        paced_from is not None and i >= paced_from for i in range(6)]
 
 
 @pytest.mark.parametrize("landed", [True, False])
@@ -179,7 +191,9 @@ def test_with_tracing_off_the_loop_asks_for_nothing(monkeypatch):
     monkeypatch.setattr(time, "thread_time", counting)
     FakeArray.calls = 0
     _run_loop(5, 2)
-    assert asked["thread_time"] == 0 and FakeArray.calls == 0
+    # untraced: no clock of the thread's own, and the one probe an
+    # iteration that bounds the run-ahead (steps 3 and 4 here)
+    assert asked["thread_time"] == 0 and FakeArray.calls == 2
     obs.set_tracer(obs.Tracer(capacity=256))
     try:
         _run_loop(5, 2)
