@@ -5,11 +5,11 @@ What the timed path produced in its first steps (taken by the tap in
 drives) is held against the plain reference, which is given the
 benchmark's own seeded weights and its own regenerated rows. Numbers:
 
-* ``feed_mismatch`` — values of the delivered batches (images and labels
-  of every checked step) that differ from the regenerated rows. Exact.
+* ``feed_mismatch`` — values of the delivered batches (every key of the
+  feed, every checked step) that differ from the regenerated rows. Exact.
 * ``loss_gap_<k>`` — |program loss - reference loss| of step k.
-* ``grad_gap_kernels`` — the first gradient as the optimizer got it (the
-  momentum buffer after one step, weight decay included), worst leaf
+* ``grad_gap_kernels`` — the first gradient as the optimizer got it (from
+  its state after one step, where the optimizer's module says), worst leaf
   among the kernels (leaves with two axes or more longer than 1): the gap
   between the program's norm and the reference's, against the reference's
   norm of that leaf or of the median leaf, whichever is larger.
@@ -86,12 +86,17 @@ def global_gap(program, reference) -> float:
 
 
 def feed_mismatch(delivered: List[tuple], regenerated: List[tuple]) -> int:
+    """Values that differ, exactly, over every checked step and every
+    array of its batch (one per key of the feed, in the feed's order). A
+    step whose arrays differ in number or shape counts whole; a step
+    that is missing on one side counts one."""
     count = 0
-    for (img_p, lab_p), (img_r, lab_r) in zip(delivered, regenerated):
-        if img_p.shape != img_r.shape or lab_p.shape != lab_r.shape:
-            return int(img_r.size + lab_r.size)
-        count += int(np.count_nonzero(img_p != img_r))
-        count += int(np.count_nonzero(lab_p != lab_r))
+    for got, want in zip(delivered, regenerated):
+        if len(got) != len(want) or any(
+                g.shape != w.shape for g, w in zip(got, want)):
+            return int(sum(w.size for w in want))
+        count += sum(int(np.count_nonzero(g != w))
+                     for g, w in zip(got, want))
     return count + abs(len(delivered) - len(regenerated))
 
 

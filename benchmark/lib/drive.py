@@ -2,9 +2,14 @@
 window, the fence, the comparison, the result line.
 
 The trainer runs as a user runs it: ``dptpu.cli.main_apex`` in this
-process, ``WORLD_SIZE=1``, ``--opt-level O2``, ``synthetic:<N>``, the
-program's defaults for whatever the configuration and traffic files do
-not state. The harness adds three things around it and edits nothing:
+process, ``WORLD_SIZE=1``, the arguments that the configuration and the
+traffic file state (``fit_argv``), the program's defaults for whatever
+they do not. What belongs to one kind of data, one family of models or
+one optimizer is not in this file: the traffic file's ``data`` names the
+feed (``feeds/``), the configuration's ``reference`` the family and its
+``optimizer.name`` the optimizer (``reference/``, ``reference/
+optimizers/``), each found by ``cells``. The harness adds three things
+around the trainer and edits nothing:
 
 * **weights from the seed.** The apex CLI has no ``--seed``; what a user
   can hand it is weights (``--pretrained`` with ``DPTPU_PRETRAINED_DIR``).
@@ -12,15 +17,16 @@ not state. The harness adds three things around it and edits nothing:
   ``--seed`` on the device in one jitted call; the program's own
   converter (``convert_state_dict`` / ``save_npz``, what
   ``python -m dptpu.tools.convert_torchvision`` calls) writes it where
-  ``--pretrained`` looks. ``--seed`` also picks ``N`` of ``synthetic:<N>``
+  ``--pretrained`` looks. ``--seed`` also picks the feed's number of rows
   (``dataset_images + seed % 128``), so each seed sees its own rows in
   its own order.
 * **a tap on the step**, at the seam between ``fit()`` and its loop
   (``train_one_epoch(state, train_step, batches, ...)``): the wrapped
   ``train_step`` copies out, during the first ``check_steps`` calls, the
-  delivered batch, the step's loss, the momentum buffers after the first
-  step and the parameters after the last, then counts calls. The same
-  step, state and feed go on into the window.
+  delivered batch (by the feed's keys), the step's loss, the first
+  gradient as the optimizer got it (where the optimizer's module finds it
+  in the state after the first step) and the parameters after the last,
+  then counts calls. The same step, state and feed go on into the window.
 * **a clock thread**: once ``warmup_iters`` calls have returned it waits
   ``--seconds``, then sends this process SIGTERM. That is the trainer's
   documented preemption path: the loop finishes the step in flight,
@@ -38,6 +44,7 @@ import importlib
 import json
 import math
 import os
+import shlex
 import shutil
 import signal
 import socket
@@ -52,7 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..reference import common as reference_common
-from . import cells, check, spans as spans_mod, synthetic, tracered
+from . import cells, check, spans as spans_mod, tracered
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 SETUP_LIMIT_S = 1100.0  # a cold first run may compile for many minutes
@@ -100,27 +107,19 @@ def _flat(tree) -> Dict[str, np.ndarray]:
             for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]}
 
 
-def _momentum(opt_state):
-    """The SGD momentum buffers of an optax chain state."""
-    import optax
-
-    is_trace = lambda n: isinstance(n, optax.TraceState)  # noqa: E731
-    found = [n for n in jax.tree_util.tree_leaves(opt_state, is_leaf=is_trace)
-             if is_trace(n)]
-    if len(found) != 1:
-        raise RuntimeError(f"expected one momentum trace in the optimizer "
-                           f"state, found {len(found)}")
-    return found[0].trace
-
-
 class StepTap:
-    """Wraps the loop's ``train_step``; see the module docstring."""
+    """Wraps the loop's ``train_step``; see the module docstring. The
+    cell gives the checked steps and the warm-up (its traffic), the keys
+    of a batch (its feed) and where the first gradient sits in the
+    program's optimizer state (its optimizer)."""
 
-    def __init__(self, check_steps: int, warmup_iters: int):
-        if warmup_iters < check_steps:
+    def __init__(self, cell):
+        self.check_steps = int(cell.traffic["check_steps"])
+        self.warmup_iters = int(cell.traffic["warmup_iters"])
+        if self.warmup_iters < self.check_steps:
             raise ValueError("warm-up must cover the checked steps")
-        self.check_steps = check_steps
-        self.warmup_iters = warmup_iters
+        self.keys = tuple(cell.feed.KEYS)
+        self.program_trace1 = cell.optimizer.program_trace1
         self.calls = 0
         self.batches: List[tuple] = []
         self.losses: List[float] = []
@@ -143,15 +142,15 @@ class StepTap:
                     (state, batch))
             if checked:
                 host = jax.device_get(batch)
-                self.batches.append((np.asarray(host["images"]),
-                                     np.asarray(host["labels"])))
+                self.batches.append(tuple(np.asarray(host[k])
+                                          for k in self.keys))
             out = train_step(state, batch)
             if checked:
                 new_state, metrics = out
                 self.losses.append(float(jax.device_get(metrics["loss"])))
                 if i == 0:
                     self.trace1 = _flat(jax.device_get(
-                        _momentum(new_state.opt_state)))
+                        self.program_trace1(new_state.opt_state)))
                 if i == self.check_steps - 1:
                     self.params_after = _flat(
                         jax.device_get(new_state.params))
@@ -387,15 +386,15 @@ class _Clock(threading.Thread):
 
 def program_template(config: dict):
     """Shapes of the program's ``{"params", "batch_stats"}`` for
-    ``config`` — no weights are made."""
+    ``config`` — no weights are made. The configuration says how the
+    program's model is made (``arch``, ``create_kwargs``), its family what
+    one row of input looks like."""
     from dptpu.models import create_model
 
-    size = config["model"]["image_size"]
-    model = create_model(config["arch"],
-                         num_classes=config["model"]["num_classes"])
+    model = create_model(config["arch"], **config["create_kwargs"])
+    example = cells.reference(config).example_input
     return jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, size, size, 3), jnp.float32),
-        train=False))
+        jax.random.PRNGKey(0), example(config["model"]), train=False))
 
 
 def to_program_layout(config: dict, template, named: Dict[str, np.ndarray],
@@ -422,22 +421,24 @@ def write_pretrained(config: dict, template, weights: Dict[str, np.ndarray],
 
 
 def dataset_images(traffic: dict, seed: int) -> int:
-    """``N`` of ``synthetic:<N>``: up to 127 rows more than the traffic
+    """The feed's number of rows: up to 127 more than the traffic
     file's, by the seed. The epoch's order depends on N; the steps per
     epoch, which the learning-rate schedule bakes into the step program,
     do not (the base is whole steps of 128 and of 512 rows less 384)."""
     return int(traffic["dataset_images"]) + int(seed) % 128
 
 
-def fit_argv(cell, n_images: int) -> List[str]:
+def fit_argv(cell, num_rows: int) -> List[str]:
+    """The trainer's command line: the feed's argument, the architecture,
+    the precision, the batch, the scaled rate, the optimizer's arguments,
+    then what the traffic file adds."""
     cfg, traffic = cell.config, cell.traffic
     # the apex CLI scales --lr by global_batch / 256
     lr = float(traffic["effective_lr"]) * 256.0 / cell.global_batch
-    argv = [f"synthetic:{n_images}", "-a", cfg["arch"],
+    argv = [cell.feed.argument(num_rows), "-a", cfg["arch"],
             "--opt-level", cfg["precision"]["opt_level"],
             "-b", str(cfg["per_chip_batch"]), "--lr", repr(lr),
-            "--momentum", repr(cfg["optimizer"]["momentum"]),
-            "--wd", repr(cfg["optimizer"]["weight_decay"]),
+            *cell.optimizer.argv(cfg["optimizer"]),
             "--start-epoch", str(traffic["start_epoch"]), "--pretrained"]
     return argv + [str(a) for a in traffic.get("extra_argv", [])]
 
@@ -452,6 +453,7 @@ def run_fit(cell, seed: int, work: str, weights: Dict[str, np.ndarray],
     write_pretrained(cell.config, template, weights,
                      os.path.join(work, "pretrained"))
     argv = fit_argv(cell, dataset_images(cell.traffic, seed))
+    print(f"benchmark: main_apex {shlex.join(argv)}", file=sys.stderr)
     cwd = os.getcwd()
     os.chdir(work)  # checkpoints and runs/ land in the scratch directory
     clock.start()
@@ -482,33 +484,33 @@ def run_fit(cell, seed: int, work: str, weights: Dict[str, np.ndarray],
 
 def reference_run(cell, weights, batches, mode: str = "f32") -> dict:
     """The plain reference over ``batches`` (the rows the benchmark
-    regenerated itself), from ``weights``. Torch names."""
-    cfg = cell.config
-    ref = cells.reference(cfg)
+    regenerated itself, one tuple a step in the order of the feed's
+    keys), from ``weights``: the family's loss under the optimizer's
+    plain update. The family's names."""
+    cfg, family = cell.config, cell.family
+    keys = cell.feed.KEYS
     return reference_common.train_steps(
-        functools.partial(ref.forward, cfg["model"]),
-        ref.trainable(cfg["model"]), weights, batches,
+        functools.partial(family.loss, cfg["model"]), cell.optimizer,
+        cfg["optimizer"], family.trainable(cfg["model"]), weights,
+        [dict(zip(keys, arrays)) for arrays in batches],
         lr=float(cell.traffic["effective_lr"]),
-        momentum=cfg["optimizer"]["momentum"],
-        weight_decay=cfg["optimizer"]["weight_decay"],
         block_rows=int(cfg["reference_block_rows"]), mode=mode)
 
 
 def regenerate_batches(cell, seed: int):
-    cfg, traffic = cell.config, cell.traffic
-    model = cfg["model"]
-    order = synthetic.epoch_order(dataset_images(traffic, seed),
-                                  int(traffic["sampler_seed"]),
-                                  int(traffic["start_epoch"]))
-    return [synthetic.batch(order, k, cell.global_batch, model["image_size"],
-                            model["num_classes"])
+    """The checked steps' rows as the feed should have delivered them:
+    one tuple a step, one array per key of the feed."""
+    traffic, feed = cell.traffic, cell.feed
+    order = feed.epoch_order(dataset_images(traffic, seed),
+                             int(traffic["sampler_seed"]),
+                             int(traffic["start_epoch"]))
+    return [feed.batch(order, k, cell.global_batch, cell.config["model"])
             for k in range(int(traffic["check_steps"]))]
 
 
 def make_weights(cell, seed: int) -> Dict[str, np.ndarray]:
-    ref = cells.reference(cell.config)
     made = reference_common.make_weights(
-        ref.weight_spec(cell.config["model"]), seed)
+        cell.family.weight_spec(cell.config["model"]), seed)
     return {k: np.asarray(v) for k, v in jax.device_get(made).items()}
 
 
@@ -634,8 +636,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     try:
         template = program_template(cell.config)
         weights = make_weights(cell, seed)
-        tap = tap_factory(int(traffic["check_steps"]),
-                          int(traffic["warmup_iters"]))
+        tap = tap_factory(cell)
         clock = _Clock(tap, seconds, TailBudget(started_wall),
                        os.path.join(work, "trace") if trace else None,
                        traffic)

@@ -16,6 +16,5 @@ def read(context):
         return None
     config = context["cell"].config
     flops = cells.reference(config).train_flops(
-        config["model"], config["per_chip_batch"],
-        config["model"]["image_size"])
+        config["model"], config["per_chip_batch"])
     return 100.0 * flops / (step_ms / 1e3) / peaks["bf16_flops_per_s"]

@@ -1,4 +1,5 @@
-"""What every plain reference shares: precision modes, seeded weights, SGD.
+"""What every plain reference shares: precision modes, seeded weights, the
+image families' loss, the training driver.
 
 A reference is the published architecture in straightforward ``jax.numpy``:
 float32 at ``Precision.HIGHEST``, no kernels, torchvision's parameter
@@ -15,12 +16,11 @@ Precision modes (``mode``), chosen per call:
   rounded to float8_e4m3 (the nearest precision below the bfloat16 that the
   configurations state), activations stored in bfloat16.
 
-The training driver follows torch SGD as the program's CLIs promise it:
-``g += wd * p; buf = momentum * buf + g; p -= lr * buf`` on every
-parameter, with the global-batch-mean gradient. The batch is taken in
-blocks of ``block_rows``: for a BatchNorm model a block is one replica's
-batch (per-replica statistics, DDP's default), for others any divisor of
-the batch, and the blocks' gradients are averaged.
+The training driver takes the family's loss on one block of rows and the
+optimizer's plain update (``optimizers/<name>.py``) and knows neither. The
+batch is taken in blocks of ``block_rows``: for a BatchNorm model a block
+is one replica's batch (per-replica statistics, DDP's default), for others
+any divisor of the batch, and the blocks' gradients are averaged.
 """
 
 from __future__ import annotations
@@ -99,6 +99,20 @@ def cross_entropy(logits, labels):
     return jnp.mean(logz - picked)
 
 
+def image_loss(forward: Callable, model: dict, weights, batch, mode: str):
+    """What the image families share: the mean cross-entropy of
+    ``forward`` on one block of raw uint8 rows with their labels."""
+    logits = forward(model, weights, normalize(batch["images"]), mode)
+    return cross_entropy(logits, batch["labels"])
+
+
+def image_example_input(model: dict):
+    """One float32 row of the configuration's image size, for the shapes
+    of the program's ``model.init``."""
+    size = model["image_size"]
+    return jnp.zeros((1, size, size, 3), jnp.float32)
+
+
 # ----------------------------------------------------------- seeded weights --
 
 
@@ -133,42 +147,40 @@ def make_weights(spec, seed: int) -> Dict[str, jax.Array]:
 # ------------------------------------------------------------ the training --
 
 
-def train_steps(forward: Callable, trainable, weights: Dict[str, jax.Array],
-                batches, *, lr: float, momentum: float, weight_decay: float,
+def train_steps(loss: Callable, optimizer, hyper: dict, trainable,
+                weights: Dict[str, jax.Array], batches, *, lr: float,
                 block_rows: int, mode: str = "f32"):
-    """Follow ``len(batches)`` SGD steps from ``weights``.
+    """Follow ``len(batches)`` optimizer steps from ``weights``.
 
-    ``forward(weights, images_f32, mode) -> logits`` is the architecture;
-    ``trainable`` names the leaves SGD moves (the rest are buffers).
-    ``batches`` is ``[(images_u8, labels), ...]`` as numpy. Returns
-    ``{"loss": [per step], "trace1": {leaf: momentum buffer after the
-    first step}, "delta": {leaf: parameters after the last step minus
-    the seeded ones}}`` — float32 numpy, torch names.
+    ``loss(weights, block, mode) -> scalar`` is the family's, on one block
+    of rows (``{key: array}``, the feed's keys); ``optimizer`` is a module
+    of ``optimizers/`` and ``hyper`` the configuration's block for it;
+    ``trainable`` names the leaves the optimizer moves (the rest are
+    buffers). ``batches`` is ``[{key: numpy array}, ...]``. Returns
+    ``{"loss": [per step], "trace1": {leaf: the first gradient as the
+    optimizer got it, from its state after the first step}, "delta":
+    {leaf: parameters after the last step minus the seeded ones}}`` —
+    float32 numpy, the family's names.
     """
     trainable = list(trainable)
     buffers = {k: v for k, v in weights.items() if k not in trainable}
 
-    def block_loss(params, images_u8, labels):
-        logits = forward({**buffers, **params}, normalize(images_u8), mode)
-        return cross_entropy(logits, labels)
+    def block_loss(params, block):
+        return loss({**buffers, **params}, block, mode)
 
     grad_fn = jax.jit(jax.value_and_grad(block_loss))
 
     @jax.jit
-    def update(params, bufs, grads, scale):
-        new_p, new_b = {}, {}
-        for k in params:
-            g = grads[k] * scale + weight_decay * params[k]
-            new_b[k] = momentum * bufs[k] + g
-            new_p[k] = params[k] - lr * new_b[k]
-        return new_p, new_b
+    def update(params, state, total, scale):
+        grads = {k: total[k] * scale for k in params}
+        return optimizer.update(params, state, grads, lr, hyper)
 
     params = {k: jnp.asarray(weights[k], jnp.float32) for k in trainable}
     start = params
-    bufs = {k: jnp.zeros_like(v) for k, v in params.items()}
+    state = optimizer.init(params)
     losses, trace1 = [], None
-    for images, labels in batches:
-        n = images.shape[0]
+    for batch in batches:
+        n = len(next(iter(batch.values())))
         if n % block_rows:
             raise ValueError(f"batch of {n} rows is not whole blocks of "
                              f"{block_rows}")
@@ -176,15 +188,16 @@ def train_steps(forward: Callable, trainable, weights: Dict[str, jax.Array],
         total, loss_sum = None, 0.0
         for b in range(n_blocks):
             rows = slice(b * block_rows, (b + 1) * block_rows)
-            loss, grads = grad_fn(params, jnp.asarray(images[rows]),
-                                  jnp.asarray(labels[rows]))
-            loss_sum = loss_sum + loss
+            value, grads = grad_fn(
+                params, {k: jnp.asarray(v[rows]) for k, v in batch.items()})
+            loss_sum = loss_sum + value
             total = grads if total is None else jax.tree_util.tree_map(
                 jnp.add, total, grads)
-        params, bufs = update(params, bufs, total, 1.0 / n_blocks)
+        params, state = update(params, state, total, 1.0 / n_blocks)
         losses.append(float(loss_sum) / n_blocks)
         if trace1 is None:
-            trace1 = {k: np.asarray(v) for k, v in bufs.items()}
+            trace1 = {k: np.asarray(v)
+                      for k, v in optimizer.trace1(state).items()}
     delta = {k: np.asarray(params[k] - start[k]) for k in params}
     return {"loss": losses, "trace1": trace1, "delta": delta}
 

@@ -144,9 +144,19 @@ def forward(model, w, x, mode: str = "f32"):
     return common.linear(x, w["fc.weight"], w["fc.bias"], mode)
 
 
-def train_flops(model, batch: int, image_size: int) -> float:
-    """Operations one optimizer step needs, forward and backward, from the
-    shapes: 2 per multiply-add of every convolution (taps on the zero
+def loss(model, w, batch, mode: str = "f32"):
+    """Mean cross-entropy of one block of rows (``images`` uint8 NHWC,
+    ``labels``)."""
+    return common.image_loss(forward, model, w, batch, mode)
+
+
+def example_input(model):
+    return common.image_example_input(model)
+
+
+def train_flops(model, rows: int) -> float:
+    """Operations one optimizer step of ``rows`` rows needs, forward and
+    backward, from the shapes: 2 per multiply-add of every convolution (taps on the zero
     padding not counted) and the classifier, three times over (forward, input gradient, weight gradient) except the
     stem, whose input is the image and gets no gradient. Elementwise work
     (BN, ReLU, pooling, SGD) is not counted: it does not run on the MXU
@@ -156,6 +166,7 @@ def train_flops(model, batch: int, image_size: int) -> float:
     def taps(size, kernel, stride, padding):
         return common.valid_taps(size, kernel, stride, padding) ** 2
 
+    image_size = model["image_size"]
     macs_stem = taps(image_size, 7, 2, 3) * 64 * 3
     size = (image_size + 2 * 3 - 7) // 2 + 1
     size = (size + 2 - 3) // 2 + 1
@@ -173,4 +184,4 @@ def train_flops(model, batch: int, image_size: int) -> float:
             macs += out * out * in_ch * planes * exp
         size = out
     macs += 512 * exp * model["num_classes"]
-    return float(batch) * 2.0 * (3 * macs + 2 * macs_stem)
+    return float(rows) * 2.0 * (3 * macs + 2 * macs_stem)

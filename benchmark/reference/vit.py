@@ -130,16 +130,26 @@ def forward(model, w, x, mode: str = "f32"):
                          w["heads.head.bias"], mode)
 
 
-def train_flops(model, batch: int, image_size: int) -> float:
-    """Operations one optimizer step needs, forward and backward, from the
-    shapes: 2 per multiply-add of the patch projection, every Linear, the
+def loss(model, w, batch, mode: str = "f32"):
+    """Mean cross-entropy of one block of rows (``images`` uint8 NHWC,
+    ``labels``)."""
+    return common.image_loss(forward, model, w, batch, mode)
+
+
+def example_input(model):
+    return common.image_example_input(model)
+
+
+def train_flops(model, rows: int) -> float:
+    """Operations one optimizer step of ``rows`` rows needs, forward and
+    backward, from the shapes: 2 per multiply-add of the patch projection, every Linear, the
     two attention products and the head, three times over (forward, input
     gradient, weight gradient); the patch projection reads the image and
     gets no input gradient. LayerNorm, softmax, GELU and SGD are not
     counted: they do not run on the MXU the peak is quoted for."""
     h, mlp, p = model["hidden_size"], model["mlp_dim"], model["patch_size"]
-    seq = _tokens(model, image_size)
+    seq = _tokens(model, model["image_size"])
     macs_patch = (seq - 1) * h * 3 * p * p
     per_layer = seq * (h * 3 * h + h * h + 2 * h * mlp) + 2 * seq * seq * h
     macs = model["num_layers"] * per_layer + h * model["num_classes"]
-    return float(batch) * 2.0 * (3 * macs + 2 * macs_patch)
+    return float(rows) * 2.0 * (3 * macs + 2 * macs_patch)

@@ -36,11 +36,11 @@ def test_train_flops_matches_xla_cost_analysis(name, per_step_tflop):
         variables["params"], variables.get("batch_stats", {}),
         images).cost_analysis()
     xla = float(cost["flops"])
-    ours = cells.reference(config).train_flops(model_cfg, batch, size)
+    ours = cells.reference(config).train_flops(model_cfg, batch)
     # XLA also counts the elementwise work (BN, LN, softmax, GELU): ours
     # is the MXU's share, a few percent under it
     assert 0.93 * xla <= ours <= xla, (ours, xla)
     # and XLA's figures for a whole step of 128 rows (ISSUE 24), which
     # hold the elementwise work too
-    at_128 = cells.reference(config).train_flops(model_cfg, 128, size) / 1e12
+    at_128 = cells.reference(config).train_flops(model_cfg, 128) / 1e12
     assert at_128 == pytest.approx(per_step_tflop, rel=0.03)

@@ -12,12 +12,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.lib import cells, check, drive, synthetic
+from benchmark.feeds import synthetic
+from benchmark.lib import cells, check, drive
 from benchmark.reference import common
 
 CONFIGS = os.path.join(cells.BENCH_DIR, "configs")
 SIZE = 64   # pixels: a tiny image, the published widths
 ROWS = 8
+SIZES = {"image_size": SIZE, "num_classes": 1000}
 
 
 def _config(name):
@@ -29,7 +31,7 @@ def _config(name):
 
 def _rows(n=ROWS, seed=0):
     order = synthetic.epoch_order(64, seed, 0)
-    return synthetic.batch(order, 0, n, SIZE, 1000)
+    return synthetic.batch(order, 0, n, SIZES)
 
 
 @pytest.mark.parametrize("name,by_value", [
@@ -92,11 +94,12 @@ def test_the_fp8_control_is_not_correct(name):
     ref = cells.reference(config)
     model_cfg = config["model"]
     weights = common.make_weights(ref.weight_spec(model_cfg), 11)
-    batches = [_rows(16, seed) for seed in (1, 2, 3)]
+    batches = [dict(zip(synthetic.KEYS, _rows(16, seed)))
+               for seed in (1, 2, 3)]
     run = functools.partial(
-        common.train_steps, functools.partial(ref.forward, model_cfg),
-        ref.trainable(model_cfg), weights, batches, lr=0.01, momentum=0.9,
-        weight_decay=1e-4, block_rows=16)
+        common.train_steps, functools.partial(ref.loss, model_cfg),
+        cells.optimizer(config), config["optimizer"],
+        ref.trainable(model_cfg), weights, batches, lr=0.01, block_rows=16)
     sound = run(mode="f32")
     limits = config["limits"]
 
@@ -133,6 +136,6 @@ def test_synthetic_copy_matches_the_program_feed():
         loader.close()
     order = synthetic.epoch_order(200, 0, 5)
     for step, got in enumerate((first, second)):
-        images, labels = synthetic.batch(order, step, 8, SIZE, 1000)
+        images, labels = synthetic.batch(order, step, 8, SIZES)
         assert np.array_equal(got["images"], images)
         assert np.array_equal(got["labels"], labels)

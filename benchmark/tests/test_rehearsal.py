@@ -15,19 +15,15 @@ from benchmark.lib import cells, drive
 ROOT = cells.ROOT
 
 
-def tiny_cell():
-    """ResNet-18 (the reference file serves the family), 4 rows a step,
-    a 2,000-row epoch: what a CPU holds. The limits are the ResNet-50
-    configuration's own, but for the kernels, which four rows of batch
-    statistics make noisier."""
+def _tiny(name, config_name, shrink):
+    """Cell ``name`` cut to what a CPU holds: 4 rows a step, a 2,000-row
+    epoch, the configuration changed by ``shrink``."""
     bench = cells.manifest()
-    name = "rn50-fit-1chip"
     with open(os.path.join(cells.BENCH_DIR, "configs",
-                           "resnet50-224-bf16.json")) as f:
+                           config_name + ".json")) as f:
         config = json.load(f)
-    config["arch"] = "resnet18"
-    config["model"].update(stage_sizes=[2, 2, 2, 2], block="basic")
     config["per_chip_batch"] = config["reference_block_rows"] = 4
+    shrink(config)
     with open(os.path.join(cells.BENCH_DIR, "traffic",
                            "fit-synthetic.json")) as f:
         traffic = json.load(f)
@@ -38,6 +34,32 @@ def tiny_cell():
         name=name, chips=1, config=config, traffic=traffic,
         end_to_end=tuple(filter(reported, bench["end_to_end"])),
         per_layer=tuple(filter(reported, bench["per_layer"])))
+
+
+def tiny_cell():
+    """ResNet-18 (the reference file serves the family). The limits are
+    the ResNet-50 configuration's own, but for the kernels, which four
+    rows of batch statistics make noisier."""
+    def shrink(config):
+        config["arch"] = "resnet18"
+        config["model"].update(stage_sizes=[2, 2, 2, 2], block="basic")
+
+    return _tiny("rn50-fit-1chip", "resnet50-224-bf16", shrink)
+
+
+def tiny_vit_cell():
+    """ViT-B/32: the published widths over 50 tokens where ViT-B/16 has
+    197, the reference in two blocks of two rows. The limits are the
+    ViT-B/16 configuration's own but for the loss: a mean over four rows
+    reads up to 0.0057 in sound bfloat16 where one over 128 read 0.0012
+    (two seeds on the CPU, PR 29; PERF.md section 2)."""
+    def shrink(config):
+        config["arch"] = "vit_b_32"
+        config["model"].update(patch_size=32, sequence_length=50)
+        config["reference_block_rows"] = 2
+        config["limits"] = dict(config["limits"], loss_gap=0.03)
+
+    return _tiny("vitb16-fit-1chip", "vit-b16-224-bf16", shrink)
 
 
 @pytest.fixture(scope="module")
@@ -100,6 +122,20 @@ class FrozenStateTap(drive.StepTap):
         return super().wrap(frozen)
 
 
+def test_a_tiny_vit_run_is_correct_through_the_same_seams():
+    """The second family under the same feed and optimizer modules: the
+    transformer's loss and attention, no BatchNorm, two reference blocks
+    a step."""
+    result = drive.run_cell(tiny_vit_cell(), 2**31 + 77, 1.0, False,
+                            time.time())
+    assert result["correct"] is True, result["numbers"]
+    assert result["attempted"] > 0 and result["failed"] == 0
+    assert set(result["metrics"]) == {
+        "train_img_s_chip", "step_ms_p95", "setup_s"}
+    # every leaf moved as the reference's did: none reads near 1
+    assert result["worst_leaf"]["delta_gap"]["all"][1] < 0.05
+
+
 def test_a_step_that_returns_its_state_unchanged_is_not_correct(cell):
     result = drive.run_cell(cell, 5, 1.0, False, time.time(),
                             tap_factory=FrozenStateTap)
@@ -134,7 +170,7 @@ def test_every_cell_of_the_manifest_loads():
             assert hasattr(cells.reader(m["name"]), "read")
             assert cells.layer_metric(m["name"])["layer"] == m["layer"]
         ref = cells.reference(loaded.config)
-        assert ref.train_flops(loaded.config["model"], 1, 224) > 0
+        assert ref.train_flops(loaded.config["model"], 1) > 0
     assert cells.peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
     with pytest.raises(SystemExit):
         cells.peaks("TPU v9 imaginary")

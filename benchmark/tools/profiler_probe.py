@@ -159,8 +159,7 @@ def main(argv=None) -> int:
                 self._one(k, setting)
             self._signal()
 
-    tap = Tap(int(cell.traffic["check_steps"]),
-              int(cell.traffic["warmup_iters"]))
+    tap = Tap(cell)
     # no run's limit holds here: one stop_trace at level 1 is minutes
     budget = drive.TailBudget(time.time(), limit_s=3600.0, after_s=0.0)
     probe = Probe(tap, 0.0, budget, os.path.join(work, "trace"),

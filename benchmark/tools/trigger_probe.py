@@ -111,8 +111,7 @@ def main(argv=None) -> int:
             time.sleep(3.0)  # and a steady loop after
             self._signal()
 
-    tap = Tap(int(cell.traffic["check_steps"]),
-              int(cell.traffic["warmup_iters"]))
+    tap = Tap(cell)
     budget = drive.TailBudget(time.time(), limit_s=3600.0, after_s=0.0)
     probe = Probe(tap, 0.0, budget, os.path.join(work, "unused"),
                   {"trace_read_s": 1.0, "trace_stall_cap_s": 20.0})
