@@ -158,7 +158,7 @@ def parse_model_specs(raw: str):
     """``[name=]arch[,...]`` -> ordered (name, arch) pairs; the first
     entry is the router's default route. A bare arch names itself, so
     co-serving the same arch twice needs explicit names."""
-    from dptpu.models import model_names
+    from dptpu.models import model_names, model_task
 
     pairs = []
     for spec in str(raw).split(","):
@@ -173,6 +173,13 @@ def parse_model_specs(raw: str):
                 f"(e.g. {', '.join(model_names()[:4])}, ...; full list: "
                 f"python -c 'from dptpu.models import model_names; "
                 f"print(model_names())')"
+            )
+        if model_task(arch) != "images":
+            raise ValueError(
+                f"--arch={arch!r} is a token-sequence model: dptpu serve "
+                f"classifies images (it has no cache of keys, values or "
+                f"convolution state and no decode loop); train it with "
+                f"the imagenet_ddp*.py entry points on tokens:<N>"
             )
         if name in (n for n, _ in pairs):
             raise ValueError(

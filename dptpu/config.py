@@ -76,6 +76,10 @@ class Config:
     # schedule and label smoothing (dptpu/ops/optimizers.py,
     # dptpu/train/step.py). Defaults reproduce the reference exactly.
     optimizer: str = "sgd"
+    # adamw's moments (torch AdamW's defaults)
+    beta1: float = 0.9
+    beta2: float = 0.999
+    eps: float = 1e-8
     accum_steps: int = 1
     warmup_epochs: int = 0
     label_smoothing: float = 0.0
@@ -86,6 +90,15 @@ class Config:
     # the reference topology. Env twin DPTPU_SLICES wins when set;
     # DPTPU_DCN_DTYPE=bf16 additionally compresses the DCN hop.
     slices: int = 1
+    # a token-sequence model (dptpu extension, all variants; ``-a`` names
+    # one, e.g. lfm2_8b_a1b): tokens in a row, and the share of the
+    # model this chip holds in an expert-parallel, vocabulary-parallel,
+    # pipelined deployment, each FIRST:COUNT over the published model.
+    # Empty/0 = the model's own (its default length, all of it).
+    seq_len: int = 0
+    layers: str = ""
+    experts: str = ""
+    vocab_rows: str = ""
     # distributed (ddp/nd; apex uses env:// exclusively)
     world_size: int = -1
     rank: int = -1
@@ -180,10 +193,34 @@ def build_parser(variant: str = "ddp", model_names=None) -> argparse.ArgumentPar
     # cosine LR, label smoothing. Env twins: DPTPU_OPT / DPTPU_ACCUM /
     # DPTPU_WARMUP_EPOCHS / DPTPU_LABEL_SMOOTH (env wins when set).
     p.add_argument("--optimizer", default="sgd",
-                   choices=("sgd", "lars", "lamb"),
-                   help="update rule: reference SGD (default), or the "
+                   choices=("sgd", "lars", "lamb", "adamw"),
+                   help="update rule: reference SGD (default), the "
                         "large-batch layer-wise trust-ratio optimizers "
-                        "LARS/LAMB")
+                        "LARS/LAMB, or AdamW (decoupled weight decay on "
+                        "matrices only; --beta1/--beta2/--eps, --wd)")
+    p.add_argument("--beta1", default=0.9, type=float, metavar="B",
+                   help="adamw: decay of the first moment")
+    p.add_argument("--beta2", default=0.999, type=float, metavar="B",
+                   help="adamw: decay of the second moment")
+    p.add_argument("--eps", default=1e-8, type=float, metavar="E",
+                   help="adamw: added to the root of the second moment")
+    # dptpu token-sequence extension (not reference flags): what a
+    # language model (-a lfm2_8b_a1b) needs beside the image flags
+    p.add_argument("--seq-len", default=0, type=int, metavar="S",
+                   help="tokens in a row of a token-sequence model "
+                        "(0 = the model's default)")
+    p.add_argument("--layers", default="", type=str, metavar="FIRST:COUNT",
+                   help="token-sequence models: the published layers this "
+                        "chip holds (a pipeline stage); empty = all")
+    p.add_argument("--experts", default="", type=str, metavar="FIRST:COUNT",
+                   help="token-sequence models: the experts of each expert "
+                        "layer this chip holds (expert parallelism: the "
+                        "router still routes over all); empty = all")
+    p.add_argument("--vocab-rows", default="", type=str,
+                   metavar="FIRST:COUNT",
+                   help="token-sequence models: the vocabulary rows this "
+                        "chip holds (ids, logits and loss are over them); "
+                        "empty = all")
     p.add_argument("--accum-steps", default=1, type=int, metavar="K",
                    help="gradient-accumulation microbatches per step: "
                         "each replica's batch splits into K fp32-"

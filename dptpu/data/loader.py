@@ -72,7 +72,9 @@ from dptpu.data.sampler import ShardedSampler
 
 
 class DataLoader:
-    """Batches of ``{"images": uint8 [B,H,W,C], "labels": int32 [B]}``.
+    """Batches of ``{"images": uint8 [B,H,W,C], "labels": int32 [B]}``,
+    or of whatever a dataset with a ``collate`` makes of its items
+    (``dptpu.data.tokens``: ``tokens``, ``labels``, ``mask``).
 
     Final-batch policy when the shard doesn't divide evenly:
       * ``drop_last=True`` — drop the remainder (train default in fit).
@@ -169,6 +171,10 @@ class DataLoader:
         # through the shm pipeline's pre-issue pump.
         self._prefetch_extents = getattr(dataset, "prefetch_extents", None)
         self._item_shape = None  # probed from the first sample
+        self._item_dtype = np.dtype(np.uint8)  # the probe's, likewise
+        # a dataset whose rows are not one image and one label (token
+        # rows) says how a batch is made of the items and their scalars
+        self._collate = getattr(dataset, "collate", None)
         self._probe = None  # owned-by: caller — (index, epoch, img, label) probe, consumed at submit time
         self._pipeline = None  # lazy shm ring (process mode)
         self._prev_cache_counts = (0, 0)  # feed_stats interval baseline
@@ -243,7 +249,7 @@ class DataLoader:
             # ``prefetch_batches`` from now, so the bytes land first
             self._prefetch_extents(batch_indices)
         out_size = self.batch_size if self.pad_final else n_valid
-        imgs = np.empty((out_size,) + self._item_shape, np.uint8)
+        imgs = np.empty((out_size,) + self._item_shape, self._item_dtype)
         labels = np.zeros((out_size,), np.int32)
         # the shape probe already decoded one sample of this epoch with
         # its exact rng: reuse it HERE, on the caller thread, so _probe
@@ -293,7 +299,6 @@ class DataLoader:
 
     def _assemble(self, imgs, labels, n_valid, valid=None):
         """Pad/mask policy shared by the thread and process backends."""
-        batch = {"images": imgs, "labels": labels}
         out_size = imgs.shape[0]
         # the eval mask flags positions an exact aggregation must skip:
         # batch-tail padding AND the sampler's wrap-around duplicates
@@ -307,11 +312,18 @@ class DataLoader:
         if n_valid < out_size:  # pad tail by repeating sample 0
             imgs[n_valid:] = imgs[0]
             labels[n_valid:] = labels[0]
+        mask = None
         if need_mask:
             mask = np.zeros((out_size,), np.float32)
             mask[:n_valid] = (
                 1.0 if valid is None else valid.astype(np.float32)
             )
+        if self._collate is not None:
+            # the dataset's own batch layout; the rows the mask leaves
+            # out are left out of whatever it counts
+            return self._collate(imgs, labels, mask)
+        batch = {"images": imgs, "labels": labels}
+        if mask is not None:
             batch["mask"] = mask
         return batch
 
@@ -347,6 +359,7 @@ class DataLoader:
             img, label = self._load_one(probe_idx, epoch)
             img = np.asarray(img)
             self._item_shape = img.shape
+            self._item_dtype = img.dtype
             self._probe = (probe_idx, epoch, img, label)
 
         ahead = 1 + max(0, prefetch_batches)
@@ -580,6 +593,7 @@ class DataLoader:
                 speculate=self.speculate,
                 speculate_after_s=self.speculate_after_s,
                 readahead=self.readahead,
+                item_dtype=self._item_dtype,
             )
             # fresh workers count from zero: re-baseline the interval
             # hit-rate bookkeeping in feed_stats

@@ -232,7 +232,8 @@ def _contiguous_spans(batch_indices, num_workers: int):
 
 
 def _worker_main(worker_id, dataset, imgs_name, labels_name, slots,
-                 batch_size, item_shape, seed, num_workers, task_q, res_q):
+                 batch_size, item_shape, seed, num_workers, task_q, res_q,
+                 item_dtype="uint8"):
     """Decode-worker loop: pull ``(slot, task, offsets, indices, epoch)``
     spans from THIS worker's queue, write pixels/labels straight into the
     shared ring, ack on ``res_q``.
@@ -250,8 +251,8 @@ def _worker_main(worker_id, dataset, imgs_name, labels_name, slots,
     # the segments if the parent dies uncleanly.
     shm_imgs = shared_memory.SharedMemory(name=imgs_name)
     shm_labels = shared_memory.SharedMemory(name=labels_name)
-    imgs = np.ndarray((slots, batch_size) + tuple(item_shape), np.uint8,
-                      buffer=shm_imgs.buf)
+    imgs = np.ndarray((slots, batch_size) + tuple(item_shape),
+                      np.dtype(item_dtype), buffer=shm_imgs.buf)
     labels = np.ndarray((slots, batch_size), np.int32,
                         buffer=shm_labels.buf)
     cache = getattr(dataset, "decode_cache", None)
@@ -363,11 +364,15 @@ class ShmBatchPipeline:
                  span_affinity: bool = True,
                  speculate: bool = True,
                  speculate_after_s: float = 0.5,
-                 readahead: bool = True):
+                 readahead: bool = True,
+                 item_dtype="uint8"):
         import multiprocessing as mp
 
         self.batch_size = batch_size
         self.item_shape = tuple(int(d) for d in item_shape)
+        # uint8 pixels, or what a row of another kind is made of (int32
+        # token ids): the ring holds items of one shape and one dtype
+        self.item_dtype = np.dtype(item_dtype)
         self.num_workers = max(1, num_workers)
         self.slots = max(2, slots)
         self.span_affinity = span_affinity
@@ -403,7 +408,7 @@ class ShmBatchPipeline:
             raise ValueError(
                 "DPTPU_POOL_RESTARTS and DPTPU_SPAN_RETRIES must be >= 0"
             )
-        item_bytes = int(np.prod(self.item_shape))
+        item_bytes = int(np.prod(self.item_shape)) * self.item_dtype.itemsize
         self._ctx = mp.get_context(mp_start)
         self._shm_imgs = create_named_segment(
             SEGMENT_PREFIX,
@@ -413,7 +418,7 @@ class ShmBatchPipeline:
             SEGMENT_PREFIX, self.slots * batch_size * 4
         )
         self._imgs = np.ndarray(
-            (self.slots, batch_size) + self.item_shape, np.uint8,
+            (self.slots, batch_size) + self.item_shape, self.item_dtype,
             buffer=self._shm_imgs.buf,
         )
         self._labels = np.ndarray(
@@ -498,7 +503,7 @@ class ShmBatchPipeline:
                 args=(wid, self._dataset, self._shm_imgs.name,
                       self._shm_labels.name, self.slots, self.batch_size,
                       self.item_shape, self._seed, self.num_workers,
-                      self._task_qs[wid], self._res_q),
+                      self._task_qs[wid], self._res_q, self.item_dtype.str),
                 daemon=True,
                 name=f"dptpu-data-{wid}",
             )

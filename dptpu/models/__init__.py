@@ -11,6 +11,7 @@ from dptpu.models import densenet as _densenet  # noqa: F401
 from dptpu.models import efficientnet as _efficientnet  # noqa: F401
 from dptpu.models import googlenet as _googlenet  # noqa: F401
 from dptpu.models import inception as _inception  # noqa: F401
+from dptpu.models import lfm2 as _lfm2  # noqa: F401
 from dptpu.models import maxvit as _maxvit  # noqa: F401
 from dptpu.models import mnasnet as _mnasnet  # noqa: F401
 from dptpu.models import mobilenet as _mobilenet  # noqa: F401
@@ -22,6 +23,11 @@ from dptpu.models import squeezenet as _squeezenet  # noqa: F401
 from dptpu.models import swin as _swin  # noqa: F401
 from dptpu.models import vgg as _vgg  # noqa: F401
 from dptpu.models import vit as _vit  # noqa: F401
-from dptpu.models.registry import create_model, model_names, register_model
+from dptpu.models.registry import (
+    create_model,
+    model_names,
+    model_task,
+    register_model,
+)
 
-__all__ = ["create_model", "model_names", "register_model"]
+__all__ = ["create_model", "model_names", "model_task", "register_model"]

@@ -354,12 +354,55 @@ def _torch_module(arch: str, mod: Tuple[str, ...]) -> str:
     raise ValueError(f"no torchvision key mapping for arch {arch!r}")
 
 
+def _lfm2_key_map(variables):
+    """The ``lfm2_moe`` checkpoint's names (``model.embed_tokens``,
+    ``model.layers.N.{operator_norm, ffn_norm}``, ``.conv.{in_proj, conv,
+    out_proj}``, ``.self_attn.{q_proj, k_proj, v_proj, out_proj,
+    q_layernorm, k_layernorm}``, ``.feed_forward.{w1, w2, w3}``,
+    ``.feed_forward.{gate, expert_bias}``,
+    ``.feed_forward.experts.E.{w1, w2, w3}``, ``model.embedding_norm``;
+    written from memory of the published layout, there is no network
+    here). dptpu/models/lfm2.py names its modules after them, so the map
+    is mechanical: ``layers_N`` <-> ``layers.N``, ``experts_E`` <->
+    ``experts.E``; every matrix is a torch Linear (OI <-> IO), the short
+    convolution's taps are torch's depthwise ``[channels, 1, taps]``
+    <-> ``[taps, channels]``, and ``expert_bias`` is a buffer with no
+    ``.weight``."""
+    out = {}
+    for collection in ("params", "batch_stats"):
+        flat = jax.tree_util.tree_flatten_with_path(
+            variables.get(collection, {}))[0]
+        for path, leaf in flat:
+            names = tuple(p.key for p in path)
+            mods = [n.replace("layers_", "layers.").replace(
+                "experts_", "experts.") for n in names]
+            module = "model." + ".".join(mods[:-1])  # the leaf's module
+            itself = "model." + ".".join(mods)  # a raw torch Parameter
+            if names[-1] == "expert_bias":
+                key, kind = itself, "direct"
+            elif names[-1] == "kernel":
+                key, kind = module + ".weight", "dense"
+            elif names[-1] in ("scale", "embedding"):
+                key, kind = module + ".weight", "direct"
+            elif names[-2:] == ("conv", "conv"):
+                key, kind = itself + ".weight", "conv1d_dw"
+            elif leaf.ndim == 2:  # gate, an expert's w1/w2/w3
+                key, kind = itself + ".weight", "dense"
+            else:
+                raise ValueError(f"no lfm2_moe key for {'/'.join(names)}")
+            assert key not in out, f"duplicate torch key {key}"
+            out[key] = (collection, names, kind)
+    return out
+
+
 def torch_key_map(arch: str, variables) -> Dict[str, Tuple[str, Tuple[str, ...], str]]:
     """``{torch_key: (collection, dptpu_path, kind)}`` for every leaf.
 
     ``kind`` is ``conv`` (4-D kernel, needs OIHW->HWIO), ``dense`` (2-D
     kernel, needs OI->IO) or ``direct``.
     """
+    if arch.startswith("lfm2"):
+        return _lfm2_key_map(variables)
     out = {}
     for collection in ("params", "batch_stats"):
         tree = variables.get(collection, {})
@@ -429,6 +472,8 @@ def _from_torch(arr: np.ndarray, kind) -> np.ndarray:
         ).reshape(h * w * c, o)
     if kind == "layer_scale":
         return arr.reshape(-1)  # torch (C,1,1) -> NHWC (C,)
+    if kind == "conv1d_dw":
+        return np.transpose(arr[:, 0, :], (1, 0))  # (C,1,L) -> (L,C)
     if isinstance(kind, tuple) and kind[0] == "vit_qkv":
         _, heads, leaf = kind
         if leaf == "kernel":
@@ -451,6 +496,8 @@ def _to_torch(arr: np.ndarray, kind) -> np.ndarray:
         ).reshape(o, c * h * w)
     if kind == "layer_scale":
         return arr.reshape(-1, 1, 1)  # NHWC (C,) -> torch (C,1,1)
+    if kind == "conv1d_dw":
+        return np.transpose(arr, (1, 0))[:, None, :]  # (L,C) -> (C,1,L)
     if isinstance(kind, tuple) and kind[0] == "vit_qkv":
         _, heads, leaf = kind
         arr = qkv_permute(arr, heads, to_head_major=False)
@@ -655,7 +702,8 @@ def require_weights(arch: str) -> str:
     return path
 
 
-def load_pretrained_variables(arch: str, model, input_shape=(1, 224, 224, 3)):
+def load_pretrained_variables(arch: str, model, input_shape=(1, 224, 224, 3),
+                              input_dtype=np.float32):
     """Load converted weights for ``arch`` and validate against ``model``.
 
     The pytree structure must match the model's own ``init`` exactly
@@ -668,9 +716,11 @@ def load_pretrained_variables(arch: str, model, input_shape=(1, 224, 224, 3)):
         # converted before this family's head-major qkv switch: same
         # shapes, permuted columns — migrate silently-correctly
         loaded = _qkv_to_head_major(arch, loaded)
-    template = model.init(
-        jax.random.PRNGKey(0), np.zeros(input_shape, np.float32), train=False
-    )
+    # shapes only: nothing is initialised to be thrown away (a
+    # half-billion-parameter template would be 2 GB of it)
+    template = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), np.zeros(input_shape, input_dtype), train=False
+    ))
     t_struct = jax.tree_util.tree_structure(
         {"params": template["params"],
          "batch_stats": template.get("batch_stats", {})}

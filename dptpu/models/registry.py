@@ -142,6 +142,17 @@ def model_names():
     return sorted(_REGISTRY)
 
 
+def model_task(name) -> str:
+    """What ``fit()`` trains ``name`` on: ``"images"`` (rows of one image
+    and one label; every torchvision architecture) or ``"tokens"`` (rows
+    of token ids with next-token labels and a loss mask). A factory says
+    so by a ``task`` attribute; the data source, the step's loss and the
+    arguments the factory is handed follow from it."""
+    if name not in _REGISTRY:
+        raise KeyError(f"unknown architecture {name!r}; choices: {model_names()}")
+    return getattr(_REGISTRY[name], "task", "images")
+
+
 def create_model(name, pretrained=False, **kwargs):
     """``models.__dict__[arch](pretrained=...)`` analog (imagenet_ddp.py:108-114).
 

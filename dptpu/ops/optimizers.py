@@ -179,6 +179,24 @@ def lamb(
     )
 
 
+def adamw(
+    b1: float = 0.9,
+    b2: float = 0.999,
+    eps: float = 1e-8,
+    weight_decay: float = 1e-2,
+) -> optax.GradientTransformation:
+    """AdamW direction (decoupled weight decay, arXiv:1711.05101),
+    WITHOUT the learning rate: bias-corrected Adam moments, then
+    ``+ wd * p`` on the skip list's members (matrices; norms' scales and
+    other vectors decay nowhere). The train step's ``p -= lr * u`` makes
+    it torch ``AdamW``'s ``p -= lr * (m_hat / (sqrt(v_hat) + eps) +
+    wd * p)``. Elementwise, so it needs no ``sumsq_reduce``."""
+    return optax.chain(
+        optax.scale_by_adam(b1=b1, b2=b2, eps=eps),
+        optax.masked(optax.add_decayed_weights(weight_decay), trust_mask),
+    )
+
+
 def trust_ratio_stats(opt_state):
     """Extract the ``ScaleByTrustRatioState`` summary from an optimizer
     state tree, or None when the optimizer has no trust-ratio stage

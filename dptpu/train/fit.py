@@ -36,7 +36,7 @@ from dptpu.data import (
     train_transform,
     val_transform,
 )
-from dptpu.models import create_model
+from dptpu.models import create_model, model_task
 from dptpu.ops.schedules import (
     make_step_decay_schedule,
     make_warmup_cosine_schedule,
@@ -113,6 +113,46 @@ def _shard_source(data: str):
     if os.path.exists(os.path.join(data, "train", MANIFEST_NAME)):
         return os.path.join(data, "train"), os.path.join(data, "val")
     return None
+
+
+def _token_model_kwargs(cfg: Config, task: str) -> dict:
+    """The arguments a token-sequence model's factory takes from the
+    command line (``--seq-len``, ``--layers``, ``--experts``,
+    ``--vocab-rows``); an image model is handed none of them and a run
+    that gives it one fails here, before anything is built."""
+    given = {"sequence_length": cfg.seq_len or None,
+             "layers": cfg.layers or None, "experts": cfg.experts or None,
+             "vocab": cfg.vocab_rows or None}
+    given = {k: v for k, v in given.items() if v is not None}
+    if task != "tokens" and given:
+        raise ValueError(
+            f"--seq-len/--layers/--experts/--vocab-rows are a "
+            f"token-sequence model's arguments, and '{cfg.arch}' is "
+            f"trained on images"
+        )
+    return given
+
+
+def _build_token_datasets(cfg: Config, task: str, model_config):
+    """``tokens:<N>[@first]`` for a token-sequence model: rows of the
+    model's sequence length over the vocabulary rows it holds;
+    validation on the N/10 rows behind the training rows."""
+    from dptpu.data.tokens import TokenDataset, parse_source
+
+    source = parse_source(cfg.data)
+    if task != "tokens" or source is None:
+        raise ValueError(
+            f"'{cfg.arch}' is trained on "
+            + ("token rows: give tokens:<N> as the data source, not "
+               f"{cfg.data!r}" if task == "tokens" else
+               f"images, and {cfg.data!r} is a source of token rows "
+               f"(for a token-sequence model such as lfm2_8b_a1b)")
+        )
+    rows, first = source
+    length, vocab = model_config.sequence_length, model_config.vocab_size
+    return (TokenDataset(rows, length, vocab, first),
+            TokenDataset(max(rows // 10, 1), length, vocab, first + rows),
+            vocab)
 
 
 def _build_datasets(cfg: Config, image_size: int, cache_bytes: int = 0,
@@ -200,7 +240,7 @@ def _opt_knobs(cfg: Config) -> tuple:
     programmatically get the identical validation as env values:
 
     * ``DPTPU_OPT`` / ``--optimizer`` — ``sgd`` (reference), ``lars``,
-      ``lamb`` (dptpu/ops/optimizers.py);
+      ``lamb``, ``adamw`` (dptpu/ops/optimizers.py);
     * ``DPTPU_ACCUM`` / ``--accum-steps`` — microbatches per update,
       >= 1 (1 = the exact unaccumulated step);
     * ``DPTPU_WARMUP_EPOCHS`` / ``--warmup-epochs`` — > 0 selects the
@@ -209,12 +249,14 @@ def _opt_knobs(cfg: Config) -> tuple:
     """
     from dptpu.envknob import env_choice, env_float, env_int
 
-    name = env_choice("DPTPU_OPT", ("sgd", "lars", "lamb"))
+    names = ("sgd", "lars", "lamb", "adamw")
+    name = env_choice("DPTPU_OPT", names)
     if name is None:
         name = cfg.optimizer
-        if name not in ("sgd", "lars", "lamb"):
+        if name not in names:
             raise ValueError(
-                f"--optimizer {name!r} must be one of 'sgd'/'lars'/'lamb'"
+                f"--optimizer {name!r} must be one of "
+                + "/".join(repr(n) for n in names)
             )
     accum = env_int("DPTPU_ACCUM", None)
     if accum is None:
@@ -316,6 +358,10 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
     # large-batch engine knobs (optimizer / accumulation / warmup /
     # smoothing) fail fast pre-compile under the same locked contract
     opt_name, accum_steps, warmup_epochs, label_smooth = _opt_knobs(cfg)
+    # what the model is trained on decides the data source, the step's
+    # loss and what its factory is handed (dptpu/models/registry.py)
+    task = model_task(cfg.arch)
+    token_kwargs = _token_model_kwargs(cfg, task)
     # hierarchical-comms knobs (--slices/DPTPU_SLICES, DPTPU_DCN_DTYPE)
     # fail fast pre-compile too; divisibility is checked against the
     # device count once the mesh is factored below
@@ -640,9 +686,19 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
             # after a failed build (dptpu/native/build.py says why)
             + f", native={native_image.available()}"
         )
-    train_ds, val_ds, num_classes = _build_datasets(
-        cfg, image_size, cache_bytes=cache_bytes, cache_scope=cache_scope
-    )
+    if task == "tokens" or cfg.data.startswith("tokens"):
+        # the rows' length and the ids' range are the model's: its
+        # configuration is read before any weight is made
+        train_ds, val_ds, num_classes = _build_token_datasets(
+            cfg, task,
+            create_model(cfg.arch, **token_kwargs).config
+            if task == "tokens" else None,
+        )
+    else:
+        train_ds, val_ds, num_classes = _build_datasets(
+            cfg, image_size, cache_bytes=cache_bytes,
+            cache_scope=cache_scope
+        )
 
     # per-host loaders over disjoint shards (DistributedSampler contract);
     # batches are per-HOST (global batch = per_host × hosts).
@@ -839,6 +895,14 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
         and mesh is not None and not cfg.evaluate
         and not use_zero3 and not use_zero1 and not use_sp
     )
+    if task == "tokens" and (use_tp or use_sp or use_zero3 or use_zero1
+                             or use_gspmd or batch_ramp is not None):
+        raise ValueError(
+            f"'{cfg.arch}' is a token-sequence model: it trains on the "
+            f"replicated data-parallel step only (one chip, or "
+            f"--slices over a data mesh); unset DPTPU_TP / DPTPU_SP / "
+            f"DPTPU_ZERO* / DPTPU_FSDP / DPTPU_GSPMD / DPTPU_BATCH_RAMP"
+        )
     if want_gspmd and use_sp and verbose:
         print("=> DPTPU_GSPMD ignored: DPTPU_SP drives the "
               "sequence-parallel step")
@@ -937,7 +1001,13 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
 
         _bn_axis = squeeze_axes(data_axis_names(mesh))
     _setup_phase("model_init")
+    # a factory takes what applies to it and is not handed the rest: a
+    # token-sequence model its share and length, an image model the
+    # classes and the BatchNorm policy
     model = create_model(
+        cfg.arch, pretrained=cfg.pretrained, dtype=compute_dtype,
+        **token_kwargs,
+    ) if task == "tokens" else create_model(
         cfg.arch,
         pretrained=cfg.pretrained,
         num_classes=num_classes,
@@ -992,7 +1062,9 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
         schedule = make_warmup_step_decay_schedule(sched_lr, steps_per_epoch)
     else:
         schedule = make_step_decay_schedule(sched_lr, steps_per_epoch)
-    tx = make_optimizer(cfg.momentum, cfg.weight_decay, name=opt_name)
+    adam_kw = {"betas": (cfg.beta1, cfg.beta2), "eps": cfg.eps}
+    tx = make_optimizer(cfg.momentum, cfg.weight_decay, name=opt_name,
+                        **adam_kw)
     if verbose and (opt_name != "sgd" or accum_steps > 1 or warmup_epochs
                     or label_smooth):
         print(
@@ -1006,6 +1078,13 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
             + f", label smoothing {label_smooth}"
         )
     rng = jax.random.PRNGKey(cfg.seed if cfg.seed is not None else 0)
+    # one row as ``model.init`` takes it: an image, or a row of token ids
+    # of the model's own length
+    input_shape, input_dtype = (
+        ((1, model.config.sequence_length), jnp.int32)
+        if task == "tokens"
+        else ((1, image_size, image_size, 3), jnp.float32)
+    )
     pretrained_vars = None
     if cfg.pretrained:
         # converted-torchvision weights (imagenet_ddp.py:109-111); see
@@ -1017,7 +1096,8 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
         # compiles show inside this phase
         _setup_phase("pretrained")
         pretrained_vars = load_pretrained_variables(
-            cfg.arch, model, input_shape=(1, image_size, image_size, 3)
+            cfg.arch, model, input_shape=input_shape,
+            input_dtype=input_dtype,
         )
         if verbose:
             print(f"=> using pre-trained model '{cfg.arch}'")
@@ -1026,7 +1106,8 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
         rng,
         model,
         tx,
-        input_shape=(1, image_size, image_size, 3),
+        input_shape=input_shape,
+        input_dtype=input_dtype,
         # --start-epoch without --resume still lands on the reference's
         # epoch-N learning rate (the schedule reads the global step);
         # under a batch ramp the offset is the cumulative step count
@@ -1301,7 +1382,7 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
                 accum_steps=accum_steps, label_smoothing=label_smooth,
                 tx_factory=partial(
                     make_optimizer, cfg.momentum, cfg.weight_decay,
-                    opt_name
+                    opt_name, **adam_kw
                 ),
                 dcn_dtype=dcn_dtype if use_hier else "fp32",
                 overlap=use_overlap, bucket_bytes=bucket_bytes,
@@ -1340,7 +1421,7 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
                 accum_steps=accum_steps, label_smoothing=label_smooth,
                 tx_factory=partial(
                     make_optimizer, cfg.momentum, cfg.weight_decay,
-                    opt_name
+                    opt_name, **adam_kw
                 ),
                 dcn_dtype=dcn_dtype if use_hier else "fp32",
                 overlap=use_overlap, bucket_bytes=bucket_bytes,
@@ -1457,6 +1538,7 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
                 accum_steps=accum_steps, label_smoothing=label_smooth,
                 dcn_dtype=dcn_dtype if use_hier else "fp32",
                 overlap=use_overlap, bucket_bytes=bucket_bytes,
+                task=task,
             )
 
         train_step = _build_train_step(schedule)
@@ -1474,7 +1556,7 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
             state = (put(state) if single_device
                      else jax.device_put(state, replicated_sharding(mesh)))
             _setup_phase("step_build")
-    eval_step = make_eval_step(mesh, compute_dtype)
+    eval_step = make_eval_step(mesh, compute_dtype, task=task)
 
     if cfg.evaluate:
         stats = validate(
@@ -2121,6 +2203,24 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
                     scalars[tag] = train_stats[key]
             if opt_shard_bytes is not None:
                 scalars["Opt/update_shard_bytes"] = opt_shard_bytes
+            # expert layers (a model that has them): the load of the
+            # experts held here, a step and a layer, over the epoch
+            for tag, key in (
+                ("Moe/load_max", "moe_load_max"),
+                ("Moe/load_mean", "moe_load_mean"),
+                ("Moe/local_slot_share", "moe_local_slot_share"),
+                ("Moe/dropped_tokens", "moe_dropped"),
+            ):
+                if key in train_stats:
+                    scalars[tag] = train_stats[key]
+            if verbose and "moe_load_max" in train_stats:
+                print(
+                    "Moe: busiest held expert {moe_load_max:.1f} tokens a "
+                    "layer a step (mean {moe_load_mean:.1f}), "
+                    "{moe_local_slot_share:.2f}% of the routed slots on "
+                    "held experts, {moe_dropped:.0f} tokens "
+                    "dropped".format(**train_stats)
+                )
             if obs_report is not None:
                 scalars.update({
                     "Obs/data_wait_s": obs_report["data_wait_s"],

@@ -45,6 +45,55 @@ from dptpu.utils.meters import AverageMeter, ProgressMeter, Summary
 MAX_IN_FLIGHT = 2
 
 
+class MoeLoad:
+    """The expert layers' load over the steps fetched so far, for a
+    model whose step reports it (``moe_counts`` ``[layers, experts
+    held]``, ``moe_slots``, ``moe_dropped``): per step and layer the
+    tokens at the busiest held expert and at the mean one, the routed
+    slots that fell on held experts, the tokens dropped. ``take`` gives
+    what one fetch adds as the ``fetch`` span's attributes; ``stats``
+    the epoch's averages."""
+
+    def __init__(self):
+        self.steps = 0
+        self.load_max = self.load_mean = 0.0
+        self.local = self.slots = self.dropped = 0
+
+    def take(self, fetched) -> dict:
+        """``fetched``: the metrics of the steps one fetch read. Empty
+        where the model has no experts."""
+        steps = [m for m in fetched if "moe_counts" in m]
+        if not steps:
+            return {}
+        counts = [np.asarray(m["moe_counts"], np.float64) for m in steps]
+        add = {
+            "moe_load_max": float(np.mean([c.max(axis=1).mean()
+                                           for c in counts])),
+            "moe_load_mean": float(np.mean([c.mean() for c in counts])),
+            "moe_local_slots": int(sum(c.sum() for c in counts)),
+            "moe_slots": int(sum(int(m["moe_slots"]) for m in steps)),
+            "moe_dropped": int(sum(int(m["moe_dropped"]) for m in steps)),
+        }
+        n = len(steps)
+        self.steps += n
+        self.load_max += add["moe_load_max"] * n
+        self.load_mean += add["moe_load_mean"] * n
+        self.local += add["moe_local_slots"]
+        self.slots += add["moe_slots"]
+        self.dropped += add["moe_dropped"]
+        return add
+
+    def stats(self) -> dict:
+        if not self.steps:
+            return {}
+        return {
+            "moe_load_max": self.load_max / self.steps,
+            "moe_load_mean": self.load_mean / self.steps,
+            "moe_local_slot_share": 100.0 * self.local / max(self.slots, 1),
+            "moe_dropped": float(self.dropped),
+        }
+
+
 def _landed(x) -> bool:
     """Whether a device array has landed (host values always have)."""
     is_ready = getattr(x, "is_ready", None)
@@ -110,6 +159,7 @@ def train_one_epoch(
     # reported like lr — absent keys mean a plain-SGD step
     opt_last = {}
     _TRUST_KEYS = ("trust_min", "trust_mean", "trust_max")
+    moe_load = MoeLoad()
     steps_done = start_step  # batches of THIS epoch consumed so far
     preempted = False
     # step-phase spans (dptpu/obs): data_wait / step / fetch / ckpt plus
@@ -170,7 +220,9 @@ def train_one_epoch(
                     if _landed(m["loss"]):
                         break
                     inflight += 1
-                input_ready = _landed(batch["images"])
+                input_ready = _landed(
+                    batch["images"] if "images" in batch
+                    else batch["tokens"])
             n = int(np.prod(batch["labels"].shape))
             state, metrics = train_step(state, batch)
             if traced:
@@ -198,8 +250,9 @@ def train_one_epoch(
                 cut = max(len(pending) - lag, 0)
                 ready, pending = pending[:cut], pending[cut:]
                 t_fetch = pc()
-                for m, nb in jax.device_get(  # dptpu: allow-host-sync(the ONE lagged fetch per print interval — it reads steps the bounded run-ahead has already seen land; the newest MAX_IN_FLIGHT stay in flight)
-                        [(p[0], p[1]) for p in ready]):
+                fetched = jax.device_get(  # dptpu: allow-host-sync(the ONE lagged fetch per print interval — it reads steps the bounded run-ahead has already seen land; the newest MAX_IN_FLIGHT stay in flight)
+                    [(p[0], p[1]) for p in ready])
+                for m, nb in fetched:
                     losses.update(float(m["loss"]), nb)
                     top1.update(float(m["top1"]), nb)
                     top5.update(float(m["top5"]), nb)
@@ -208,7 +261,9 @@ def train_one_epoch(
                         if tk in m:
                             opt_last[tk] = float(m[tk])
                 tracer.record("fetch", t_fetch, pc() - t_fetch,
-                              step=steps_done - 1)
+                              step=steps_done - 1,
+                              attrs=moe_load.take([m for m, _ in fetched])
+                              or None)
                 batch_time.update(time.time() - end)
                 if verbose:
                     progress.display(i + start_step)
@@ -250,7 +305,8 @@ def train_one_epoch(
                 pass
         raise
     t_fetch = pc()
-    for m, nb in jax.device_get(pending):  # dptpu: allow-host-sync(epoch-tail drain: the last un-fetched steps sync once, after the loop)
+    fetched = jax.device_get(pending)  # dptpu: allow-host-sync(epoch-tail drain: the last un-fetched steps sync once, after the loop)
+    for m, nb in fetched:
         losses.update(float(m["loss"]), nb)
         top1.update(float(m["top1"]), nb)
         top5.update(float(m["top5"]), nb)
@@ -261,7 +317,8 @@ def train_one_epoch(
     if pending:
         # the epoch-tail sync: the last un-fetched steps drain here
         tracer.record("fetch", t_fetch, pc() - t_fetch,
-                      step=steps_done - 1)
+                      step=steps_done - 1,
+                      attrs=moe_load.take([m for m, _ in fetched]) or None)
     stats = {
         "loss": losses.avg,
         "top1": top1.avg,
@@ -278,6 +335,7 @@ def train_one_epoch(
         "steps_done": steps_done,
         "preempted": preempted,
         **opt_last,
+        **moe_load.stats(),
     }
     if feed_stats is not None:
         for k, v in feed_stats().items():

@@ -30,6 +30,8 @@ def make_optimizer(
     weight_decay: float = 1e-4,
     name: str = "sgd",
     sumsq_reduce=None,
+    betas=(0.9, 0.999),
+    eps: float = 1e-8,
 ) -> optax.GradientTransformation:
     """Build the lr-less optimizer direction chain.
 
@@ -44,6 +46,11 @@ def make_optimizer(
       optimizers (dptpu/ops/optimizers.py); these follow their papers'
       skip list instead (no decay/trust on ndim<2 leaves). ``momentum``
       feeds LARS's momentum; LAMB keeps its Adam betas.
+    * ``adamw`` — Adam's bias-corrected moments (``betas``, ``eps``)
+      with DECOUPLED weight decay (``p -= lr·(m̂/(√v̂ + eps) + wd·p)``,
+      torch ``AdamW``), the decay on the same skip list: matrices only,
+      not norms' scales or other vectors. Two moments: 16 bytes a
+      parameter with the float32 parameter and gradient.
 
     Every chain yields the un-scaled direction; the train step
     multiplies by ``-lr(state.step)`` itself (torch's
@@ -73,8 +80,14 @@ def make_optimizer(
         from dptpu.ops.optimizers import lamb
 
         return lamb(weight_decay=weight_decay, sumsq_reduce=sumsq_reduce)
+    if name == "adamw":
+        from dptpu.ops.optimizers import adamw
+
+        return adamw(b1=betas[0], b2=betas[1], eps=eps,
+                     weight_decay=weight_decay)
     raise ValueError(
-        f"unknown optimizer {name!r}: expected 'sgd', 'lars' or 'lamb'"
+        f"unknown optimizer {name!r}: expected 'sgd', 'lars', 'lamb' "
+        f"or 'adamw'"
     )
 
 

@@ -21,6 +21,7 @@ XLA way (fused into the first conv's input, zero extra HBM round-trips, and
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional
 
 import jax
@@ -112,11 +113,51 @@ def normalize_images(images, dtype=jnp.float32):
     return images.astype(dtype)
 
 
+def token_row_weights(mask):
+    """Each kept token's weight in its row's mean: a row weighs the same
+    however many of its tokens the mask keeps, so the loss of a batch is
+    the mean over rows of the row's mean over kept tokens, and does not
+    depend on how rows are grouped into shards or blocks."""
+    kept = mask.astype(jnp.float32)
+    return kept / jnp.maximum(jnp.sum(kept, axis=-1, keepdims=True), 1.0)
+
+
+def token_loss_and_grads(state, batch, denom, gather_params=None,
+                         wrap_params=None):
+    """The token task's half of the step: the model's per-token
+    cross-entropy (float32, over row blocks of the head: dptpu/ops/loss.py)
+    as the mean over this shard's rows, its gradient over ``denom``
+    (the replicas, as the image loss), top-1/top-5 over kept tokens, and
+    the expert layers' load if the model has any. Returns
+    ``((loss, top1, top5, batch_stats, moe), grads)``."""
+    rows = batch["tokens"].shape[0]
+    weights = token_row_weights(batch["mask"])
+
+    def loss_fn(params):
+        if wrap_params is not None:
+            params = wrap_params(params)
+        full = gather_params(params) if gather_params else params
+        sums, mutated = state.apply_fn(
+            {"params": full, "batch_stats": state.batch_stats},
+            batch["tokens"], train=True, labels=batch["labels"],
+            mask=weights, mutable=["batch_stats"],
+        )
+        local_loss = sums["loss_sum"] / rows
+        return local_loss / denom, (
+            local_loss, sums, mutated.get("batch_stats", state.batch_stats))
+
+    (_, (loss, sums, new_stats)), grads = jax.value_and_grad(
+        loss_fn, has_aux=True)(state.params)
+    moe = {k: v for k, v in sums.items() if k.startswith("moe_")}
+    return (loss, sums["correct1"] / rows, sums["correct5"] / rows,
+            new_stats, moe), grads
+
+
 def train_step_body(state, batch, *, compute_dtype, lr_schedule, seed,
                     axis_size, on_mesh, gather_params=None,
                     reduce_grads=None, tx=None, accum_steps=1,
                     label_smoothing=0.0, axis_names=(DATA_AXIS,),
-                    overlap_plan=None):
+                    overlap_plan=None, task="images"):
     """The shared per-shard train-step math — ONE source of truth for the
     DDP step below, the ZeRO-1 step (dptpu/parallel/zero.py) and the
     GSPMD step (dptpu/parallel/gspmd.py), which differ only in their
@@ -204,7 +245,16 @@ def train_step_body(state, batch, *, compute_dtype, lr_schedule, seed,
         )
         return aux, grads
 
-    if accum_steps == 1:
+    moe = {}
+    if task == "tokens":
+        if accum_steps != 1 or label_smoothing:
+            raise ValueError(
+                "a token-sequence model trains without --accum-steps and "
+                "--label-smoothing: the per-token loss has no microbatch "
+                "scan and no smoothed target yet")
+        (loss, top1, top5, new_stats, moe), grads = token_loss_and_grads(
+            state, batch, axis_size, gather_params, wrap_params)
+    elif accum_steps == 1:
         dropout_key = step_key
         if on_mesh:
             dropout_key = jax.random.fold_in(
@@ -283,14 +333,19 @@ def train_step_body(state, batch, *, compute_dtype, lr_schedule, seed,
         new_stats, loss, top1, top5 = lax.pmean(
             (new_stats, loss, top1, top5), pmean_axes
         )
+        moe = lax.psum(moe, pmean_axes)  # counts add up over the replicas
     # SGD's chain is elementwise, so it is equally valid on full params
     # (DDP) and ZeRO-1 shard-local slices; LARS/LAMB additionally need
     # per-layer norms, which the injected `tx`'s sumsq_reduce completes
     # across shards with one small psum (dptpu/ops/optimizers.py)
-    direction, new_opt = tx.update(grads, state.opt_state, state.params)
-    lr = lr_schedule(state.step)
-    updates = jax.tree_util.tree_map(lambda u: -lr * u, direction)
-    params = optax.apply_updates(state.params, updates)
+    # named for the device trace in the step that names its other parts
+    # (the image step's program stays as it was, metadata included)
+    with jax.named_scope("optimizer") if task == "tokens" \
+            else contextlib.nullcontext():
+        direction, new_opt = tx.update(grads, state.opt_state, state.params)
+        lr = lr_schedule(state.step)
+        updates = jax.tree_util.tree_map(lambda u: -lr * u, direction)
+        params = optax.apply_updates(state.params, updates)
     new_state = state.replace(
         step=state.step + 1,
         params=params,
@@ -302,6 +357,7 @@ def train_step_body(state, batch, *, compute_dtype, lr_schedule, seed,
         "top1": top1 * 100.0,
         "top5": top5 * 100.0,
         "lr": jnp.asarray(lr, jnp.float32),
+        **moe,
     }
     tstats = trust_ratio_stats(new_opt)
     if tstats is not None:
@@ -317,7 +373,8 @@ def train_step_body(state, batch, *, compute_dtype, lr_schedule, seed,
 def make_train_step(mesh: Optional[Mesh] = None, compute_dtype=jnp.float32,
                     lr_schedule=None, seed: int = 0, accum_steps: int = 1,
                     label_smoothing: float = 0.0, dcn_dtype: str = "fp32",
-                    overlap: bool = False, bucket_bytes: Optional[int] = None):
+                    overlap: bool = False, bucket_bytes: Optional[int] = None,
+                    task: str = "images"):
     """Build the jitted train step.
 
     Returns ``step(state, batch) -> (state, metrics)`` where ``batch`` is a
@@ -406,7 +463,7 @@ def make_train_step(mesh: Optional[Mesh] = None, compute_dtype=jnp.float32,
             lr_schedule=lr_schedule, seed=seed, axis_size=axis_size,
             on_mesh=mesh is not None, reduce_grads=reduce_grads,
             accum_steps=accum_steps, label_smoothing=label_smoothing,
-            axis_names=axis_names, overlap_plan=overlap_plan,
+            axis_names=axis_names, overlap_plan=overlap_plan, task=task,
         )
 
     opts = tpu_compiler_options()
@@ -422,7 +479,8 @@ def make_train_step(mesh: Optional[Mesh] = None, compute_dtype=jnp.float32,
     return jax.jit(sharded, donate_argnums=0, compiler_options=opts)
 
 
-def make_eval_step(mesh: Optional[Mesh] = None, compute_dtype=jnp.float32):
+def make_eval_step(mesh: Optional[Mesh] = None, compute_dtype=jnp.float32,
+                   task: str = "images"):
     """Build the jitted eval step.
 
     Returns ``eval_step(state, batch) -> sums`` with ``loss_sum``,
@@ -431,7 +489,23 @@ def make_eval_step(mesh: Optional[Mesh] = None, compute_dtype=jnp.float32):
     all-reduce behavior of the Apex path (imagenet_ddp_apex.py:232-234,
     457-460), but without its per-step host sync. An optional f32 ``mask``
     in the batch (1.0 = real sample) makes padded remainder batches exact.
+
+    ``task="tokens"``: the same four sums over the kept TOKENS of the
+    global batch (per-token loss and accuracy; the loader's row mask is
+    already folded into the token mask).
     """
+
+    def token_step(state, batch):
+        sums = state.apply_fn(
+            {"params": state.params, "batch_stats": state.batch_stats},
+            batch["tokens"], train=False, labels=batch["labels"],
+            mask=batch["mask"],
+        )
+        sums = {k: sums[k]
+                for k in ("loss_sum", "correct1", "correct5", "count")}
+        if mesh is not None:
+            sums = lax.psum(sums, squeeze_axes(data_axis_names(mesh)))
+        return sums
 
     def step(state, batch):
         images = normalize_images(batch["images"], compute_dtype)
@@ -455,6 +529,8 @@ def make_eval_step(mesh: Optional[Mesh] = None, compute_dtype=jnp.float32):
             sums = lax.psum(sums, squeeze_axes(data_axis_names(mesh)))
         return sums
 
+    if task == "tokens":
+        step = token_step
     opts = tpu_compiler_options()
     if mesh is None:
         return jax.jit(step, compiler_options=opts)

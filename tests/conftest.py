@@ -41,6 +41,15 @@ import pytest  # noqa: E402
 # "SIGTERM'd run resumes bit-identically" must hold in tier 1, and it can
 # only be asserted through fit().
 _FAST_MODULES = {
+    # token-sequence model (ISSUE 30): LFM2 against its plain reference
+    # at hidden 64 (a dozen small jits), the tokens:<N> feed against the
+    # benchmark's copy with ONE fit()-driven case at the same size (train,
+    # validate, resume), and the benchmark's seam cases (11 s)
+    "test_lfm2", "test_tokens_feed", "test_benchmark_seams",
+    # the expert layer and the blockwise attention compiled for a
+    # described v5e at the cell's widths (no chip; 25 s; skips where the
+    # TPU's compiler cannot describe the chip)
+    "test_tpu_compile",
     "test_bench_logic", "test_config", "test_schedules", "test_metrics",
     "test_meters", "test_data", "test_tensorboard", "test_native",
     "test_cache", "test_shm_loader", "test_feed_knobs", "test_tv_template",

@@ -1,0 +1,419 @@
+"""LFM2 (``dptpu/models/lfm2.py``) against its plain reference
+(``benchmark/reference/lfm2_moe.py``) at a small size on the CPU: hidden
+64, 2 heads of 32 over 1 key/value head, 8 experts top-2, vocabulary 256,
+64 tokens. Seeded weights in the checkpoint's names go through the
+program's own converter, as the benchmark's do.
+
+Tolerances: both sides compute in float32 with HIGHEST matrix products;
+what is left is the order of float32 sums (blockwise attention and loss,
+sorted expert runs against dense masked experts), so 2e-5 of a leaf's
+largest entry holds a gradient and 1e-5 a loss near 6. Three AdamW steps
+divide by the root of a tiny second moment, which magnifies those sums'
+last bits in single entries: the parameters' change is held to 2e-3 of
+its own norm, a leaf at a time.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import common as reference_common
+from benchmark.reference import lfm2_moe as reference
+from benchmark.reference.optimizers import adamw as reference_adamw
+from dptpu.models import lfm2
+from dptpu.models.pretrained import (
+    _to_torch,
+    convert_state_dict,
+    torch_key_map,
+)
+from dptpu.models.registry import _REGISTRY, model_task, register_model
+from dptpu.ops.attention import causal_attention, plain_causal_attention
+from dptpu.ops.loss import token_cross_entropy_sums
+from dptpu.train.state import create_train_state, make_optimizer
+from dptpu.train.step import make_eval_step, make_train_step
+
+TINY = lfm2.Lfm2Config(
+    vocab_size=256, hidden_size=64, intermediate_size=96,
+    moe_intermediate_size=48, num_hidden_layers=4,
+    layer_types=("conv", "conv", "full_attention", "conv"),
+    num_dense_layers=2, num_attention_heads=2, num_key_value_heads=1,
+    num_experts=8, num_experts_per_tok=2, sequence_length=64)
+ARCH = "lfm2_test_tiny"
+if ARCH not in _REGISTRY:
+    register_model(lfm2.factory(ARCH, TINY))
+
+HYPER = {"name": "adamw", "b1": 0.9, "b2": 0.95, "eps": 1e-8,
+         "weight_decay": 0.1}
+
+
+def reference_model(config: lfm2.Lfm2Config) -> dict:
+    """The reference's ``model`` group for a program configuration."""
+    first, count = config.experts_here
+    return {
+        "hidden_size": config.hidden_size,
+        "intermediate_size": config.intermediate_size,
+        "moe_intermediate_size": config.moe_intermediate_size,
+        "layer_types": list(config.layer_types),
+        "num_dense_layers": config.num_dense_layers,
+        "num_attention_heads": config.num_attention_heads,
+        "num_key_value_heads": config.num_key_value_heads,
+        "head_dim": config.head_dim,
+        "router_experts": config.num_experts,
+        "experts_first": first, "experts_held": count,
+        "num_experts_per_tok": config.num_experts_per_tok,
+        "norm_topk_prob": config.norm_topk_prob,
+        "routed_scaling_factor": config.routed_scaling_factor,
+        "use_expert_bias": config.use_expert_bias,
+        "conv_L_cache": config.conv_L_cache, "norm_eps": config.norm_eps,
+        "rope_theta": config.rope_theta, "vocab_size": config.vocab_size,
+        "sequence_length": config.sequence_length,
+    }
+
+
+def seeded(config, seed=5, bias_scale=None):
+    """``(reference model, weights by checkpoint name, program
+    variables)`` for ``config``."""
+    model = reference_model(config)
+    spec = reference.weight_spec(model)
+    if bias_scale is not None:
+        spec = [(n, s, k, bias_scale if n.endswith("expert_bias") else v)
+                for n, s, k, v in spec]
+    weights = {k: np.asarray(v) for k, v in
+               reference_common.make_weights(spec, seed).items()}
+    net = lfm2.Lfm2(config)
+    template = jax.eval_shape(lambda: net.init(
+        jax.random.PRNGKey(0), net.example_input()))
+    return model, weights, net, convert_state_dict(ARCH, weights, template)
+
+
+def rows(config, n=2, seed=0):
+    rng = np.random.RandomState(seed)
+    length = config.sequence_length
+    ids = rng.randint(0, config.vocab_size, (n, length + 1)).astype(np.int32)
+    kept = rng.randint(length - length // 16, length + 1, n)
+    return {"tokens": ids[:, :-1], "labels": ids[:, 1:],
+            "mask": np.arange(length)[None] < kept[:, None]}
+
+
+def program_loss(net, variables, batch):
+    """The step's loss: the mean over rows of the row's mean."""
+    from dptpu.train.step import token_row_weights
+
+    def loss(params):
+        sums = net.apply({**variables, "params": params}, batch["tokens"],
+                         labels=batch["labels"],
+                         mask=token_row_weights(jnp.asarray(batch["mask"])))
+        return sums["loss_sum"] / batch["tokens"].shape[0]
+    return loss
+
+
+@pytest.fixture(autouse=True)
+def highest_precision():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+# --------------------------------------------------- program == reference --
+
+
+@pytest.mark.parametrize("share", [None, (2, 4)], ids=["whole", "experts2-5"])
+def test_loss_and_every_gradient_leaf_match_the_reference(share):
+    config = TINY.held(experts=share)
+    model, weights, net, variables = seeded(config, bias_scale=0.05)
+    batch = rows(config)
+    want, want_grads = jax.value_and_grad(
+        lambda w: reference.loss(model, {**weights, **w}, batch, "f32"))(
+        {k: jnp.asarray(weights[k]) for k in reference.trainable(model)})
+    # nothing trains the buffer: it only chooses experts, so its
+    # gradient is zero to the bit (it is an argument of the compiled
+    # loss all the same: reference.trainable says why)
+    biases = [k for k in want_grads if k.endswith("expert_bias")]
+    assert biases and not any(np.asarray(want_grads.pop(k)).any()
+                              for k in biases)
+    got, got_grads = jax.value_and_grad(
+        program_loss(net, variables, batch))(variables["params"])
+    assert float(got) == pytest.approx(float(want), abs=1e-5)
+    kmap = torch_key_map(ARCH, variables)
+    checked = 0
+    for key, (collection, names, kind) in kmap.items():
+        if collection != "params":
+            continue
+        leaf = functools.reduce(lambda t, n: t[n], names, got_grads)
+        there = _to_torch(np.asarray(leaf), kind)
+        scale = max(float(np.abs(want_grads[key]).max()), 1e-6)
+        np.testing.assert_allclose(there, want_grads[key],
+                                   atol=2e-5 * scale + 1e-9, err_msg=key)
+        checked += 1
+    assert checked == len(want_grads)
+    # every leaf got a gradient worth comparing, but for an expert that
+    # no token of these 128 chose
+    idle = [k for k, g in want_grads.items() if not np.abs(g).max()]
+    assert len(idle) <= 6 and all(".experts." in k for k in idle), idle
+
+
+def test_three_adamw_steps_match_the_reference():
+    model, weights, net, variables = seeded(TINY)
+    batches = [rows(TINY, seed=s) for s in range(3)]
+    lr = 1e-3
+    want = reference_common.train_steps(
+        functools.partial(reference.loss, model), reference_adamw, HYPER,
+        reference.trainable(model), weights, batches, lr=lr, block_rows=1)
+    tx = make_optimizer(weight_decay=HYPER["weight_decay"], name="adamw",
+                        betas=(HYPER["b1"], HYPER["b2"]), eps=HYPER["eps"])
+    state = create_train_state(jax.random.PRNGKey(0), net, tx,
+                               variables=variables)
+    step = make_train_step(None, jnp.float32, lr_schedule=lambda c: lr,
+                           task="tokens")
+    losses, mu1 = [], None
+    for batch in batches:
+        state, metrics = step(state, {k: jnp.asarray(v)
+                                      for k, v in batch.items()})
+        losses.append(float(metrics["loss"]))
+        if mu1 is None:
+            # copied out: the next step donates the state it is part of
+            mu1 = jax.device_get(
+                reference_adamw.program_trace1(state.opt_state))
+    assert losses == pytest.approx(want["loss"], abs=1e-5)
+    assert int(metrics["moe_dropped"]) == 0
+    for key, (collection, names, kind) in torch_key_map(
+            ARCH, variables).items():
+        leaf = lambda tree: _to_torch(np.asarray(functools.reduce(  # noqa: E731
+            lambda t, n: t[n], names, tree)), kind)
+        if collection != "params":  # the buffer stays where it was seeded
+            np.testing.assert_array_equal(leaf(state.batch_stats),
+                                          weights[key])
+            assert not want["delta"][key].any() \
+                and not want["trace1"][key].any()
+            continue
+        scale = max(float(np.abs(want["trace1"][key]).max()), 1e-9)
+        np.testing.assert_allclose(leaf(mu1), want["trace1"][key],
+                                   atol=2e-5 * scale, err_msg=key)
+        # Adam's step is the gradient over its own size: an entry whose
+        # gradient is all but zero turns on the last bits of a float32
+        # sum, so the change is held as a whole leaf, not entry by entry
+        delta = leaf(state.params) - weights[key]
+        off = np.linalg.norm(delta - want["delta"][key]) \
+            / np.linalg.norm(want["delta"][key])
+        assert off < 2e-3, (key, off)
+
+
+def test_eval_step_sums_over_kept_tokens():
+    model, weights, net, variables = seeded(TINY)
+    batch = rows(TINY, n=3)
+    state = create_train_state(jax.random.PRNGKey(0), net,
+                               make_optimizer(name="adamw"),
+                               variables=variables)
+    sums = make_eval_step(None, jnp.float32, task="tokens")(
+        state, {k: jnp.asarray(v) for k, v in batch.items()})
+    logits = np.stack([np.asarray(reference.forward(model, weights, t))
+                       for t in batch["tokens"]])
+    nll = jax.nn.logsumexp(logits, -1) - np.take_along_axis(
+        logits, batch["labels"][..., None], -1)[..., 0]
+    assert float(sums["count"]) == batch["mask"].sum()
+    assert float(sums["loss_sum"]) == pytest.approx(
+        float((nll * batch["mask"]).sum()), rel=1e-5)
+    top1 = (logits.argmax(-1) == batch["labels"]) & batch["mask"]
+    assert float(sums["correct1"]) == top1.sum()
+    assert float(sums["correct5"]) >= float(sums["correct1"])
+
+
+# ------------------------------------------------------------ the shares --
+
+
+def _layer_input(config, seed=3):
+    rng = np.random.RandomState(seed)
+    return rng.randn(2, config.sequence_length,
+                     config.hidden_size).astype(np.float32)
+
+
+def test_four_shares_of_the_experts_add_up_to_the_uncut_layer():
+    model, weights, _, variables = seeded(TINY, bias_scale=0.05)
+    f = "model.layers.2.feed_forward."
+    x = _layer_input(TINY)
+    want = np.stack([np.asarray(reference.expert_layer(
+        model, weights, f, row, "f32", experts=range(8))) for row in x])
+    whole = variables["params"]["layers_2"]["feed_forward"]
+    stats = {"expert_bias":
+             variables["batch_stats"]["layers_2"]["feed_forward"]["expert_bias"]}
+    total, counts = 0.0, []
+    for first in (0, 2, 4, 6):
+        config = TINY.held(experts=(first, 2))
+        params = {"gate": whole["gate"],
+                  **{f"experts_{e}": whole[f"experts_{e}"]
+                     for e in range(first, first + 2)}}
+        out, sizes = lfm2.SparseExperts(config).apply(
+            {"params": params, "batch_stats": stats}, jnp.asarray(x))
+        total = total + np.asarray(out)
+        counts += list(np.asarray(sizes))
+    np.testing.assert_allclose(total, want, atol=2e-6)
+    # every slot of every token is on exactly one chip's experts
+    assert sum(counts) == x.shape[0] * x.shape[1] * 2
+
+
+def test_four_slices_of_the_vocabulary_combine_to_the_whole_loss():
+    model, weights, net, variables = seeded(TINY)
+    batch = rows(TINY, n=1)
+    want = float(reference.loss(model, weights, batch, "f32"))
+    _, state = net.apply(
+        variables, batch["tokens"],
+        capture_intermediates=lambda m, _: m.name == "embedding_norm")
+    hidden = state["intermediates"]["embedding_norm"]["__call__"][0][0]
+    embedding = variables["params"]["embed_tokens"]["embedding"]
+    one = jnp.ones((1,), jnp.float32)
+
+    def slice_lse(rows_held):
+        # the program's loss op on one slice, a token at a time with the
+        # slice's row 0 as its label: logsumexp = that loss + that logit
+        nll = jax.vmap(lambda h: token_cross_entropy_sums(
+            h[None], rows_held, jnp.zeros((1,), jnp.int32), one)["loss_sum"])
+        return nll(hidden) + hidden @ rows_held[0]
+
+    lse = jax.nn.logsumexp(jnp.stack(
+        [slice_lse(embedding[s:s + 64]) for s in range(0, 256, 64)]), axis=0)
+    picked = jnp.sum(hidden * embedding[batch["labels"][0]], axis=-1)
+    mask = batch["mask"][0]
+    got = float(jnp.sum((lse - picked) * mask) / mask.sum())
+    assert got == pytest.approx(want, abs=1e-5)
+
+
+def test_no_token_is_dropped_when_every_token_picks_the_same_experts():
+    model, weights, _, variables = seeded(TINY)
+    f = "model.layers.3.feed_forward."
+    # a bias that outweighs every score: experts 0 and 1, for every token
+    bias = np.zeros(8, np.float32)
+    bias[:2] = 10.0
+    weights = {**weights, f + "expert_bias": bias}
+    x = _layer_input(TINY)
+    tokens = x.shape[0] * x.shape[1]
+    whole = variables["params"]["layers_3"]["feed_forward"]
+    out, sizes = lfm2.SparseExperts(TINY).apply(
+        {"params": whole, "batch_stats": {"expert_bias": jnp.asarray(bias)}},
+        jnp.asarray(x))
+    assert list(np.asarray(sizes)) == [tokens, tokens, 0, 0, 0, 0, 0, 0]
+    want = np.stack([np.asarray(reference.expert_layer(
+        model, weights, f, row, "f32")) for row in x])
+    np.testing.assert_allclose(np.asarray(out), want, atol=2e-6)
+    # and a share that holds neither of them computes nothing, drops nothing
+    config = TINY.held(experts=(4, 4))
+    out, sizes = lfm2.SparseExperts(config).apply(
+        {"params": {"gate": whole["gate"],
+                    **{f"experts_{e}": whole[f"experts_{e}"]
+                       for e in range(4, 8)}},
+         "batch_stats": {"expert_bias": jnp.asarray(bias)}}, jnp.asarray(x))
+    assert not np.asarray(sizes).any() and not np.asarray(out).any()
+
+
+def test_the_selection_takes_the_bias_and_the_weights_do_not():
+    scores = jnp.asarray([[0.9, 0.8, 0.1, 0.2]])
+    chosen, weights = lfm2.route(scores, jnp.asarray([0.0, 0.0, 1.0, 0.0]),
+                                 2, True, 1.0)
+    assert sorted(np.asarray(chosen)[0]) == [0, 2]
+    by_expert = dict(zip(np.asarray(chosen)[0], np.asarray(weights)[0]))
+    assert by_expert[0] == pytest.approx(0.9 / (1.0 + 1e-6))
+    assert by_expert[2] == pytest.approx(0.1 / (1.0 + 1e-6))
+
+
+# ---------------------------------------------------------------- the ops --
+
+
+@pytest.mark.parametrize("length,block", [(64, 16), (50, 16), (7, 16),
+                                          (33, 8), (17, 1)])
+def test_blockwise_attention_is_the_plain_one(length, block):
+    keys = jax.random.split(jax.random.PRNGKey(length), 4)
+    q = jax.random.normal(keys[0], (2, length, 4, 8))
+    k = jax.random.normal(keys[1], (2, length, 2, 8))
+    v = jax.random.normal(keys[2], (2, length, 2, 8))
+    weigh = jax.random.normal(keys[3], (2, length, 4, 8))
+
+    def scalar(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * weigh)
+
+    blockwise = functools.partial(causal_attention, scale=0.35, block=block)
+    plain = functools.partial(plain_causal_attention, scale=0.35)
+    np.testing.assert_allclose(blockwise(q, k, v), plain(q, k, v), atol=2e-6)
+    got = jax.grad(scalar(blockwise), (0, 1, 2))(q, k, v)
+    want = jax.grad(scalar(plain), (0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=5e-6)
+
+
+@pytest.mark.parametrize("tokens,block", [(100, 32), (64, 64), (5, 2048)])
+def test_blockwise_token_loss_is_the_whole_one(tokens, block):
+    keys = jax.random.split(jax.random.PRNGKey(tokens), 3)
+    hidden = jax.random.normal(keys[0], (tokens, 16))
+    embedding = jax.random.normal(keys[1], (40, 16))
+    labels = jax.random.randint(keys[2], (tokens,), 0, 40)
+    mask = jnp.arange(tokens) < tokens - 3
+
+    def whole(hidden, embedding):
+        logits = hidden @ embedding.T
+        nll = jax.nn.logsumexp(logits, -1) - jnp.take_along_axis(
+            logits, labels[:, None], -1)[:, 0]
+        return jnp.sum(nll * mask), logits
+
+    def blocks(hidden, embedding):
+        return token_cross_entropy_sums(hidden, embedding, labels, mask,
+                                        block=block)
+
+    got = blocks(hidden, embedding)
+    want, logits = whole(hidden, embedding)
+    assert float(got["loss_sum"]) == pytest.approx(float(want), rel=1e-6)
+    assert float(got["count"]) == tokens - 3
+    _, top = jax.lax.top_k(logits, 5)
+    hit = np.asarray(top == labels[:, None]) & np.asarray(mask)[:, None]
+    assert float(got["correct1"]) == hit[:, :1].any(1).sum()
+    assert float(got["correct5"]) == hit.any(1).sum()
+    got_g = jax.grad(lambda h, e: blocks(h, e)["loss_sum"], (0, 1))(
+        hidden, embedding)
+    want_g = jax.grad(lambda h, e: whole(h, e)[0], (0, 1))(hidden, embedding)
+    for g, w in zip(got_g, want_g):
+        np.testing.assert_allclose(g, w, atol=1e-5)
+
+
+# ------------------------------------------------- names and configuration --
+
+
+def test_every_leaf_name_goes_through_the_converter_and_back():
+    model, weights, _, variables = seeded(TINY)
+    kmap = torch_key_map(ARCH, variables)
+    assert set(kmap) == set(weights)  # every name of the layout, no other
+    for key, (collection, names, kind) in kmap.items():
+        leaf = functools.reduce(lambda t, n: t[n], names,
+                                variables[collection])
+        np.testing.assert_array_equal(_to_torch(np.asarray(leaf), kind),
+                                      weights[key], err_msg=key)
+    assert kmap["model.layers.2.feed_forward.expert_bias"][0] == "batch_stats"
+    assert "model.layers.0.conv.conv.weight" in kmap
+    assert "model.layers.2.self_attn.q_layernorm.weight" in kmap
+    assert "model.layers.3.feed_forward.experts.7.w2.weight" in kmap
+
+
+def test_the_published_configuration_and_a_chips_share():
+    published = lfm2.Lfm2Config()
+    assert model_task("lfm2_8b_a1b") == "tokens"
+    assert model_task("resnet50") == "images"
+    assert published.head_dim == 64 and published.num_experts == 32
+    assert [i for i, kind in enumerate(published.layer_types)
+            if kind == "full_attention"] == [2, 6, 10, 14, 18, 21]
+    share = published.held(layers=(1, 5), experts=(0, 8),
+                           vocab=(0, 16384), sequence_length=8192)
+    assert share.layer_types == ("conv", "full_attention", "conv", "conv",
+                                 "conv")
+    assert share.num_dense_layers == 1 and share.num_experts == 32
+    assert share.experts_here == (0, 8) and share.vocab_size == 16384
+    # no width moves
+    for width in ("hidden_size", "intermediate_size", "head_dim",
+                  "moe_intermediate_size", "num_experts_per_tok"):
+        assert getattr(share, width) == getattr(published, width)
+    net = lfm2.Lfm2(share)
+    shapes = jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0),
+                                             net.example_input()))
+    assert sum(x.size for x in jax.tree_util.tree_leaves(
+        shapes["params"])) == 507_820_160
+    with pytest.raises(ValueError, match="FIRST:COUNT"):
+        lfm2.factory("x", published)(experts="8")
+    with pytest.raises(ValueError, match="not among the 32 experts"):
+        published.held(experts=(30, 4))
