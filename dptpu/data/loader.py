@@ -10,7 +10,14 @@ imagenet_ddp_apex.py:26-39,304-351), rebuilt for the TPU host model:
   a shared-memory batch ring (``dptpu/data/shm.py``) — the GIL caps the
   thread pool at ~1 core of useful decode on real hosts (HOSTBENCH r5:
   542.8 img/s at 8 threads vs 516.6 at 1), while processes scale with
-  host cores and pixels still never get pickled;
+  host cores and pixels still never get pickled. This constructor's
+  default stays ``thread`` (a consumer that retains batches relies on
+  it); ``fit()`` asks for ``process`` unless ``DPTPU_WORKERS_MODE``
+  says otherwise or the host has too few cores for it to pay
+  (``dptpu/train/fit.py::_feed_knobs``), and brings the pool up during
+  set-up with ``start()``: the loop's own thread then shares its
+  interpreter with no worker (four pool threads held the loop's
+  dispatch call for 65 ms of a 69 ms ResNet-50 iteration, PERF.md);
 * CHUNKED submission, decoded in place: each batch submits one span per
   worker (not one task per image), and each worker decodes its span of
   samples DIRECTLY into the preallocated uint8 NHWC batch
@@ -64,8 +71,6 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Iterator, Optional
 
 import numpy as np
-
-import jax
 
 from dptpu import obs
 from dptpu.data.sampler import ShardedSampler
@@ -288,6 +293,11 @@ class DataLoader:
                 f.result()  # wait + propagate decode errors
             return self._assemble(imgs, labels, n_valid, valid)
         attrs = {"ready": all(f.done() for f in futs), "rows": n_valid}
+        if self._degraded:
+            # these threads stand in for a process pool that gave up: a
+            # log that shows it tells a run that gained nothing from the
+            # pool apart from a run that never had one
+            attrs["degraded"] = True
         t0 = time.perf_counter()
         spent = [f.result() for f in futs]
         if all(r is not None for r in spent):
@@ -352,15 +362,10 @@ class DataLoader:
                 )
             chunks = chunks[start_batch:]
         if self._item_shape is None and chunks:
-            # one probe decode fixes the item shape for preallocation
-            # (cached on the loader; only the first epoch() call pays —
-            # and thread mode reuses the decode for the sample's row)
-            probe_idx = int(chunks[0][0][0])
-            img, label = self._load_one(probe_idx, epoch)
-            img = np.asarray(img)
-            self._item_shape = img.shape
-            self._item_dtype = img.dtype
-            self._probe = (probe_idx, epoch, img, label)
+            # cached on the loader: only the first epoch() call pays (or
+            # start() did), and thread mode reuses the decode for the
+            # sample's row
+            self._probe_item(int(chunks[0][0][0]), epoch)
 
         ahead = 1 + max(0, prefetch_batches)
         if self.workers_mode == "process":
@@ -368,6 +373,56 @@ class DataLoader:
                                            start_batch)
             return
         yield from self._epoch_thread(chunks, epoch, ahead, start_batch)
+
+    def _probe_item(self, index: int, epoch: int):
+        """One probe decode fixes the item shape and dtype that every
+        batch is preallocated to."""
+        img, label = self._load_one(index, epoch)
+        img = np.asarray(img)
+        self._item_shape = img.shape
+        self._item_dtype = img.dtype
+        self._probe = (index, epoch, img, label)
+
+    def _ring_slots(self, ahead: int) -> tuple:
+        """``(issue window, ring slots)`` of a process-mode epoch that
+        keeps ``ahead`` batches in flight. An explicit decode_ahead is
+        exact (=1 is the batch-serial baseline); the default keeps at
+        least the legacy prefetch window, deepened to 4 for multi-batch
+        lookahead."""
+        window = (
+            self.decode_ahead if self.decode_ahead is not None
+            else max(ahead, 4)
+        )
+        slots = (
+            self.ring_depth if self.ring_depth is not None
+            else window + 1 + (self.lease_depth if self.leased else 0)
+        )
+        return window, slots
+
+    def start(self, prefetch_batches: int = 2):
+        """Bring the process pool up NOW, without waiting for it: probe
+        the item shape, create the ring and spawn the workers, whose
+        interpreters then start and import beside whatever the caller
+        does next (``fit()``: weights, state, the step's compile). The
+        first ``epoch()`` with the same ``prefetch_batches`` finds this
+        pool and spawns nothing. A no-op in thread mode, on an empty
+        shard, and when the pool is already up."""
+        if self.workers_mode != "process" or self._pipeline is not None:
+            return
+        t0 = time.perf_counter()
+        if self._item_shape is None:
+            # any row of this host's shard has the shape of all of them
+            indices, _ = self.sampler.indices_and_validity(0)
+            if not len(indices):
+                return
+            self._probe_item(int(indices[0]), 0)
+        _, slots = self._ring_slots(1 + max(0, prefetch_batches))
+        self._ensure_pipeline(slots=slots)
+        obs.get_tracer().record(
+            "feed_start", t0, time.perf_counter() - t0,
+            attrs={"mode": self.workers_mode, "workers": self.num_workers,
+                   "slots": slots},
+        )
 
     def _epoch_thread(self, chunks, epoch, ahead, first=0):
         """Thread-pool epoch over an explicit chunk list (also the landing
@@ -409,17 +464,7 @@ class DataLoader:
         nb = len(chunks)
         b = 0
         try:
-            # issue window: explicit decode_ahead is exact (=1 is the
-            # batch-serial baseline); default keeps at least the legacy
-            # prefetch window, deepened to 4 for multi-batch lookahead
-            window = (
-                self.decode_ahead if self.decode_ahead is not None
-                else max(ahead, 4)
-            )
-            slots = (
-                self.ring_depth if self.ring_depth is not None
-                else window + 1 + (self.lease_depth if self.leased else 0)
-            )
+            window, slots = self._ring_slots(ahead)
             pipe = self._ensure_pipeline(slots=slots)
             pipe.reset()  # reclaim slots from an abandoned prior epoch
             pending = deque()
@@ -463,8 +508,8 @@ class DataLoader:
                     slot, out_size, leased=self.leased
                 )
                 if tracer.enabled:
-                    # no CPU seconds here: the worker's ack carries its
-                    # wall time only, and no IPC field is added for it
+                    # ready, cpu_s, wall_s: the workers' own clocks, off
+                    # their acks (the thread path's attributes exactly)
                     tracer.record(
                         "collect", t_collect,
                         time.perf_counter() - t_collect, step=first + b,
@@ -778,11 +823,16 @@ class DevicePrefetcher:
       use it to drive the raw lease protocol with a custom ``put``).
     """
 
-    def __init__(self, batches: Iterator[dict], put=jax.device_put,
+    def __init__(self, batches: Iterator[dict], put=None,
                  copy_before_put: Optional[bool] = None,
                  first_step: int = 0):
+        # jax is imported where the device is touched, never at this
+        # module's top: a spawned decode worker imports dptpu.data (for
+        # its dataset) and must not pay for, or see, jax
+        import jax
+
         self._it = iter(batches)
-        self._put = put
+        self._put = jax.device_put if put is None else put
         self._copy = copy_before_put
         # epoch index of the next batch: its ``h2d`` span carries the
         # step that will consume it (``first_step`` = the resume point)
@@ -790,6 +840,8 @@ class DevicePrefetcher:
         self._next = self._advance()
 
     def _advance(self):
+        import jax
+
         tracer = obs.get_tracer()
         try:
             batch = next(self._it)

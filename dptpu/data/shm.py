@@ -276,7 +276,7 @@ def _worker_main(worker_id, dataset, imgs_name, labels_name, slots,
                 break
             slot, task_id, offsets, idxs, epoch = task
             try:
-                t_span = time.monotonic()
+                t_span, c_span = time.monotonic(), time.thread_time()
                 for off, index in zip(offsets, idxs):
                     if fault_plan is not None:
                         fault_plan.worker_decode_hook(worker_id, index)
@@ -295,8 +295,12 @@ def _worker_main(worker_id, dataset, imgs_name, labels_name, slots,
                 # the span's own decode wall time rides the ack — the
                 # straggler controller's per-worker speed signal,
                 # unpolluted by queue wait or the parent's drain cadence
+                # — and this thread's CPU seconds beside it: what the
+                # rows cost against what they took (the ``collect``
+                # span's ``cpu_s`` / ``wall_s``, as in thread mode)
                 res_q.put(("done", worker_id, slot, task_id, hits, misses,
-                           time.monotonic() - t_span))
+                           time.monotonic() - t_span,
+                           time.thread_time() - c_span))
             except BaseException:
                 res_q.put(
                     ("error", worker_id, slot, task_id,
@@ -425,10 +429,12 @@ class ShmBatchPipeline:
             (self.slots, batch_size), np.int32, buffer=self._shm_labels.buf
         )
         self._outstanding = [0] * self.slots  # span acks still in flight
-        # workers' own decode seconds of each slot's batch, from the acks
+        # workers' own decode seconds of each slot's batch, wall and
+        # CPU, from the acks
         self._slot_wall_s = [0.0] * self.slots
+        self._slot_cpu_s = [0.0] * self.slots
         # what the last collect() found (the loader's collect span)
-        self.last_collect = {"ready": True, "wall_s": 0.0}
+        self.last_collect = {"ready": True, "cpu_s": 0.0, "wall_s": 0.0}
         self._pending = {s: {} for s in range(self.slots)}  # task_id -> task
         self._retries = {}  # (slot, task_id) -> attempts so far
         self._free = list(range(self.slots))
@@ -584,6 +590,7 @@ class ShmBatchPipeline:
             self._worker_load[wid] += 1
         self._outstanding[slot] = len(self._pending[slot])
         self._slot_wall_s[slot] = 0.0
+        self._slot_cpu_s[slot] = 0.0
         if self._readahead:
             self._issue_readahead(batch_indices)
         return slot, len(batch_indices)
@@ -652,6 +659,7 @@ class ShmBatchPipeline:
             self._handle(self._next_result(tick=_tick), mode="normal")
         self._io_wait_s += time.monotonic() - t0
         self.last_collect = {"ready": ready,
+                             "cpu_s": self._slot_cpu_s[slot],
                              "wall_s": self._slot_wall_s[slot]}
         self._occ_sum += self.slots - len(self._free)
         self._occ_n += 1
@@ -993,6 +1001,8 @@ class ShmBatchPipeline:
             self._outstanding[slot] -= 1
             if len(msg) > 6:
                 self._slot_wall_s[slot] += float(msg[6])
+            if len(msg) > 7:
+                self._slot_cpu_s[slot] += float(msg[7])
             self._retries.pop((slot, task_id), None)
             return
         # kind == "error"
@@ -1104,11 +1114,20 @@ class ShmBatchPipeline:
                 q.put(None)
             except Exception:
                 pass
+        # an idle worker leaves on the sentinel within milliseconds. One
+        # that still has pre-issued spans queued (an abandoned epoch)
+        # would decode them all first, for nobody: it gets what is left
+        # of ONE short grace shared by the pool, then SIGTERM. Safe at
+        # any point: its rows land in a ring that is unlinked below, and
+        # a pooled cache slab recovers a lock whose owner died.
+        grace_end = time.monotonic() + 0.25
         for p in self._procs:
-            p.join(timeout=1.0)
+            p.join(timeout=max(0.0, grace_end - time.monotonic()))
+        for p in self._procs:
             if p.is_alive():
                 p.terminate()
-                p.join(timeout=2.0)
+        for p in self._procs:
+            p.join(timeout=2.0)
             if p.is_alive():  # hung in non-interruptible state: no mercy
                 p.kill()
                 p.join(timeout=2.0)

@@ -17,6 +17,7 @@ Differences by design (TPU-first):
 
 from __future__ import annotations
 
+import os
 import sys
 import time
 from functools import partial
@@ -198,12 +199,33 @@ def _build_datasets(cfg: Config, image_size: int, cache_bytes: int = 0,
     return train_ds, val_ds, len(train_ds.classes)
 
 
+def _host_cores() -> int:
+    """The cores this process may run on (its affinity mask, which a
+    container or ``taskset`` narrows; the machine's count where the
+    platform has no such call)."""
+    try:
+        return len(os.sched_getaffinity(0))
+    except (AttributeError, OSError):
+        return os.cpu_count() or 1
+
+
 def _feed_knobs() -> tuple:
     """The input-pipeline env knobs, under the locked fail-fast contract:
     every explicit-but-invalid value raises with the accepted values.
 
     Returns ``(workers_mode, cache_bytes, cache_scope, leased)``:
 
+    * ``DPTPU_WORKERS_MODE`` — ``process`` (spawned decode workers
+      writing into the shared-memory ring) or ``thread`` (a pool inside
+      this interpreter). Unset, it is ``process``: pool threads share
+      the interpreter lock with the loop's own thread, and four of them
+      held its dispatch call for 65 ms of a 69 ms ResNet-50 iteration
+      (PERF.md §6, PR 31). The one exception reads the host, nothing
+      else: with two cores or fewer to run on, worker processes cannot
+      run beside the loop anyway and only add their start-up, so the
+      default there is ``thread``. Thread and process batches are
+      bit-identical, and a process pool that keeps failing degrades to
+      threads by itself (``DataLoader._degrade_to_thread``);
     * ``DPTPU_CACHE_SCOPE`` — ``pooled`` (one cross-process /dev/shm
       slab, the process-mode default) or ``sharded`` (in-process
       ``DecodeCache``, split N ways by a worker pool; the thread-mode
@@ -214,7 +236,8 @@ def _feed_knobs() -> tuple:
     from dptpu.envknob import env_bool, env_choice
 
     workers_mode = env_choice(
-        "DPTPU_WORKERS_MODE", ("thread", "process"), default="thread"
+        "DPTPU_WORKERS_MODE", ("thread", "process"),
+        default="process" if _host_cores() > 2 else "thread",
     )
     cache_bytes = _os_environ_int("DPTPU_CACHE_BYTES")
     if cache_bytes is not None and cache_bytes < 0:
@@ -664,12 +687,15 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
             "Currently, inception_v3 is not supported by this example."
         )
 
-    # DPTPU_WORKERS_MODE=process routes decode through the shared-memory
-    # worker-process ring (dptpu/data/shm.py) — same batches bit-for-bit,
-    # but decode scales with host cores instead of the GIL; DPTPU_CACHE_BYTES
-    # budgets a decoded-pixel cache so epoch 1+ skips JPEG Huffman decode
-    # (DPTPU_CACHE_SCOPE picks pooled-slab vs per-worker-sharded), and
-    # DPTPU_LEASE keeps process-mode batches zero-copy end to end.
+    # Decode runs in the shared-memory worker-process ring
+    # (dptpu/data/shm.py) unless DPTPU_WORKERS_MODE=thread asks for the
+    # in-interpreter pool (or the host has two cores or fewer) — same
+    # batches bit-for-bit, but decode scales with host cores and the
+    # loop's thread shares the interpreter lock with no worker;
+    # DPTPU_CACHE_BYTES budgets a decoded-pixel cache so epoch 1+ skips
+    # JPEG Huffman decode (DPTPU_CACHE_SCOPE picks pooled-slab vs
+    # per-worker-sharded), and DPTPU_LEASE keeps process-mode batches
+    # zero-copy end to end.
     _setup_phase("data")
     workers_mode, cache_bytes, cache_scope, leased = _feed_knobs()
     if verbose:
@@ -776,6 +802,12 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
 
     ramp_mult = _ramp_mult(cfg.start_epoch)
     train_loader = _make_train_loader(host_batch * ramp_mult)
+    if not cfg.evaluate:
+        # the workers' interpreters start and import HERE, beside the
+        # weights, the state and the step's compile, and not on the
+        # loop's first iteration; the validation loader below keeps
+        # building its pool at its first pass
+        train_loader.start()
     # Validation sharding follows the reference's split behavior:
     # * ddp/nd validate the FULL val set on every rank with no cross-rank
     #   reduction (imagenet_ddp.py:186-194, nd_imagenet.py) — here every
@@ -1330,6 +1362,7 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
         ramp_mult = _ramp_mult(start_epoch)
         train_loader.close()
         train_loader = _make_train_loader(host_batch * ramp_mult)
+        train_loader.start()
         steps_per_epoch = max(len(train_loader), 1)
         schedule = _phase_schedule(ramp_mult, start_epoch)
 
@@ -1802,8 +1835,9 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
                 )
         elif verbose:
             print("=> DPTPU_STRAGGLER_FACTOR ignored: thread-mode feed "
-                  "(set DPTPU_WORKERS_MODE=process to get a worker "
-                  "pool the controller can re-split/evict)")
+                  "(DPTPU_WORKERS_MODE=thread, or a host with two "
+                  "cores or fewer: no worker pool the controller "
+                  "could re-split/evict)")
 
     # online tune control (dptpu/tune/controller.py, ISSUE 19): armed
     # by DPTPU_TUNE_CONTROL, each actuator bounded, rate-limited, and
@@ -1841,7 +1875,9 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
                 ))
             elif verbose:
                 print("=> tune control: decode_ahead ignored on a "
-                      "thread-mode feed (no ring to deepen)")
+                      "thread-mode feed (DPTPU_WORKERS_MODE=thread, or "
+                      "a host with two cores or fewer: no ring to "
+                      "deepen)")
         if not tune_ctl.actuators:
             tune_ctl = None
         elif verbose:

@@ -23,10 +23,18 @@ def host_provenance() -> dict:
     The jax version is read from ``sys.modules`` WITHOUT importing jax:
     a lint-only ``dptpu check --no-hlo`` run (or a spawned data worker)
     must stay genuinely jax-free — every caller that benches jax code
-    has already imported it, so the field is still populated wherever
-    it is meaningful (``None`` = the stamping process never loaded
-    jax)."""
+    has already imported it. A process that never loaded jax (a feed
+    bench: ``dptpu.data`` imports none since PR 31) stamps the
+    INSTALLED version, read from the package's metadata, which imports
+    nothing either (``None`` = no jax installed)."""
     jax_version = getattr(sys.modules.get("jax"), "__version__", None)
+    if jax_version is None:
+        from importlib import metadata
+
+        try:
+            jax_version = metadata.version("jax")
+        except metadata.PackageNotFoundError:
+            pass
     affinity = None
     if hasattr(os, "sched_getaffinity"):
         try:

@@ -46,6 +46,11 @@ _FAST_MODULES = {
     # benchmark's copy with ONE fit()-driven case at the same size (train,
     # validate, resume), and the benchmark's seam cases (11 s)
     "test_lfm2", "test_tokens_feed", "test_benchmark_seams",
+    # fit()'s default train feed (ISSUE 31): ONE module fixture runs
+    # fit() four times at the sizes above (resnet18@32 and the tiny
+    # token model, 4-8 steps, default and thread mode); the rest drives
+    # loaders of a few dozen rows
+    "test_feed_default",
     # the expert layer and the blockwise attention compiled for a
     # described v5e at the cell's widths (no chip; 25 s; skips where the
     # TPU's compiler cannot describe the chip)

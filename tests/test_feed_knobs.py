@@ -6,6 +6,8 @@ actionable message — including the previously-silent ``DPTPU_TP=0`` /
 no-op notice nor the error).
 """
 
+import os
+
 import pytest
 
 from dptpu.train.fit import _axis_env_knob, _feed_knobs, _os_environ_int
@@ -33,12 +35,61 @@ def test_axis_junk_raises(monkeypatch):
         _axis_env_knob("DPTPU_TP", "model-axis size")
 
 
+def _host_with(monkeypatch, cores: int):
+    monkeypatch.setattr(os, "sched_getaffinity",
+                        lambda pid: set(range(cores)), raising=False)
+
+
+@pytest.mark.parametrize("cores,mode,scope", [
+    # worker processes leave the loop's interpreter wherever they can
+    # run beside it; their cache default is the pooled cross-process slab
+    (30, "process", "pooled"), (8, "process", "pooled"),
+    (3, "process", "pooled"),
+    # two cores or fewer: processes buy nothing there (HOSTBENCH's host),
+    # so the pool stays in the interpreter, where the in-process cache
+    # is already pooled ("sharded" = the plain DecodeCache)
+    (2, "thread", "sharded"), (1, "thread", "sharded"),
+])
+def test_unset_workers_mode_reads_the_hosts_cores(monkeypatch, cores, mode,
+                                                  scope):
+    for k in ("DPTPU_WORKERS_MODE", "DPTPU_CACHE_BYTES",
+              "DPTPU_CACHE_SCOPE", "DPTPU_LEASE"):
+        monkeypatch.delenv(k, raising=False)
+    _host_with(monkeypatch, cores)
+    assert _feed_knobs() == (mode, 0, scope, True)
+
+
+@pytest.mark.parametrize("cores", [1, 30])
+@pytest.mark.parametrize("asked", ["thread", "process"])
+def test_an_explicit_workers_mode_wins_on_any_host(monkeypatch, cores, asked):
+    monkeypatch.delenv("DPTPU_CACHE_SCOPE", raising=False)
+    _host_with(monkeypatch, cores)
+    monkeypatch.setenv("DPTPU_WORKERS_MODE", asked)
+    assert _feed_knobs()[0] == asked
+
+
+def test_host_cores_without_an_affinity_call(monkeypatch):
+    from dptpu.train.fit import _host_cores
+
+    monkeypatch.delattr(os, "sched_getaffinity", raising=False)
+    monkeypatch.setattr(os, "cpu_count", lambda: 6)
+    assert _host_cores() == 6
+    monkeypatch.setattr(os, "cpu_count", lambda: None)
+    assert _host_cores() == 1
+
+
 def test_feed_knobs_defaults_and_validation(monkeypatch):
     for k in ("DPTPU_WORKERS_MODE", "DPTPU_CACHE_BYTES",
               "DPTPU_CACHE_SCOPE", "DPTPU_LEASE"):
         monkeypatch.delenv(k, raising=False)
-    # thread mode defaults: in-process cache is already pooled, so the
-    # scope default is the plain DecodeCache ("sharded")
+    _host_with(monkeypatch, 8)
+    # process workers are what a user who types nothing gets: their
+    # cache default is the pooled cross-process slab
+    assert _feed_knobs() == ("process", 0, "pooled", True)
+
+    monkeypatch.setenv("DPTPU_WORKERS_MODE", "thread")
+    # thread mode: the in-process cache is already pooled, so the scope
+    # default is the plain DecodeCache ("sharded")
     assert _feed_knobs() == ("thread", 0, "sharded", True)
 
     monkeypatch.setenv("DPTPU_WORKERS_MODE", "process")

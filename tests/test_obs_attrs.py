@@ -321,10 +321,11 @@ def test_process_mode_collect_reads_what_the_acks_carry(tracer):
     assert [s["step"] for s in collects] == [1, 2, 3]
     for s in collects:
         a = s["attrs"]
-        # the workers' acks carry their wall time; nothing carries CPU
-        assert set(a) == {"rows", "ready", "wall_s"}
+        # the workers' acks carry their own wall and CPU seconds: the
+        # thread path's attributes exactly
+        assert set(a) == {"rows", "ready", "cpu_s", "wall_s"}
         assert a["rows"] == 4 and isinstance(a["ready"], bool)
-        assert a["wall_s"] > 0.0
+        assert a["wall_s"] > 0.0 and 0.0 <= a["cpu_s"] <= a["wall_s"] + 1e-3
 
 
 def test_untraced_feed_times_nothing():

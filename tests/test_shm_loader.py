@@ -87,6 +87,41 @@ def test_process_loader_bit_identical_to_thread(jpeg_folder):
         pr.close()
 
 
+@pytest.mark.parametrize("leased", [False, True])
+@pytest.mark.parametrize("pad_final", [False, True])
+def test_process_loader_bit_identical_to_thread_on_token_rows(leased,
+                                                              pad_final):
+    """The same contract for rows that are no image: int32 ids through
+    the ring's ``item_dtype`` and the dataset's own ``collate`` (three
+    arrays a batch, the row mask folded into the token mask), on the
+    copy path and on leased views, with and without a padded tail."""
+    from dptpu.data.tokens import KEYS, TokenDataset
+
+    ds = TokenDataset(22, 40, 500, 3)  # 22 rows: 5 batches of 4 and a tail
+    kwargs = dict(num_workers=2, seed=9, drop_last=not pad_final,
+                  pad_final=pad_final)
+    th = DataLoader(ds, 4, **kwargs)
+    pr = DataLoader(ds, 4, workers_mode="process", leased=leased, **kwargs)
+    try:
+        for epoch in (0, 1):
+            want = list(th.epoch(epoch))
+            # a leased batch is a view that lives until the next one is
+            # taken: copy it out as a consumer that releases would
+            got = [{k: np.array(v) for k, v in b.items() if k != "_lease"}
+                   for b in pr.epoch(epoch)]
+            assert len(want) == len(got) == (6 if pad_final else 5)
+            for a, b in zip(want, got):
+                assert set(a) == set(b) == set(KEYS)
+                for key in KEYS:
+                    assert a[key].dtype == b[key].dtype, key
+                    np.testing.assert_array_equal(a[key], b[key])
+        assert pr.feed_stats()["bytes_copied_per_batch"] == (
+            0.0 if leased else 4 * (41 * 4 + 4))
+    finally:
+        th.close()
+        pr.close()
+
+
 def test_process_loader_cache_parity_and_stats(jpeg_folder):
     """Per-worker decode caches change nothing about the pixels (hit and
     miss resample the same decoded buffer) and aggregate into
