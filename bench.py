@@ -97,8 +97,6 @@ def main():
     global_batch = per_chip_batch * n_chips
 
     mesh = make_mesh() if n_chips > 1 else None
-    # standard 7x7/2 stem; the space-to-depth variant remains available
-    # via stem_space_to_depth
     model = create_model("resnet50", dtype=jnp.bfloat16)
     tx = make_optimizer(0.9, 1e-4)
     state = create_train_state(

@@ -15,10 +15,6 @@ one process at a time), at the full width of ResNet-50:
 * **serve** — ``dptpu.cli.main(['serve', '-a', 'resnet50', '--selftest',
   '32'])`` at the default bucket ladder and 224 px; 32 completed, 0
   failed, from the returned stats.
-* **stem kernels** — the repo's one Pallas pair (``dptpu/ops/fused_stem.py``)
-  at the ResNet-50 stem shape ``(128, 112, 112, 64)`` bf16: compiled by
-  Mosaic, dispatched to by the public op (no fallback), equal to the XLA
-  reference forward and backward.
 * **mesh** (only when more than one device is visible) — the train phase
   already ran over the default mesh; this adds the batch/params placement
   check and the **update-parity** check: one fp32 optimizer step of
@@ -280,55 +276,6 @@ def serve_phase(arch: str = ARCH, n: int = SELFTEST_REQUESTS) -> dict:
     }
 
 
-def stem_kernel_phase() -> dict:
-    """The repo's one Pallas pair (dptpu/ops/fused_stem.py, opt-in via
-    ``DPTPU_FUSED_STEM=1``) at the ResNet-50 stem shape: it must compile
-    under Mosaic, be what the public op dispatches to on the TPU (no
-    fallback), and match the XLA reference forward and backward."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from dptpu.ops import fused_stem as fs
-
-    keys = jax.random.split(jax.random.PRNGKey(0), 4)
-    z = jax.random.normal(keys[0], (PER_CHIP_BATCH, 112, 112, 64),
-                          jnp.bfloat16)
-    gamma = 1.0 + 0.1 * jax.random.normal(keys[1], (64,), jnp.float32)
-    beta = 0.1 * jax.random.normal(keys[2], (64,), jnp.float32)
-    g = jax.random.normal(keys[3], (PER_CHIP_BATCH, 56, 56, 64),
-                          jnp.bfloat16)
-
-    def loss(z, gamma, beta):
-        y = fs.affine_relu_pool(z, gamma, beta)
-        return (y.astype(jnp.float32) * g.astype(jnp.float32)).sum()
-
-    fwd = jax.jit(fs.affine_relu_pool)
-    # grad alone: the forward kernel's output is dead there, so what is
-    # left in the lowering is the backward kernel
-    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-    for name, fn in (("forward", fwd), ("backward", grad)):
-        check("tpu_custom_call" in fn.lower(z, gamma, beta).as_text(),
-              f"affine_relu_pool fell back: no Mosaic custom call in the "
-              f"TPU lowering of its {name}")
-    got = jax.device_get((fwd(z, gamma, beta),) + grad(z, gamma, beta))
-    want = jax.device_get(
-        (jax.jit(fs._fwd_xla)(z, gamma, beta),)
-        + jax.jit(fs._bwd_xla)(z, gamma, beta, g)
-    )
-    errs = {}
-    for name, a, b in zip(("y", "dz", "dgamma", "dbeta"), got, want):
-        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-        check(a.shape == b.shape and np.isfinite(a).all(),
-              f"fused stem {name}: shape {a.shape} vs {b.shape} or "
-              f"non-finite values")
-        errs[name] = float(np.abs(a - b).max() / max(np.abs(b).max(), 1e-6))
-        check(errs[name] <= 2e-2,
-              f"fused stem {name} differs from the XLA reference by "
-              f"{errs[name]:.3g} of its range")
-    return {f"{k}_rel_err": round(v, 6) for k, v in errs.items()}
-
-
 def placement_phase(n_chips: int) -> dict:
     """The batch really is split over every chip (fit's own ``put``)."""
     import numpy as np
@@ -456,7 +403,6 @@ def main() -> int:
             smoke.run("train_thread", train_phase, "thread", n_chips)
             smoke.run("train_process", train_phase, "process", n_chips)
             smoke.run("serve", serve_phase)
-            smoke.run("stem_kernel", stem_kernel_phase)
             if n_chips > 1:
                 smoke.run("placement", placement_phase, n_chips)
                 smoke.run("update_parity", parity_phase, n_chips)

@@ -4,10 +4,10 @@ source of truth.
 Every ``DPTPU_*`` name the code reads MUST have an entry here, and every
 non-internal entry MUST appear in README's knob docs (the knob-contract
 lint enforces both directions, so a knob can no longer ship undocumented
-the way DPTPU_SERVE_SLOTS / DPTPU_FUSED_STEM / DPTPU_NO_LHS / DPTPU_S2D
-did before ISSUE 12). ``kind`` names the envknob helper that parses the
-value — the fail-fast contract (dptpu/envknob.py) is what makes a typo'd
-knob raise instead of silently falling back.
+the way DPTPU_SERVE_SLOTS / DPTPU_NO_LHS did before ISSUE 12). ``kind``
+names the envknob helper that parses the value — the fail-fast contract
+(dptpu/envknob.py) is what makes a typo'd knob raise instead of silently
+falling back.
 
 ``internal=True`` marks child-process sentinels the bench drivers set
 for their own subprocesses (never user-facing, so README documentation
@@ -32,8 +32,6 @@ KNOB_REGISTRY = {
     "DPTPU_BATCH_RAMP": _k("str", "train"),
     "DPTPU_DIST_EVAL": _k("bool", "train"),
     "DPTPU_LABEL_SMOOTH": _k("float", "train"),
-    "DPTPU_FUSED_STEM": _k("bool", "train"),
-    "DPTPU_S2D": _k("bool", "train"),
     "DPTPU_NO_LHS": _k("bool", "train"),
     "DPTPU_PROFILE": _k("str", "train"),
     "DPTPU_ASYNC_CKPT": _k("bool", "train"),

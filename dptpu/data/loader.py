@@ -14,7 +14,7 @@ imagenet_ddp_apex.py:26-39,304-351), rebuilt for the TPU host model:
   default stays ``thread`` (a consumer that retains batches relies on
   it); ``fit()`` asks for ``process`` unless ``DPTPU_WORKERS_MODE``
   says otherwise or the host has too few cores for it to pay
-  (``dptpu/train/fit.py::_feed_knobs``), and brings the pool up during
+  (``dptpu/data/feed.py::feed_knobs``), and brings the pool up during
   set-up with ``start()``: the loop's own thread then shares its
   interpreter with no worker (four pool threads held the loop's
   dispatch call for 65 ms of a 69 ms ResNet-50 iteration, PERF.md);

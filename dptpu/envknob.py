@@ -3,7 +3,7 @@
 The locked knob contract (SURVEY §7 / PR 1): an UNSET or empty knob means
 "use the default", but every EXPLICIT value must parse or raise an
 actionable error — a typo'd knob must never silently fall back. One
-implementation serves the trainer (``dptpu/train/fit.py``), the data
+implementation serves the trainer (``dptpu/train``), the data
 pipeline's supervision knobs (``dptpu/data/shm.py``) and the fault
 harness (``dptpu/resilience/faults.py``); this module is imported inside
 spawned data workers, so it stays stdlib-only — never JAX.
@@ -29,6 +29,19 @@ def env_int(name: str, default: Optional[int] = None,
         raise ValueError(
             f"{name}={raw!r} is not an integer (expected e.g. {name}=2)"
         ) from None
+
+
+def env_axis(name: str, what: str, environ=None) -> int:
+    """Parallelism-axis env knob (``DPTPU_TP``, ``DPTPU_SP``): unset → 0
+    (off); any explicit value ≤ 0 raises — 0 gets the same fail-fast
+    treatment as negatives (every explicit value produces feedback; =1
+    gets a no-op notice where the knob is read)."""
+    n = env_int(name, None, environ)
+    if n is not None and n <= 0:
+        raise ValueError(
+            f"{name}={n} must be a positive {what} (e.g. {name}=2)"
+        )
+    return n or 0
 
 
 def env_float(name: str, default: Optional[float] = None,

@@ -18,14 +18,12 @@ is also what the reference's ``--channels-last`` flag asks for,
 imagenet_ddp_apex.py:95,133-136).
 """
 
-from typing import Any, Optional
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
-
-from dptpu.ops.fused_stem import affine_relu_pool
 
 # kaiming_normal(mode='fan_out', nonlinearity='relu'): N(0, sqrt(2/fan_out))
 kaiming_normal_fan_out = nn.initializers.variance_scaling(
@@ -124,57 +122,6 @@ def max_pool_same_as_torch(x, window, stride, padding):
         strides=(stride, stride),
         padding=((padding, padding), (padding, padding)),
     )
-
-
-class FusedBNReLUPool(nn.Module):
-    """BN -> ReLU -> MaxPool2d(3,2,1) with the fused custom-VJP region.
-
-    Drop-in replacement for the resnet stem's ``BatchNorm -> relu ->
-    max_pool`` sequence (imagenet_ddp.py:108-114 via torchvision resnet).
-    Parameter/stat names and shapes match ``nn.BatchNorm`` exactly
-    (``scale``/``bias`` params, ``mean``/``var`` batch_stats), so
-    checkpoints interchange with the unfused model. BN statistics follow
-    flax semantics: f32 accumulation, biased batch variance, EMA update
-    ``ra = momentum * ra + (1 - momentum) * batch``, optional cross-replica
-    ``lax.pmean`` via ``axis_name`` (the SyncBN analog). The normalize +
-    ReLU + pool themselves run as ``dptpu.ops.fused_stem.affine_relu_pool``
-    with the statistics folded into a per-channel affine.
-    """
-
-    use_running_average: bool = False
-    momentum: float = 0.9
-    epsilon: float = 1e-5
-    axis_name: Optional[str] = None
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, z):
-        c = z.shape[-1]
-        scale = self.param("scale", nn.initializers.ones, (c,), jnp.float32)
-        bias = self.param("bias", nn.initializers.zeros, (c,), jnp.float32)
-        ra_mean = self.variable("batch_stats", "mean",
-                                lambda: jnp.zeros((c,), jnp.float32))
-        ra_var = self.variable("batch_stats", "var",
-                               lambda: jnp.ones((c,), jnp.float32))
-        if self.use_running_average:
-            mean, var = ra_mean.value, ra_var.value
-        else:
-            zf = z.astype(jnp.float32)
-            mean = zf.mean(axis=(0, 1, 2))
-            mean2 = (zf * zf).mean(axis=(0, 1, 2))
-            if self.axis_name is not None:
-                mean, mean2 = jax.lax.pmean((mean, mean2), self.axis_name)
-            var = mean2 - mean * mean  # flax's biased batch variance
-            if not self.is_initializing():
-                ra_mean.value = (self.momentum * ra_mean.value
-                                 + (1.0 - self.momentum) * mean)
-                ra_var.value = (self.momentum * ra_var.value
-                                + (1.0 - self.momentum) * var)
-        gamma_t = scale * jax.lax.rsqrt(var + self.epsilon)
-        beta_t = bias - mean * gamma_t
-        return affine_relu_pool(
-            z, gamma_t.astype(self.dtype), beta_t.astype(self.dtype)
-        )
 
 
 def ceil_max_pool(x, window=3, stride=2):

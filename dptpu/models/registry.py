@@ -153,6 +153,24 @@ def model_task(name) -> str:
     return getattr(_REGISTRY[name], "task", "images")
 
 
+def token_model_kwargs(cfg, task: str) -> dict:
+    """The arguments a token-sequence model's factory takes from the
+    command line (``--seq-len``, ``--layers``, ``--experts``,
+    ``--vocab-rows``); an image model is handed none of them and a run
+    that gives it one fails here, before anything is built."""
+    given = {"sequence_length": cfg.seq_len or None,
+             "layers": cfg.layers or None, "experts": cfg.experts or None,
+             "vocab": cfg.vocab_rows or None}
+    given = {k: v for k, v in given.items() if v is not None}
+    if task != "tokens" and given:
+        raise ValueError(
+            f"--seq-len/--layers/--experts/--vocab-rows are a "
+            f"token-sequence model's arguments, and '{cfg.arch}' is "
+            f"trained on images"
+        )
+    return given
+
+
 def create_model(name, pretrained=False, **kwargs):
     """``models.__dict__[arch](pretrained=...)`` analog (imagenet_ddp.py:108-114).
 

@@ -33,7 +33,6 @@ from flax import linen as nn
 from jax import lax
 
 from dptpu.models.layers import (
-    FusedBNReLUPool,
     kaiming_normal_fan_out,
     max_pool_same_as_torch,
     torch_default_bias_init,
@@ -119,51 +118,21 @@ class Bottleneck(nn.Module):
 
 
 class _Stem(nn.Module):
-    """The 7×7/2 stem conv, with an optional space-to-depth fast path.
-
-    The parameter is ALWAYS the torchvision-shaped ``kernel [7,7,3,64]``
-    (checkpoints interchange freely between modes); in ``space_to_depth``
-    mode the input is rearranged into 2×2 blocks ([B,224,224,3] →
-    [B,116,116,12] after padding) and the kernel is zero-padded to 8×8 and
-    folded to [4,4,12,64] *inside the compiled step* — mathematically
-    identical output, but the MXU sees 12 input channels and a dense
-    stride-1 conv instead of a 3-channel stride-2 one (3/128 lane
-    occupancy), the standard TPU ResNet stem optimization.
-    """
+    """The 7×7/2 stem conv on the torchvision-shaped
+    ``kernel [7,7,3,64]``."""
 
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
-    space_to_depth: bool = False
 
     @nn.compact
     def __call__(self, x):
         kernel = self.param(
             "kernel", kaiming_normal_fan_out, (7, 7, 3, 64), self.param_dtype
         ).astype(self.dtype)
-        x = x.astype(self.dtype)
-        dn = ("NHWC", "HWIO", "NHWC")
-        if not self.space_to_depth:
-            return lax.conv_general_dilated(
-                x, kernel, (2, 2), ((3, 3), (3, 3)), dimension_numbers=dn
-            )
-        b, h, w, c = x.shape
-        if h % 2 or w % 2:
-            raise ValueError(
-                f"space-to-depth stem requires even input H/W, got {h}x{w}"
-            )
-        # pad to the conv's receptive field, rounded up even for 2×2 blocks
-        xp = jnp.pad(x, ((0, 0), (3, 5), (3, 5), (0, 0)))
-        hp, wp = h + 8, w + 8
-        xp = xp.reshape(b, hp // 2, 2, wp // 2, 2, c)
-        xp = xp.transpose(0, 1, 3, 2, 4, 5).reshape(b, hp // 2, wp // 2, 4 * c)
-        k = jnp.pad(kernel, ((0, 1), (0, 1), (0, 0), (0, 0)))  # 7→8, zeros
-        k = k.reshape(4, 2, 4, 2, c, 64)
-        k = k.transpose(0, 2, 1, 3, 4, 5).reshape(4, 4, 4 * c, 64)
-        out = lax.conv_general_dilated(
-            xp, k, (1, 1), "VALID", dimension_numbers=dn
+        return lax.conv_general_dilated(
+            x.astype(self.dtype), kernel, (2, 2), ((3, 3), (3, 3)),
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
         )
-        # the extra tail position exists only because of even-size padding
-        return out[:, : (h + 6 - 7) // 2 + 1, : (w + 6 - 7) // 2 + 1, :]
 
 
 class ResNet(nn.Module):
@@ -180,16 +149,6 @@ class ResNet(nn.Module):
     # retaining the keep_batchnorm_fp32 guarantee where it matters (the
     # running statistics and learned scale/shift).
     bn_dtype: Optional[Any] = None
-    # space-to-depth stem (see _Stem): identical math + identical params,
-    # faster on MXU. Requires even input H/W.
-    stem_space_to_depth: bool = False
-    # fused stem pool: run bn1 -> relu -> maxpool as the custom-VJP region
-    # of dptpu.ops.fused_stem (Pallas kernels on TPU). Identical params and
-    # batch_stats (checkpoints interchange); activation numerics shift by
-    # <= 1 ulp because the affine folds the statistics before multiplying.
-    # Opt-in (DPTPU_FUSED_STEM=1): correct and parity-tested, but measured
-    # slower than XLA's native stem on v5e Mosaic — see PERF.md.
-    fused_stem: bool = False
     # Bottleneck width generalization (see Bottleneck): plain ResNet is
     # (64, 1); wide_resnet*_2 use base_width 128; resnext* use groups 32.
     base_width: int = 64
@@ -217,24 +176,11 @@ class ResNet(nn.Module):
             axis_name=self.bn_axis_name,
         )
         x = _Stem(
-            dtype=self.dtype,
-            param_dtype=self.param_dtype,
-            space_to_depth=self.stem_space_to_depth,
-            name="conv1",
+            dtype=self.dtype, param_dtype=self.param_dtype, name="conv1",
         )(x)
-        if self.fused_stem:
-            x = FusedBNReLUPool(
-                use_running_average=not train,
-                momentum=bn_momentum,
-                epsilon=bn_epsilon,
-                axis_name=self.bn_axis_name,
-                dtype=bn_io_dtype,
-                name="bn1",
-            )(x)
-        else:
-            x = norm(name="bn1")(x)
-            x = nn.relu(x)
-            x = max_pool_same_as_torch(x, 3, 2, 1)
+        x = norm(name="bn1")(x)
+        x = nn.relu(x)
+        x = max_pool_same_as_torch(x, 3, 2, 1)
         if self.block_cls is Bottleneck:
             width_kw = {"base_width": self.base_width, "groups": self.groups}
         else:

@@ -203,8 +203,9 @@ class CheckpointManager:
         # save so a changed-geometry --resume can name both tuples
         self.geometry = geometry
         # the run's sharding fingerprint ("<rules-hash>:<placement>" /
-        # "replicated" — fit.py computes it), stamped so a --resume
-        # under a changed sharding config can name both fingerprints
+        # "replicated" — dptpu/train/plan.py computes it), stamped so a
+        # --resume under a changed sharding config can name both
+        # fingerprints
         self.sharding = sharding
 
     def save_step(self, state, *, epoch: int, step_in_epoch: int,

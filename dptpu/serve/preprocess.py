@@ -136,8 +136,9 @@ def _native_ingest(data: bytes, size: int, resize: int,
 
 def val_resize_for(size: int) -> int:
     """The val pipeline's resize edge for a crop of ``size``: the
-    reference 256-resize-then-224-crop ratio, scaled (fit.py builds the
-    val dataset with exactly this formula — 256 at the standard 224).
+    reference 256-resize-then-224-crop ratio, scaled (dptpu/data/feed.py
+    builds the val dataset with exactly this formula — 256 at the
+    standard 224).
     Serving MUST use the same formula or a non-224 engine would crop a
     different fraction of the image than the accuracy was measured on."""
     return int(size * 256 / 224)

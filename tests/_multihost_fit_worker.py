@@ -32,13 +32,11 @@ def main():
     from dptpu.config import parse_config
     from dptpu.train import fit
 
-    # capture the mesh fit() ACTUALLY builds so the host-major
-    # hierarchical ordering is asserted end-to-end, not on a replica
-    # (importlib: the package re-exports fit the FUNCTION under the
-    # same dotted name, shadowing the module attribute)
-    import importlib
+    # capture the mesh fit() ACTUALLY builds (dptpu/train/plan.py makes
+    # it) so the host-major hierarchical ordering is asserted
+    # end-to-end, not on a replica
+    from dptpu.train import plan as fit_mod
 
-    fit_mod = importlib.import_module("dptpu.train.fit")
     real_make_mesh = fit_mod.make_mesh
     captured = {}
 

@@ -76,6 +76,9 @@ _FAST_MODULES = {
     # test_fault_resume precedent) — the accumulation/trust-ratio locks
     # must hold in tier 1
     "test_opt_knobs", "test_optimizers",
+    # the step family, the mesh and the notices (ISSUE 33): one table
+    # over dptpu.train.plan.decide, a pure function; no compile
+    "test_train_plan",
     # serving (PR 7): knob validation is pure; test_serve compiles only
     # tiny-model bucket ladders (resnet18@32 / vit_b_32@64 — the
     # test_fault_resume precedent) and holds the ISSUE acceptance bar —

@@ -41,13 +41,13 @@ def test_the_lfm2_cell_lands_as_files_and_keeps_every_contract():
     # create_kwargs mirrors, and the model both build is the `model` group
     from dptpu.config import parse_config
     from dptpu.models import create_model, model_task
-    from dptpu.train.fit import _token_model_kwargs
+    from dptpu.models.registry import token_model_kwargs
 
     argv = drive.fit_argv(cell, drive.dataset_images(traffic, 2**31 + 130))
     assert argv[0] == "tokens:8192@2"  # the seed's rows: a first row
     parsed = parse_config(argv, variant="apex")
     assert model_task(parsed.arch) == "tokens"
-    assert _token_model_kwargs(parsed, "tokens") == config["create_kwargs"]
+    assert token_model_kwargs(parsed, "tokens") == config["create_kwargs"]
     assert (parsed.optimizer, parsed.beta1, parsed.beta2, parsed.eps,
             parsed.weight_decay) == ("adamw", 0.9, 0.95, 1e-8, 0.1)
     held = create_model(config["arch"], **config["create_kwargs"]).config

@@ -61,8 +61,8 @@ def test_distributed_flags_train_end_to_end(variant, extra, tmp_path,
 def test_multiprocessing_distributed_prints_notice(tmp_path, monkeypatch,
                                                    capsys):
     """--multiprocessing-distributed is a deliberate no-op (one process
-    per host drives every chip) but must SAY so, like DPTPU_ZERO1 /
-    DPTPU_S2D do — no silent flag swallowing (VERDICT r3 #8)."""
+    per host drives every chip) but must SAY so, like DPTPU_ZERO1
+    does — no silent flag swallowing (VERDICT r3 #8)."""
     monkeypatch.chdir(tmp_path)
     cfg = parse_config(
         ["synthetic:48", "-a", "resnet18", "-b", "16", "--epochs", "1",

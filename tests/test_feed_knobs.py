@@ -10,29 +10,30 @@ import os
 
 import pytest
 
-from dptpu.train.fit import _axis_env_knob, _feed_knobs, _os_environ_int
+from dptpu.data.feed import feed_knobs, host_cores
+from dptpu.envknob import env_axis, env_int
 
 
 def test_unset_knob_is_none_then_off(monkeypatch):
     monkeypatch.delenv("DPTPU_TP", raising=False)
-    assert _os_environ_int("DPTPU_TP") is None
-    assert _axis_env_knob("DPTPU_TP", "model-axis size") == 0
+    assert env_int("DPTPU_TP", None) is None
+    assert env_axis("DPTPU_TP", "model-axis size") == 0
 
 
 def test_axis_zero_raises_like_negatives(monkeypatch):
     for bad in ("0", "-2"):
         monkeypatch.setenv("DPTPU_TP", bad)
         with pytest.raises(ValueError, match="DPTPU_TP"):
-            _axis_env_knob("DPTPU_TP", "model-axis size")
+            env_axis("DPTPU_TP", "model-axis size")
     monkeypatch.setenv("DPTPU_SP", "0")
     with pytest.raises(ValueError, match="DPTPU_SP"):
-        _axis_env_knob("DPTPU_SP", "seq-axis size")
+        env_axis("DPTPU_SP", "seq-axis size")
 
 
 def test_axis_junk_raises(monkeypatch):
     monkeypatch.setenv("DPTPU_TP", "two")
     with pytest.raises(ValueError, match="not an integer"):
-        _axis_env_knob("DPTPU_TP", "model-axis size")
+        env_axis("DPTPU_TP", "model-axis size")
 
 
 def _host_with(monkeypatch, cores: int):
@@ -56,7 +57,7 @@ def test_unset_workers_mode_reads_the_hosts_cores(monkeypatch, cores, mode,
               "DPTPU_CACHE_SCOPE", "DPTPU_LEASE"):
         monkeypatch.delenv(k, raising=False)
     _host_with(monkeypatch, cores)
-    assert _feed_knobs() == (mode, 0, scope, True)
+    assert feed_knobs() == (mode, 0, scope, True)
 
 
 @pytest.mark.parametrize("cores", [1, 30])
@@ -65,17 +66,15 @@ def test_an_explicit_workers_mode_wins_on_any_host(monkeypatch, cores, asked):
     monkeypatch.delenv("DPTPU_CACHE_SCOPE", raising=False)
     _host_with(monkeypatch, cores)
     monkeypatch.setenv("DPTPU_WORKERS_MODE", asked)
-    assert _feed_knobs()[0] == asked
+    assert feed_knobs()[0] == asked
 
 
 def test_host_cores_without_an_affinity_call(monkeypatch):
-    from dptpu.train.fit import _host_cores
-
     monkeypatch.delattr(os, "sched_getaffinity", raising=False)
     monkeypatch.setattr(os, "cpu_count", lambda: 6)
-    assert _host_cores() == 6
+    assert host_cores() == 6
     monkeypatch.setattr(os, "cpu_count", lambda: None)
-    assert _host_cores() == 1
+    assert host_cores() == 1
 
 
 def test_feed_knobs_defaults_and_validation(monkeypatch):
@@ -85,33 +84,33 @@ def test_feed_knobs_defaults_and_validation(monkeypatch):
     _host_with(monkeypatch, 8)
     # process workers are what a user who types nothing gets: their
     # cache default is the pooled cross-process slab
-    assert _feed_knobs() == ("process", 0, "pooled", True)
+    assert feed_knobs() == ("process", 0, "pooled", True)
 
     monkeypatch.setenv("DPTPU_WORKERS_MODE", "thread")
     # thread mode: the in-process cache is already pooled, so the scope
     # default is the plain DecodeCache ("sharded")
-    assert _feed_knobs() == ("thread", 0, "sharded", True)
+    assert feed_knobs() == ("thread", 0, "sharded", True)
 
     monkeypatch.setenv("DPTPU_WORKERS_MODE", "process")
     monkeypatch.setenv("DPTPU_CACHE_BYTES", str(1 << 20))
     # process mode defaults to the pooled cross-process slab
-    assert _feed_knobs() == ("process", 1 << 20, "pooled", True)
+    assert feed_knobs() == ("process", 1 << 20, "pooled", True)
 
     monkeypatch.setenv("DPTPU_CACHE_BYTES", "0")  # explicit off is valid
-    assert _feed_knobs() == ("process", 0, "pooled", True)
+    assert feed_knobs() == ("process", 0, "pooled", True)
 
     monkeypatch.setenv("DPTPU_WORKERS_MODE", "gevent")
     with pytest.raises(ValueError, match="DPTPU_WORKERS_MODE"):
-        _feed_knobs()
+        feed_knobs()
 
     monkeypatch.setenv("DPTPU_WORKERS_MODE", "thread")
     monkeypatch.setenv("DPTPU_CACHE_BYTES", "-1")
     with pytest.raises(ValueError, match="DPTPU_CACHE_BYTES"):
-        _feed_knobs()
+        feed_knobs()
 
     monkeypatch.setenv("DPTPU_CACHE_BYTES", "lots")
     with pytest.raises(ValueError, match="not an integer"):
-        _feed_knobs()
+        feed_knobs()
 
 
 def test_cache_scope_and_lease_knobs(monkeypatch):
@@ -120,20 +119,20 @@ def test_cache_scope_and_lease_knobs(monkeypatch):
 
     monkeypatch.setenv("DPTPU_CACHE_SCOPE", "sharded")  # explicit override
     monkeypatch.setenv("DPTPU_LEASE", "0")
-    assert _feed_knobs() == ("process", 0, "sharded", False)
+    assert feed_knobs() == ("process", 0, "sharded", False)
 
     monkeypatch.setenv("DPTPU_CACHE_SCOPE", "pooled")
     monkeypatch.setenv("DPTPU_LEASE", "true")
-    assert _feed_knobs() == ("process", 0, "pooled", True)
+    assert feed_knobs() == ("process", 0, "pooled", True)
 
     monkeypatch.setenv("DPTPU_CACHE_SCOPE", "global")
     with pytest.raises(ValueError, match="DPTPU_CACHE_SCOPE"):
-        _feed_knobs()
+        feed_knobs()
 
     monkeypatch.setenv("DPTPU_CACHE_SCOPE", "pooled")
     monkeypatch.setenv("DPTPU_LEASE", "maybe")
     with pytest.raises(ValueError, match="DPTPU_LEASE"):
-        _feed_knobs()
+        feed_knobs()
 
 
 def test_lease_depth_knob_validated():
@@ -205,3 +204,19 @@ def test_env_bool_and_choice_contract(monkeypatch):
         env_bool("DPTPU_X")
     with pytest.raises(ValueError, match="DPTPU_X"):
         env_choice("DPTPU_X", ("a", "b"))
+
+
+def test_a_spawned_worker_imports_no_jax():
+    """What a feed worker's start-up rests on (0.53 s of imports on the
+    chip host, 2.1 s with jax; PERF.md §6, PR 31): a fresh interpreter
+    that imports what ``spawn`` makes a worker import, and the module
+    that builds the feed, has no jax among its modules."""
+    import subprocess
+    import sys
+
+    probe = ("import sys, dptpu.data, dptpu.data.shm, dptpu.data.feed; "
+             "print(sorted(m for m in sys.modules "
+             "if m == 'jax' or m.startswith(('jax.', 'jaxlib', 'flax'))))")
+    out = subprocess.run([sys.executable, "-c", probe], check=True,
+                         capture_output=True, text=True, timeout=120)
+    assert out.stdout.strip() == "[]", out.stdout

@@ -9,7 +9,7 @@ programmatically get the identical validation as env values.
 import pytest
 
 from dptpu.config import Config
-from dptpu.train.fit import _opt_knobs
+from dptpu.train.fit import opt_knobs
 
 _KNOBS = ("DPTPU_OPT", "DPTPU_ACCUM", "DPTPU_WARMUP_EPOCHS",
           "DPTPU_LABEL_SMOOTH")
@@ -27,7 +27,7 @@ def _clean_env(monkeypatch):
 
 def test_defaults_reproduce_reference(monkeypatch):
     # unset env + default config = the reference recipe exactly
-    assert _opt_knobs(_cfg()) == ("sgd", 1, 0, 0.0)
+    assert opt_knobs(_cfg()) == ("sgd", 1, 0, 0.0)
 
 
 def test_env_overrides_config(monkeypatch):
@@ -37,53 +37,53 @@ def test_env_overrides_config(monkeypatch):
     monkeypatch.setenv("DPTPU_ACCUM", "4")
     monkeypatch.setenv("DPTPU_WARMUP_EPOCHS", "5")
     monkeypatch.setenv("DPTPU_LABEL_SMOOTH", "0.1")
-    assert _opt_knobs(cfg) == ("lars", 4, 5, 0.1)
+    assert opt_knobs(cfg) == ("lars", 4, 5, 0.1)
 
 
 def test_config_values_pass_through():
     cfg = _cfg(optimizer="lamb", accum_steps=2, warmup_epochs=3,
                  label_smoothing=0.2)
-    assert _opt_knobs(cfg) == ("lamb", 2, 3, 0.2)
+    assert opt_knobs(cfg) == ("lamb", 2, 3, 0.2)
 
 
 def test_opt_choice_validated_env_and_config(monkeypatch):
     monkeypatch.setenv("DPTPU_OPT", "adam")
     with pytest.raises(ValueError, match="DPTPU_OPT"):
-        _opt_knobs(_cfg())
+        opt_knobs(_cfg())
     monkeypatch.delenv("DPTPU_OPT")
     with pytest.raises(ValueError, match="--optimizer"):
-        _opt_knobs(_cfg(optimizer="adam"))
+        opt_knobs(_cfg(optimizer="adam"))
 
 
 def test_accum_zero_negative_garbage_raise(monkeypatch):
     for bad in ("0", "-2"):
         monkeypatch.setenv("DPTPU_ACCUM", bad)
         with pytest.raises(ValueError, match="DPTPU_ACCUM"):
-            _opt_knobs(_cfg())
+            opt_knobs(_cfg())
     monkeypatch.setenv("DPTPU_ACCUM", "many")
     with pytest.raises(ValueError, match="not an integer"):
-        _opt_knobs(_cfg())
+        opt_knobs(_cfg())
     monkeypatch.delenv("DPTPU_ACCUM")
     # config field hits the same validation as the env twin
     for bad in (0, -1):
         with pytest.raises(ValueError, match="accum-steps"):
-            _opt_knobs(_cfg(accum_steps=bad))
+            opt_knobs(_cfg(accum_steps=bad))
     # =1 is the documented off value, never an error
-    assert _opt_knobs(_cfg(accum_steps=1))[1] == 1
+    assert opt_knobs(_cfg(accum_steps=1))[1] == 1
 
 
 def test_warmup_negative_and_garbage_raise(monkeypatch):
     monkeypatch.setenv("DPTPU_WARMUP_EPOCHS", "-1")
     with pytest.raises(ValueError, match="DPTPU_WARMUP_EPOCHS"):
-        _opt_knobs(_cfg())
+        opt_knobs(_cfg())
     monkeypatch.setenv("DPTPU_WARMUP_EPOCHS", "soon")
     with pytest.raises(ValueError, match="not an integer"):
-        _opt_knobs(_cfg())
+        opt_knobs(_cfg())
     monkeypatch.delenv("DPTPU_WARMUP_EPOCHS")
     with pytest.raises(ValueError, match="warmup-epochs"):
-        _opt_knobs(_cfg(warmup_epochs=-3))
+        opt_knobs(_cfg(warmup_epochs=-3))
     # explicit 0 keeps the reference schedule — valid
-    assert _opt_knobs(_cfg(warmup_epochs=0))[2] == 0
+    assert opt_knobs(_cfg(warmup_epochs=0))[2] == 0
 
 
 def test_warmup_swallowing_the_whole_run_raises(monkeypatch):
@@ -91,31 +91,31 @@ def test_warmup_swallowing_the_whole_run_raises(monkeypatch):
     would never reach peak LR — silently-worse training, so it fails
     fast like every other invalid knob (env twin and config field)."""
     with pytest.raises(ValueError, match="mid-warmup"):
-        _opt_knobs(_cfg(epochs=10, warmup_epochs=10))
+        opt_knobs(_cfg(epochs=10, warmup_epochs=10))
     with pytest.raises(ValueError, match="mid-warmup"):
-        _opt_knobs(_cfg(epochs=10, warmup_epochs=25))
+        opt_knobs(_cfg(epochs=10, warmup_epochs=25))
     monkeypatch.setenv("DPTPU_WARMUP_EPOCHS", "90")
     with pytest.raises(ValueError, match="mid-warmup"):
-        _opt_knobs(_cfg(epochs=90))
+        opt_knobs(_cfg(epochs=90))
     # the last warmup-compatible value is valid
     monkeypatch.delenv("DPTPU_WARMUP_EPOCHS")
-    assert _opt_knobs(_cfg(epochs=10, warmup_epochs=9))[2] == 9
+    assert opt_knobs(_cfg(epochs=10, warmup_epochs=9))[2] == 9
 
 
 def test_label_smooth_range_and_garbage_raise(monkeypatch):
     for bad in ("1.0", "-0.1", "2"):
         monkeypatch.setenv("DPTPU_LABEL_SMOOTH", bad)
         with pytest.raises(ValueError, match="DPTPU_LABEL_SMOOTH"):
-            _opt_knobs(_cfg())
+            opt_knobs(_cfg())
     monkeypatch.setenv("DPTPU_LABEL_SMOOTH", "a little")
     with pytest.raises(ValueError, match="not a number"):
-        _opt_knobs(_cfg())
+        opt_knobs(_cfg())
     monkeypatch.delenv("DPTPU_LABEL_SMOOTH")
     with pytest.raises(ValueError, match="label-smoothing"):
-        _opt_knobs(_cfg(label_smoothing=1.0))
+        opt_knobs(_cfg(label_smoothing=1.0))
     # boundary: 0 valid (off), 0.999... valid
-    assert _opt_knobs(_cfg(label_smoothing=0.0))[3] == 0.0
-    assert _opt_knobs(_cfg(label_smoothing=0.9))[3] == 0.9
+    assert opt_knobs(_cfg(label_smoothing=0.0))[3] == 0.0
+    assert opt_knobs(_cfg(label_smoothing=0.9))[3] == 0.9
 
 
 def test_fit_rejects_accum_not_dividing_per_device_batch(monkeypatch):

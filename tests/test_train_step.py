@@ -242,40 +242,6 @@ def test_eval_step_exact_sums_with_mask():
     assert sums["loss_sum"] == pytest.approx(single_sums["loss_sum"], rel=1e-5)
 
 
-def test_s2d_stem_sharded_parity():
-    # The opt-in space-to-depth stem under mesh sharding: identical math to
-    # the default 7x7/2 stem with identical params. (The driver dryrun
-    # exercises the default stem — the path bench/default training uses —
-    # so the s2d variant gets its sharded coverage here.)
-    from dptpu.models import create_model
-
-    mesh = make_mesh()
-    tx = make_optimizer(0.9, 1e-4)
-    rng = np.random.RandomState(7)
-    batch = {
-        "images": rng.randint(0, 256, (16, 32, 32, 3)).astype(np.uint8),
-        "labels": rng.randint(0, 10, (16,)).astype(np.int32),
-    }
-    sharded = shard_host_batch(batch, mesh)
-    metrics = {}
-    for s2d in (False, True):
-        model = create_model(
-            "resnet18", num_classes=10, stem_space_to_depth=s2d
-        )
-        state = create_train_state(
-            jax.random.PRNGKey(0), model, tx, input_shape=(1, 32, 32, 3)
-        )
-        step = make_train_step(mesh=mesh)
-        _, m = step(state, sharded)
-        metrics[s2d] = jax.device_get(m)
-    # identical math up to f32 accumulation order (the folded 4x4x12 kernel
-    # sums the same products in a different order than the 7x7x3 one)
-    assert float(metrics[True]["loss"]) == pytest.approx(
-        float(metrics[False]["loss"]), rel=2e-3
-    )
-    assert float(metrics[True]["top1"]) == float(metrics[False]["top1"])
-
-
 def test_traced_schedules_match_host_math():
     spe = 7
     sched = make_step_decay_schedule(0.1, spe)

@@ -635,6 +635,17 @@ def _tiny_cfg(**kw):
     return Config(**base)
 
 
+def _shed_tunables(monkeypatch):
+    """Unset every tunable knob for one case, and again after it: a run
+    under an artifact env-injects the artifact's knobs for good, and
+    monkeypatch only puts back what it has seen set or deleted (a
+    leaked DPTPU_DECODE_AHEAD=6 sized every later ring of the process
+    that ran this file)."""
+    for k in TUNABLE_KNOBS:
+        monkeypatch.setenv(k, "")
+        monkeypatch.delenv(k)
+
+
 def test_fit_loads_artifact_with_explicit_knob_precedence(
         tmp_path, monkeypatch):
     """The ISSUE 19 acceptance lock, through a REAL fit(): one run
@@ -649,8 +660,7 @@ def test_fit_loads_artifact_with_explicit_knob_precedence(
         "DPTPU_BUCKET_MB": "2",     # env twin below: kept explicit
         "DPTPU_ACCUM": "4",         # CLI twin below: kept explicit
     })
-    for k in TUNABLE_KNOBS:
-        monkeypatch.delenv(k, raising=False)
+    _shed_tunables(monkeypatch)
     monkeypatch.setenv("DPTPU_TUNE_ARTIFACT", path)
     monkeypatch.setenv("DPTPU_BUCKET_MB", "8")
     monkeypatch.chdir(tmp_path)
@@ -684,8 +694,7 @@ def test_serve_selftest_loads_artifact_ladder(tmp_path, monkeypatch):
     from dptpu.cli import main_serve
 
     path = _write(tmp_path, {"DPTPU_SERVE_BUCKETS": "1,2"})
-    for k in TUNABLE_KNOBS:
-        monkeypatch.delenv(k, raising=False)
+    _shed_tunables(monkeypatch)
     monkeypatch.setenv("DPTPU_TUNE_ARTIFACT", path)
     stats = main_serve(["--selftest", "3", "--arch", "resnet18",
                         "--num-classes", "8", "--image-size", "32"])
