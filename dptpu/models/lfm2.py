@@ -49,8 +49,22 @@ without, it returns the logits (tests, small sizes).
 
 Parameters are float32; ``dtype`` is the compute dtype (bfloat16 under
 ``--opt-level O2``); the norms' statistics, the router, the softmax and
-the loss are float32 either way. Every block is rematerialised on the
-way back (``nn.remat``), so a step holds one layer's activations.
+the loss are float32 either way.
+
+**What a step holds.** Every block is rematerialised on the way back
+(``nn.remat``): a step holds each block's input and, while a block's
+gradient is made, that one block's activations. Left at that, the
+backward pass runs every block's forward a second time. So the values
+that are dear to make again and cheap to hold carry names
+(``jax.ad_checkpoint.checkpoint_name``), in classes (``residual_classes``,
+dearest per byte first), and the rematerialisation keeps the classes
+that fit ``Lfm2.residual_budget`` bytes (``kept_residuals``: whole
+classes, in that order, from the shapes of the step being traced). A
+kept value is the value the backward pass would have made again, in the
+same dtype: the mathematics is the same at every budget. The budget is 0
+unless someone who knows the device's memory sets it (``fit()`` does:
+``Lfm2.fitted_to``), and at 0 nothing is kept. The sums report the
+megabytes kept (``kept_residual_mb``).
 """
 
 from __future__ import annotations
@@ -63,9 +77,11 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from dptpu.models.layers import uniform_bound_init
 from dptpu.models.registry import register_model
+from dptpu.ops import attention as attention_op
 from dptpu.ops.attention import causal_attention
 from dptpu.ops.loss import token_cross_entropy_sums
 
@@ -179,6 +195,84 @@ class Lfm2Config:
 _dense_init = nn.initializers.normal(0.02)
 
 
+# What a step takes on the device beside the train state and the kept
+# residuals: the temporaries of the fully rematerialised step (3.42 GB at
+# 16,384 tokens a step, 2.03 GB of it the gradients of a five-layer
+# share) and 15% of a 16.9 GB chip left to the allocator. Fixed: kept
+# residuals are bounded by the budget, so a longer row or a larger share
+# keeps less and the step fits where it fitted without them.
+STEP_HEADROOM_BYTES = 6_000_000_000
+
+
+@dataclasses.dataclass(frozen=True)
+class Kept:
+    """What the blocks of one step keep through the rematerialisation."""
+
+    classes: Tuple[str, ...] = ()
+    names: Tuple[str, ...] = ()
+    bytes: int = 0
+
+    @property
+    def megabytes(self) -> int:
+        return round(self.bytes / 1e6)
+
+    def notice(self, budget: int) -> str:
+        kept = ", ".join(self.classes) or "nothing (every block's forward " \
+            "is run again on the way back)"
+        return (f"=> residuals kept through the rematerialisation: {kept} "
+                f"({self.megabytes:,} MB a step of a budget of "
+                f"{round(budget / 1e6):,} MB)")
+
+
+def residual_classes(config: Lfm2Config, shape, dtype):
+    """The residuals a rematerialised block can keep, by class: ``(what
+    it is, the names it keeps, the bytes they hold over all layers)`` for
+    a step on token rows of ``shape`` ``(rows, length)``. The order is
+    the order of keeping: milliseconds of re-run forward saved per byte
+    held on the chip, dearest first (310, 14, 10-12 and 9 ms a GB at
+    16,384 tokens a step: PERF.md section 6, PR 34, which also says what
+    was measured and left out: the routing, the experts' grouped
+    products)."""
+    rows, length = shape
+    tokens, item = rows * length, jnp.dtype(dtype).itemsize
+    attn = sum(t == "full_attention" for t in config.layer_types)
+    conv = config.num_hidden_layers - attn
+    heads, kv_heads, d = (config.num_attention_heads,
+                          config.num_key_value_heads, config.head_dim)
+    # the attention pads a row up to whole blocks, its residuals with it
+    block = min(attention_op.DEFAULT_BLOCK, length)
+    padded = rows * -(-length // block) * block
+    return (
+        ("attention out+lse", attention_op.RESIDUAL_NAMES,
+         attn * padded * heads * (d * item + 4)),
+        ("q/k/v projections", ("attention_q", "attention_k", "attention_v"),
+         attn * tokens * (heads + 2 * kv_heads) * d * item),
+        # a short convolution's in_proj is three hidden sizes wide
+        ("mixer projections",
+         ("conv_in_proj", "conv_out_proj", "attention_out_proj"),
+         ((3 + 1) * conv + attn) * tokens * config.hidden_size * item),
+        ("dense feed-forward", ("ffn_gate", "ffn_up"),
+         config.num_dense_layers * 2 * tokens * config.intermediate_size
+         * item),
+    )
+
+
+def kept_residuals(config: Lfm2Config, shape, dtype, budget: int) -> Kept:
+    """The classes a step on token rows of ``shape`` keeps within
+    ``budget`` bytes: whole classes (all layers or none), in the order of
+    ``residual_classes``, up to the first that no longer fits. A class
+    the share has no layer for holds nothing and is not listed."""
+    classes, names, total = [], [], 0
+    for what, class_names, size in residual_classes(config, shape, dtype):
+        if total + size > budget:
+            break
+        if size:
+            classes.append(what)
+            names.extend(class_names)
+            total += size
+    return Kept(tuple(classes), tuple(names), total)
+
+
 class RMSNorm(nn.Module):
     """``x / sqrt(mean(x^2) + eps) * weight`` over the last axis, the
     statistics in float32."""
@@ -225,9 +319,9 @@ class ShortConv(nn.Module):
         cfg = self.config
         taps = cfg.conv_L_cache
         with jax.named_scope("conv_mixer"):
-            b, c, u = jnp.split(
-                _dense(3 * cfg.hidden_size, "in_proj", self.dtype)(x), 3,
-                axis=-1)
+            b, c, u = jnp.split(checkpoint_name(
+                _dense(3 * cfg.hidden_size, "in_proj", self.dtype)(x),
+                "conv_in_proj"), 3, axis=-1)
             # [taps, channels]: tap k weighs the input taps-1-k steps back
             kernel = self.param(
                 "conv", uniform_bound_init(1.0 / np.sqrt(taps)),
@@ -236,7 +330,9 @@ class ShortConv(nn.Module):
             length = x.shape[1]
             conv = sum(gated[:, k:k + length] * kernel[k]
                        for k in range(taps))
-            return _dense(cfg.hidden_size, "out_proj", self.dtype)(c * conv)
+            return checkpoint_name(
+                _dense(cfg.hidden_size, "out_proj", self.dtype)(c * conv),
+                "conv_out_proj")
 
 
 class Attention(nn.Module):
@@ -253,18 +349,21 @@ class Attention(nn.Module):
                               cfg.num_key_value_heads, cfg.head_dim)
         batch, length, _ = x.shape
         with jax.named_scope("attention"):
-            q = _dense(heads * d, "q_proj", self.dtype)(x).reshape(
-                batch, length, heads, d)
-            k = _dense(kv_heads * d, "k_proj", self.dtype)(x).reshape(
-                batch, length, kv_heads, d)
-            v = _dense(kv_heads * d, "v_proj", self.dtype)(x).reshape(
-                batch, length, kv_heads, d)
+            # named before the norms: their way back needs what went in
+            q, k, v = (
+                checkpoint_name(
+                    _dense(n * d, f"{which}_proj", self.dtype)(x),
+                    f"attention_{which}").reshape(batch, length, n, d)
+                for which, n in (("q", heads), ("k", kv_heads),
+                                 ("v", kv_heads)))
             q = RMSNorm(cfg.norm_eps, self.dtype, name="q_layernorm")(q)
             k = RMSNorm(cfg.norm_eps, self.dtype, name="k_layernorm")(k)
             q, k = rotary(q, cfg.rope_theta), rotary(k, cfg.rope_theta)
             out = causal_attention(q, k, v, scale=d ** -0.5)
-            return _dense(cfg.hidden_size, "out_proj", self.dtype)(
-                out.reshape(batch, length, heads * d))
+            return checkpoint_name(
+                _dense(cfg.hidden_size, "out_proj", self.dtype)(
+                    out.reshape(batch, length, heads * d)),
+                "attention_out_proj")
 
 
 class SwiGLU(nn.Module):
@@ -277,8 +376,11 @@ class SwiGLU(nn.Module):
     def __call__(self, x):
         cfg = self.config
         with jax.named_scope("dense_ffn"):
-            gate = _dense(cfg.intermediate_size, "w1", self.dtype)(x)
-            up = _dense(cfg.intermediate_size, "w3", self.dtype)(x)
+            gate = checkpoint_name(
+                _dense(cfg.intermediate_size, "w1", self.dtype)(x),
+                "ffn_gate")
+            up = checkpoint_name(
+                _dense(cfg.intermediate_size, "w3", self.dtype)(x), "ffn_up")
             return _dense(cfg.hidden_size, "w2", self.dtype)(
                 nn.silu(gate) * up)
 
@@ -423,17 +525,37 @@ class Lfm2(nn.Module):
     ``moe_counts`` ``[expert layers, experts held]`` int32, the tokens
     each held expert got; ``moe_slots``, the slots routed in all (tokens
     x k x expert layers, held or not); ``moe_dropped``, the tokens
-    dropped: a constant 0, there for the day a capacity scheme moves it.
+    dropped: a constant 0, there for the day a capacity scheme moves it;
+    ``kept_residual_mb``, the megabytes this step's blocks keep through
+    the rematerialisation: a constant of the traced program.
+
+    ``residual_budget``: the bytes the blocks may keep (``kept_residuals``);
+    0 keeps nothing.
     """
 
     config: Lfm2Config
     dtype: Any = jnp.float32
+    residual_budget: int = 0
 
     task = "tokens"
 
     def example_input(self):
         """One row as ``init`` takes it."""
         return jnp.zeros((1, self.config.sequence_length), jnp.int32)
+
+    def kept(self, rows: int) -> Kept:
+        """What a step on ``rows`` rows keeps."""
+        return kept_residuals(
+            self.config, (rows, self.config.sequence_length), self.dtype,
+            self.residual_budget)
+
+    def fitted_to(self, device_bytes: int, state_bytes: int) -> "Lfm2":
+        """This model with the budget a device of ``device_bytes`` leaves
+        once the train state (``state_bytes``) and the step's own room
+        are taken; 0 where the device reports no size."""
+        budget = device_bytes - state_bytes - STEP_HEADROOM_BYTES
+        return self.clone(
+            residual_budget=max(budget, 0) if device_bytes else 0)
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, labels=None, mask=None):
@@ -443,9 +565,13 @@ class Lfm2(nn.Module):
                          embedding_init=_dense_init, name="embed_tokens")
         with jax.named_scope("embed"):
             x = embed(tokens)
+        kept = kept_residuals(cfg, tokens.shape, self.dtype,
+                              self.residual_budget)
+        policy = jax.checkpoint_policies.save_only_these_names(
+            *kept.names) if kept.names else None
         counts = []
         for i, layer_type in enumerate(cfg.layer_types):
-            x, sizes = nn.remat(Block)(
+            x, sizes = nn.remat(Block, policy=policy)(
                 cfg, layer_type, i < cfg.num_dense_layers, self.dtype,
                 name=f"layers_{i}")(x)
             if sizes.shape[0]:
@@ -465,6 +591,7 @@ class Lfm2(nn.Module):
                 tokens.size * cfg.num_experts_per_tok * len(counts),
                 jnp.int32)
             sums["moe_dropped"] = jnp.zeros((), jnp.int32)
+        sums["kept_residual_mb"] = jnp.asarray(kept.megabytes, jnp.int32)
         return sums
 
 

@@ -6,7 +6,12 @@ at 8,192 tokens would hold 32 x 8192^2 x 4 B = 8.6 GB of scores a row.
 Here the scores exist one ``[block, block]`` tile at a time, with a
 running maximum and denominator per query (the online softmax of flash
 attention), and the backward pass recomputes each tile from the saved
-log-sum-exp instead of keeping it.
+log-sum-exp instead of keeping it. The backward rule's residuals are
+``(q, k, v, out, lse)``; the forward rule names the two that only the
+forward scan can make (``RESIDUAL_NAMES``), so that a caller which
+rematerialises the layer round this call can keep them
+(``jax.checkpoint_policies.save_only_these_names``) and the scan is not
+run a second time on the way back.
 
 One ``lax.scan`` walks the tiles ``(i, j)`` with ``j <= i`` only (the
 causal lower triangle, 136 of 256 tiles at 16 blocks), so no tile that
@@ -31,9 +36,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 DEFAULT_BLOCK = 512
 _MASKED = -1e30  # finite: exp(masked - max) underflows to 0, never NaN
+# the residuals only the forward scan can make: the output in tile layout
+# ``[B, Hkv, S * G, D]`` and the float32 log-sum-exp ``[B, Hkv, S * G]``
+RESIDUAL_NAMES = ("attention_out", "attention_lse")
 
 
 def _tile_pairs(num_blocks: int):
@@ -163,7 +172,10 @@ def _attend(q, k, v, block, groups, scale):
 
 
 def _attend_fwd(q, k, v, block, groups, scale):
-    out, lse = _forward(q, k, v, block, groups, scale)
+    # the names sit HERE, on both residuals: a name on the call's result
+    # alone leaves ``lse`` to be made again, by the whole scan
+    out, lse = map(checkpoint_name,
+                   _forward(q, k, v, block, groups, scale), RESIDUAL_NAMES)
     return out, (q, k, v, out, lse)
 
 

@@ -560,6 +560,18 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
     if pretrained_vars is None:
         # create_train_state ran ``model.init``: that was model_init
         _setup_phase("state_commit")
+    if hasattr(model, "fitted_to"):
+        # a model that can keep residuals through its rematerialisation
+        # learns what the chip has beside the train state; a device that
+        # reports no size (the CPU) leaves it keeping nothing
+        stats = jax.local_devices()[0].memory_stats() or {}
+        model = model.fitted_to(
+            stats.get("bytes_limit", 0),
+            sum(x.nbytes for x in jax.tree_util.tree_leaves(state)))
+        state = state.replace(apply_fn=model.apply)
+        # (the step decides again from the rows it is traced on)
+        _say([model.kept(derived.per_device_batch_size)
+              .notice(model.residual_budget)])
 
     best_acc1, start_epoch, resume_step = 0.0, cfg.start_epoch, 0
     elastic_resume = None  # set when DPTPU_ELASTIC re-maps a geometry

@@ -52,7 +52,9 @@ class MoeLoad:
     tokens at the busiest held expert and at the mean one, the routed
     slots that fell on held experts, the tokens dropped. ``take`` gives
     what one fetch adds as the ``fetch`` span's attributes; ``stats``
-    the epoch's averages."""
+    the epoch's averages. ``kept_residual_mb`` (the megabytes the
+    model's blocks keep through their rematerialisation, a constant of
+    the step program) is passed on as it is."""
 
     def __init__(self):
         self.steps = 0
@@ -62,11 +64,14 @@ class MoeLoad:
     def take(self, fetched) -> dict:
         """``fetched``: the metrics of the steps one fetch read. Empty
         where the model has no experts."""
+        kept = {"kept_residual_mb": int(m["kept_residual_mb"])
+                for m in fetched[-1:] if "kept_residual_mb" in m}
         steps = [m for m in fetched if "moe_counts" in m]
         if not steps:
-            return {}
+            return kept
         counts = [np.asarray(m["moe_counts"], np.float64) for m in steps]
         add = {
+            **kept,
             "moe_load_max": float(np.mean([c.max(axis=1).mean()
                                            for c in counts])),
             "moe_load_mean": float(np.mean([c.mean() for c in counts])),

@@ -148,7 +148,10 @@ def token_loss_and_grads(state, batch, denom, gather_params=None,
 
     (_, (loss, sums, new_stats)), grads = jax.value_and_grad(
         loss_fn, has_aux=True)(state.params)
-    moe = {k: v for k, v in sums.items() if k.startswith("moe_")}
+    # what the model counts beside the loss: the expert layers' load and
+    # the residuals its blocks keep
+    moe = {k: v for k, v in sums.items()
+           if k.startswith("moe_") or k == "kept_residual_mb"}
     return (loss, sums["correct1"] / rows, sums["correct5"] / rows,
             new_stats, moe), grads
 
@@ -333,7 +336,9 @@ def train_step_body(state, batch, *, compute_dtype, lr_schedule, seed,
         new_stats, loss, top1, top5 = lax.pmean(
             (new_stats, loss, top1, top5), pmean_axes
         )
-        moe = lax.psum(moe, pmean_axes)  # counts add up over the replicas
+        # counts add up over the replicas (the megabytes kept too: the
+        # step's, on all its chips)
+        moe = lax.psum(moe, pmean_axes)
     # SGD's chain is elementwise, so it is equally valid on full params
     # (DDP) and ZeRO-1 shard-local slices; LARS/LAMB additionally need
     # per-layer norms, which the injected `tx`'s sumsq_reduce completes

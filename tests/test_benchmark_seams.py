@@ -1,9 +1,10 @@
 """The benchmark's seams in tier-1: ``benchmark/tests/test_seams.py``'s
 cases (a configuration of another family lands as files: feed, family,
-optimizer, each found by name) run here as they stand, and one more holds
+optimizer, each found by name) run here as they stand, one more holds
 the configuration this repository added that way,
 ``lfm2-8b-a1b-ep4-bf16``, to its contracts and to the trainer's
-arguments."""
+arguments, and the cases of ``benchmark/tests/test_kept_residual_mb.py``
+(the reader of the token model's kept residuals) run here too."""
 
 import json
 import os
@@ -15,6 +16,13 @@ import pytest
 from benchmark.lib import cells, drive
 from benchmark.tests.test_seams import *  # noqa: F401,F403 (the 28 cases)
 from benchmark.tests.test_seams import seam_cell  # noqa: F401 (their fixture)
+# the reader of the token model's kept residuals, on its fixtures (7 cases)
+from benchmark.tests.test_kept_residual_mb import (  # noqa: F401
+    test_a_parents_log_reads_nothing,
+    test_a_window_whose_only_carriers_were_warm_up_reads_nothing,
+    test_listed_for_the_token_cell_alone_and_as_its_data_file_has_it,
+    test_reads_the_megabytes_off_the_timed_fetch_spans,
+)
 
 CELL = "lfm2moe-fit-8k-1chip"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -30,7 +38,7 @@ def test_the_lfm2_cell_lands_as_files_and_keeps_every_contract():
                          ("reference/optimizers", cell.optimizer)):
         assert all(hasattr(module, a) for a in cells.CONTRACTS[kind])
     for metric in ("expert_load_max_over_mean", "expert_local_slot_share",
-                   "expert_dropped_tokens"):
+                   "expert_dropped_tokens", "kept_residual_mb"):
         assert metric in {m["name"] for m in cell.per_layer}
         assert callable(cells.reader(metric).read)
     assert "collective_exposed_ms" not in {m["name"] for m in cell.per_layer}

@@ -14,6 +14,7 @@ its own norm, a leaf at a time.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +31,7 @@ from dptpu.models.pretrained import (
     torch_key_map,
 )
 from dptpu.models.registry import _REGISTRY, model_task, register_model
+from dptpu.ops import attention as attention_op
 from dptpu.ops.attention import causal_attention, plain_causal_attention
 from dptpu.ops.loss import token_cross_entropy_sums
 from dptpu.train.state import create_train_state, make_optimizer
@@ -371,6 +373,229 @@ def test_blockwise_token_loss_is_the_whole_one(tokens, block):
     want_g = jax.grad(lambda h, e: whole(h, e)[0], (0, 1))(hidden, embedding)
     for g, w in zip(got_g, want_g):
         np.testing.assert_allclose(g, w, atol=1e-5)
+
+
+# ------------------------------- residuals kept through rematerialisation --
+
+
+def _class_bytes(config, shape, dtype=jnp.bfloat16):
+    """Bytes of each class for a step on rows of ``shape``, in order."""
+    return [size for _, _, size in
+            lfm2.residual_classes(config, shape, dtype)]
+
+
+# layers 1-2 of TINY: a short convolution before a dense feed-forward,
+# an attention before experts, so every class has something to keep
+TWO = TINY.held(layers=(1, 2))
+
+
+@functools.lru_cache(maxsize=None)
+def _loss_and_grads(dtype, budget):
+    _, _, _, variables = seeded(TWO, bias_scale=0.05)
+    net = lfm2.Lfm2(TWO, dtype=jnp.dtype(dtype), residual_budget=budget)
+    batch = rows(TWO)
+
+    def loss(params):
+        sums = net.apply({**variables, "params": params}, batch["tokens"],
+                         labels=batch["labels"],
+                         mask=jnp.asarray(batch["mask"], jnp.float32))
+        return sums["loss_sum"] / 128, sums["kept_residual_mb"]
+
+    (loss, kept), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(
+        variables["params"])
+    return loss, grads, int(kept)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("upto", [0, 1, 3, 4],
+                         ids=["nothing", "attention", "all-but-one",
+                              "unbounded"])
+def test_keeping_residuals_changes_no_loss_and_no_gradient(upto, dtype):
+    budget = 2**62 if upto == 4 else sum(
+        _class_bytes(TWO, (2, 64), dtype)[:upto])
+    kept = lfm2.kept_residuals(TWO, (2, 64), dtype, budget)
+    assert len(kept.classes) == upto
+    want_loss, want, nothing_kept = _loss_and_grads(dtype, 0)
+    got_loss, got, kept_mb = _loss_and_grads(dtype, budget)
+    assert nothing_kept == 0 and kept_mb == kept.megabytes
+    flat_want = jax.tree_util.tree_leaves_with_path(want)
+    flat_got = jax.tree_util.tree_leaves(got)
+    assert len(flat_want) == len(flat_got) > 30
+    if dtype == "float32":
+        # a kept value is the value that would have been made again
+        assert float(got_loss) == float(want_loss)
+        for (path, a), b in zip(flat_want, flat_got):
+            np.testing.assert_array_equal(np.asarray(b), np.asarray(a),
+                                          err_msg=str(path))
+    else:
+        # bfloat16: the CPU's compiler computes a fused chain in float32
+        # and rounds at its end, so a value made again inside a fusion
+        # skips roundings the kept one (the forward pass's own) went
+        # through: a few bfloat16 ulps (2^-8 each), 1.44% of a leaf's
+        # largest entry at the worst here, and not the 2e-5 that holds
+        # the float32 sums above
+        assert float(got_loss) == pytest.approx(float(want_loss), abs=1e-5)
+        for (path, a), b in zip(flat_want, flat_got):
+            scale = max(float(np.abs(a).max()), 1e-6)
+            np.testing.assert_allclose(
+                np.asarray(b), np.asarray(a), atol=3e-2 * scale,
+                err_msg=str(path))
+
+
+def _whiles(fn, *args) -> int:
+    """``while`` loops in the program compiled for ``fn``."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return len(re.findall(r"\bwhile\(", text))
+
+
+def test_keeping_out_and_lse_saves_one_forward_scan_a_layer():
+    two_attention = lfm2.Lfm2Config(
+        vocab_size=64, hidden_size=64, intermediate_size=96,
+        moe_intermediate_size=48, num_hidden_layers=2,
+        layer_types=("full_attention",) * 2, num_dense_layers=2,
+        num_attention_heads=2, num_key_value_heads=1, num_experts=8,
+        num_experts_per_tok=2, sequence_length=1024)
+    # two blocks of 512: three tiles, so the scans stay loops
+    tokens = jnp.zeros((1, 1024), jnp.int32)
+
+    def loops(budget):
+        net = lfm2.Lfm2(two_attention, residual_budget=budget)
+        variables = net.init(jax.random.PRNGKey(0), tokens)
+        return _whiles(jax.value_and_grad(lambda p: jnp.sum(net.apply(
+            {"params": p}, tokens))), variables["params"])
+
+    class_a = _class_bytes(two_attention, (1, 1024), jnp.float32)[0]
+    # a layer's scans: forward, forward again on the way back, backward
+    assert loops(0) == 6
+    assert loops(class_a - 1) == 6
+    assert loops(class_a) == 4
+
+
+def test_a_name_on_the_calls_result_alone_does_not_save_the_scan():
+    """Why the names sit inside the forward rule, on both residuals: with
+    ``out`` named where the attention is called, ``lse`` is still made
+    again, and only the scan makes it."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    def layer(x, w):
+        q = (x @ w).reshape(1, 64, 2, 32)
+        out = causal_attention(q, q[:, :, :1], q[:, :, :1], scale=0.2,
+                               block=16)
+        return checkpoint_name(out, "call_site_out").reshape(x.shape) + x
+
+    def two_layers(*names):
+        policy = jax.checkpoint_policies.save_only_these_names(*names)
+
+        def loss(w, x):
+            for i in range(2):
+                x = jax.checkpoint(layer, policy=policy)(x, w[i])
+            return jnp.sum(x)
+        return jax.value_and_grad(loss)
+
+    w, x = jnp.full((2, 64, 64), 0.01), jnp.ones((1, 64, 64))
+    assert _whiles(two_layers(), w, x) == 6
+    assert _whiles(two_layers("call_site_out"), w, x) == 6
+    assert _whiles(two_layers(*attention_op.RESIDUAL_NAMES), w, x) == 4
+    for a, b in zip(two_layers()(w, x),
+                    two_layers(*attention_op.RESIDUAL_NAMES)(w, x)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+CELL_SHARE = dict(layers=(1, 5), experts=(0, 8), vocab=(0, 16384),
+                  sequence_length=8192)
+CELL_STATE_BYTES = 3 * 4 * 507_820_160  # float32 weights, AdamW's moments
+
+
+def test_the_cells_step_keeps_the_frozen_classes_and_counts_their_bytes():
+    share = lfm2.Lfm2Config().held(**CELL_SHARE)
+    classes = lfm2.residual_classes(share, (2, 8192), jnp.bfloat16)
+    assert [what for what, _, _ in classes] == [
+        "attention out+lse", "q/k/v projections", "mixer projections",
+        "dense feed-forward"]
+    sizes = [size for _, _, size in classes]
+    # out [2, 8, 32768, 64] bf16 and lse [2, 8, 32768] f32, one layer
+    assert sizes[0] == 2 * 8 * 32768 * (64 * 2 + 4) == 69_206_016
+    # q [16384, 2048], k and v [16384, 512] bf16
+    assert sizes[1] == 16384 * 3072 * 2 == 100_663_296
+    # in_proj [16384, 6144] + out_proj [16384, 2048] on four short
+    # convolutions, out_proj on the attention
+    assert sizes[2] == (4 * 4 + 1) * 16384 * 2048 * 2 == 1_140_850_688
+    # w1 and w3 [16384, 7168], one dense layer
+    assert sizes[3] == 2 * 16384 * 7168 * 2 == 469_762_048
+    # the chip reports 16.9 GB; at 16 GB the same classes are kept
+    for device_bytes in (16 * 10**9, 16_909_336_064):
+        net = lfm2.Lfm2(share, dtype=jnp.bfloat16).fitted_to(
+            device_bytes, CELL_STATE_BYTES)
+        assert net.residual_budget == device_bytes - CELL_STATE_BYTES \
+            - lfm2.STEP_HEADROOM_BYTES
+        kept = net.kept(rows=2)
+        assert kept.classes == tuple(what for what, _, _ in classes)
+        assert kept.names == tuple(n for _, names, _ in classes
+                                   for n in names)
+        assert kept.bytes == sum(sizes) == 1_780_482_048
+        assert kept.megabytes == 1780 and kept.bytes <= net.residual_budget
+        assert all(c in kept.notice(net.residual_budget)
+                   for c in kept.classes)
+    # a device that reports no size, or one the state fills: nothing kept
+    assert net.fitted_to(0, CELL_STATE_BYTES).residual_budget == 0
+    assert net.fitted_to(8 * 10**9, CELL_STATE_BYTES).kept(2) == lfm2.Kept()
+
+
+@pytest.mark.parametrize("shape,layers", [
+    ((2, 8192), (1, 5)),       # the cell
+    ((2, 8192), (0, 24)),      # every layer
+    ((2, 32768), (1, 5)),      # rows four times as long
+    ((1, 50), (1, 2)),         # a row the attention pads
+], ids=["cell", "24-layers", "32k-rows", "padded"])
+def test_the_choice_is_monotone_and_stays_inside_the_budget(shape, layers):
+    share = lfm2.Lfm2Config().held(**{**CELL_SHARE, "layers": layers,
+                                      "sequence_length": shape[1]})
+    sizes = _class_bytes(share, shape)
+    assert lfm2.kept_residuals(share, shape, jnp.bfloat16, 0) == lfm2.Kept()
+    before = lfm2.Kept()
+    for budget in sorted({0, 1, *np.cumsum(sizes), *(np.cumsum(sizes) - 1),
+                          2**62}):
+        kept = lfm2.kept_residuals(share, shape, jnp.bfloat16, int(budget))
+        assert kept.bytes <= budget
+        assert kept.classes[:len(before.classes)] == before.classes
+        assert kept.names[:len(before.names)] == before.names
+        assert kept.bytes >= before.bytes
+        before = kept
+    assert before.bytes == sum(sizes)
+    # a larger share keeps strictly less of the budget the cell has
+    cell = lfm2.Lfm2Config().held(**CELL_SHARE)
+    budget = 16_909_336_064 - CELL_STATE_BYTES - lfm2.STEP_HEADROOM_BYTES
+    at_cell = lfm2.kept_residuals(cell, (2, 8192), jnp.bfloat16, budget)
+    here = lfm2.kept_residuals(share, shape, jnp.bfloat16, budget)
+    if shape[0] * shape[1] > 16384 or share.num_hidden_layers > 5:
+        assert len(here.classes) < len(at_cell.classes) == 4
+    if shape == (1, 50):
+        # 50 tokens, not padded (one block of the row's own length)
+        assert sizes[0] == 50 * 32 * (64 * 2 + 4)
+
+
+@pytest.mark.parametrize("experts", [True, False],
+                         ids=["with-experts", "dense-only"])
+def test_the_loop_passes_the_megabytes_kept_on_as_they_are(experts):
+    """A constant of the step program: the ``fetch`` span says it once,
+    however many steps the fetch read, with the expert layers' load or
+    (a share of dense layers only) without."""
+    from dptpu.train.loop import MoeLoad
+
+    step = {"loss": 1.0, "kept_residual_mb": np.int32(1793)}
+    if experts:
+        step.update(moe_counts=np.full((4, 8), 2048), moe_slots=262144,
+                    moe_dropped=0)
+    load = MoeLoad()
+    attrs = load.take([step] * 3)
+    assert attrs["kept_residual_mb"] == 1793
+    assert type(attrs["kept_residual_mb"]) is int
+    assert ("moe_slots" in attrs) is experts
+    if experts:
+        assert attrs["moe_slots"] == 3 * 262144  # a sum over the steps
+    assert "kept_residual_mb" not in load.stats()
+    # a model that counts neither, and a fetch that read nothing
+    assert load.take([{"loss": 1.0}]) == {} and load.take([]) == {}
 
 
 # ------------------------------------------------- names and configuration --

@@ -7,6 +7,7 @@ second as if never stopped (the tiny model of ``tests/test_lfm2.py``)."""
 
 import os
 
+import jax
 import numpy as np
 import pytest
 
@@ -94,12 +95,34 @@ def test_fit_trains_validates_and_resumes_a_token_model(
         tiny_arch, tmp_path, monkeypatch, capsys):
     from dptpu.cli import main_apex
 
+    from dptpu.models import lfm2
+
     monkeypatch.chdir(tmp_path)
     monkeypatch.setenv("WORLD_SIZE", "1")
+    # the CPU reports no memory, so fit() would keep nothing through the
+    # rematerialisation: stand in a device of 1 GB beside the state, and
+    # every run below keeps every class
+    asked, fitted_to = [], lfm2.Lfm2.fitted_to
+
+    def on_a_device_with_room(self, device_bytes, state_bytes):
+        asked.append((device_bytes, state_bytes))
+        return fitted_to(
+            self, state_bytes + lfm2.STEP_HEADROOM_BYTES + 10**9, state_bytes)
+
+    monkeypatch.setattr(lfm2.Lfm2, "fitted_to", on_a_device_with_room)
     ckpt = str(tmp_path / "ckpt")
     straight = main_apex(["tokens:64", *_ARGS, "--epochs", "2",
                           "--ckpt-dir", str(tmp_path / "straight")])
     out = capsys.readouterr().out
+    # float32 weights and AdamW's two moments, to a few scalars
+    (device_bytes, state_bytes), = asked
+    params = sum(x.size for x in jax.tree_util.tree_leaves(
+        straight["state"].params))
+    assert device_bytes == 0 and 0 <= state_bytes - 12 * params < 4096
+    assert ("=> residuals kept through the rematerialisation: attention "
+            "out+lse, q/k/v projections, mixer projections, dense "
+            "feed-forward (0 MB a step of a budget of 1,000 MB)"
+            ) in out  # 0.1 MB at this size
     epoch = straight["history"][0]
     # uniform random ids: the loss cannot pass ln(128), and starts above
     assert np.log(128) - 0.05 < epoch["train_loss"] < 6.0

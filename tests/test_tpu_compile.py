@@ -86,3 +86,37 @@ def test_blockwise_attention_compiles_for_the_chip_at_the_cells_shape(
     # never the [heads, S, S] scores (8.6 GB a row): two scans' carries
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
     assert compiled.as_text().count("while(") >= 2
+
+
+def test_a_rematerialised_attention_block_keeps_out_and_lse_on_the_chip(
+        one_chip):
+    """An attention layer at the cell's shape as the model wraps it (a
+    dense feed-forward behind it: the experts have their own case
+    above), ``out`` and ``lse`` kept through the rematerialisation: the
+    forward scan is in the program once, not twice."""
+    from flax import linen as nn
+
+    share = lfm2.Lfm2Config().held(layers=(2, 1), sequence_length=8192)
+    kept = lfm2.kept_residuals(share, (2, 8192), jnp.bfloat16, 70_000_000)
+    assert kept.classes == ("attention out+lse",) and kept.bytes == 69_206_016
+    block = nn.remat(lfm2.Block, policy=jax.checkpoint_policies
+                     .save_only_these_names(*kept.names))(
+        share, "full_attention", True, jnp.bfloat16)
+    x = _shape((2, 8192, HIDDEN), jnp.bfloat16, one_chip)
+    variables = jax.tree_util.tree_map(
+        lambda leaf: _shape(leaf.shape, leaf.dtype, one_chip),
+        jax.eval_shape(block.init, jax.random.PRNGKey(0), x))
+
+    def loss(params, buffers, x):
+        out, sizes = block.apply({"params": params, **buffers}, x)
+        return jnp.sum(out.astype(jnp.float32)), sizes
+
+    params = variables.pop("params")
+    compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+        params, variables, x).compile()
+    scans = [line for line in compiled.as_text().splitlines()
+             if " while(" in line and "f32[2,8,32768,64]" in line]
+    assert len(scans) == 2, scans  # forward and backward, no forward again
+    # one layer's activations and its weights' gradients: 1.06 GB when
+    # this was written (1.50 GB and three scans with nothing kept)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
