@@ -11,6 +11,7 @@ from dptpu.models import densenet as _densenet  # noqa: F401
 from dptpu.models import efficientnet as _efficientnet  # noqa: F401
 from dptpu.models import googlenet as _googlenet  # noqa: F401
 from dptpu.models import inception as _inception  # noqa: F401
+from dptpu.models import joyai as _joyai  # noqa: F401
 from dptpu.models import lfm2 as _lfm2  # noqa: F401
 from dptpu.models import maxvit as _maxvit  # noqa: F401
 from dptpu.models import mnasnet as _mnasnet  # noqa: F401

@@ -70,17 +70,25 @@ megabytes kept (``kept_residual_mb``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
-from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from dptpu.models import token_model
 from dptpu.models.layers import uniform_bound_init
 from dptpu.models.registry import register_model
+from dptpu.models.token_model import (  # noqa: F401 (this model's names)
+    Kept,
+    RMSNorm,
+    SparseExperts,
+    held_expert_outputs,
+    rotary,
+)
 from dptpu.ops import attention as attention_op
 from dptpu.ops.attention import causal_attention
 from dptpu.ops.loss import token_cross_entropy_sums
@@ -136,12 +144,7 @@ class Lfm2Config:
                              "key/value heads the query heads")
         if self.conv_bias:
             raise ValueError("conv_bias is not implemented (LFM2 has none)")
-        first, count = self.experts_here
-        if not (0 <= first and 0 < count
-                and first + count <= self.num_experts):
-            raise ValueError(
-                f"experts held {first}:{count} are not among the "
-                f"{self.num_experts} experts")
+        self.routing  # refuses experts held that are not among them
 
     @property
     def head_dim(self) -> int:
@@ -151,6 +154,16 @@ class Lfm2Config:
     def experts_here(self) -> Tuple[int, int]:
         """``(first, count)`` of the experts this chip holds."""
         return self.experts_held or (0, self.num_experts)
+
+    @property
+    def routing(self) -> token_model.Routing:
+        """The expert layer's view of this configuration."""
+        return token_model.Routing(
+            experts=self.num_experts, held=self.experts_here,
+            top_k=self.num_experts_per_tok, norm_topk=self.norm_topk_prob,
+            norm_eps=ROUTE_NORM_EPS, scaling=self.routed_scaling_factor,
+            use_bias=self.use_expert_bias,
+            width=self.moe_intermediate_size)
 
     def held(self, layers: Optional[Tuple[int, int]] = None,
              experts: Optional[Tuple[int, int]] = None,
@@ -162,12 +175,8 @@ class Lfm2Config:
         vocabulary's rows."""
         changes = {}
         if layers is not None:
-            first, count = layers
-            if not (0 <= first and 0 < count
-                    and first + count <= self.num_hidden_layers):
-                raise ValueError(
-                    f"layers {first}:{count} are not among the "
-                    f"{self.num_hidden_layers} layers")
+            first, count = token_model.held_range(
+                layers, self.num_hidden_layers, "layers")
             changes.update(
                 layer_types=self.layer_types[first:first + count],
                 num_hidden_layers=count,
@@ -176,12 +185,8 @@ class Lfm2Config:
         if experts is not None:
             changes["experts_held"] = tuple(experts)
         if vocab is not None:
-            first, count = vocab
-            if not (0 <= first and 0 < count
-                    and first + count <= self.vocab_size):
-                raise ValueError(
-                    f"vocabulary rows {first}:{count} are not among the "
-                    f"{self.vocab_size} rows")
+            _, count = token_model.held_range(
+                vocab, self.vocab_size, "vocabulary rows")
             # ids are local to the slice (the data draws them below its
             # size), so only the count shapes anything on one chip
             changes["vocab_size"] = count
@@ -192,7 +197,10 @@ class Lfm2Config:
         return dataclasses.replace(self, **changes)
 
 
-_dense_init = nn.initializers.normal(0.02)
+# the 1e-6 of the family's normalisation of a token's expert weights
+ROUTE_NORM_EPS = 1e-6
+route = functools.partial(token_model.route, eps=ROUTE_NORM_EPS)
+_dense = token_model.dense
 
 
 # What a step takes on the device beside the train state and the kept
@@ -202,26 +210,6 @@ _dense_init = nn.initializers.normal(0.02)
 # residuals are bounded by the budget, so a longer row or a larger share
 # keeps less and the step fits where it fitted without them.
 STEP_HEADROOM_BYTES = 6_000_000_000
-
-
-@dataclasses.dataclass(frozen=True)
-class Kept:
-    """What the blocks of one step keep through the rematerialisation."""
-
-    classes: Tuple[str, ...] = ()
-    names: Tuple[str, ...] = ()
-    bytes: int = 0
-
-    @property
-    def megabytes(self) -> int:
-        return round(self.bytes / 1e6)
-
-    def notice(self, budget: int) -> str:
-        kept = ", ".join(self.classes) or "nothing (every block's forward " \
-            "is run again on the way back)"
-        return (f"=> residuals kept through the rematerialisation: {kept} "
-                f"({self.megabytes:,} MB a step of a budget of "
-                f"{round(budget / 1e6):,} MB)")
 
 
 def residual_classes(config: Lfm2Config, shape, dtype):
@@ -239,12 +227,10 @@ def residual_classes(config: Lfm2Config, shape, dtype):
     conv = config.num_hidden_layers - attn
     heads, kv_heads, d = (config.num_attention_heads,
                           config.num_key_value_heads, config.head_dim)
-    # the attention pads a row up to whole blocks, its residuals with it
-    block = min(attention_op.DEFAULT_BLOCK, length)
-    padded = rows * -(-length // block) * block
     return (
+        # the attention pads a row up to whole blocks, its residuals with it
         ("attention out+lse", attention_op.RESIDUAL_NAMES,
-         attn * padded * heads * (d * item + 4)),
+         attn * attention_op.residual_bytes(rows, length, heads, d, dtype)),
         ("q/k/v projections", ("attention_q", "attention_k", "attention_v"),
          attn * tokens * (heads + 2 * kv_heads) * d * item),
         # a short convolution's in_proj is three hidden sizes wide
@@ -259,53 +245,10 @@ def residual_classes(config: Lfm2Config, shape, dtype):
 
 def kept_residuals(config: Lfm2Config, shape, dtype, budget: int) -> Kept:
     """The classes a step on token rows of ``shape`` keeps within
-    ``budget`` bytes: whole classes (all layers or none), in the order of
-    ``residual_classes``, up to the first that no longer fits. A class
-    the share has no layer for holds nothing and is not listed."""
-    classes, names, total = [], [], 0
-    for what, class_names, size in residual_classes(config, shape, dtype):
-        if total + size > budget:
-            break
-        if size:
-            classes.append(what)
-            names.extend(class_names)
-            total += size
-    return Kept(tuple(classes), tuple(names), total)
-
-
-class RMSNorm(nn.Module):
-    """``x / sqrt(mean(x^2) + eps) * weight`` over the last axis, the
-    statistics in float32."""
-
-    eps: float
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        x32 = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        return (x32 * lax.rsqrt(var + self.eps) * scale).astype(self.dtype)
-
-
-def _dense(features: int, name: str, dtype):
-    return nn.Dense(features, use_bias=False, dtype=dtype,
-                    kernel_init=_dense_init, name=name)
-
-
-def rotary(x, theta: float):
-    """Rotary positions over the whole head of ``x`` ``[B, S, H, D]``
-    (the half-split convention: ``x * cos + rotate_half(x) * sin``),
-    in float32."""
-    d = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float64) / d))
-    angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] \
-        * jnp.asarray(inv_freq, jnp.float32)[None, :]
-    angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
-    x32 = x.astype(jnp.float32)
-    x1, x2 = jnp.split(x32, 2, axis=-1)
-    rotated = jnp.concatenate([-x2, x1], axis=-1)
-    return (x32 * jnp.cos(angles) + rotated * jnp.sin(angles)).astype(x.dtype)
+    ``budget`` bytes (``token_model.keep_within`` over
+    ``residual_classes``)."""
+    return token_model.keep_within(
+        residual_classes(config, shape, dtype), budget)
 
 
 class ShortConv(nn.Module):
@@ -366,126 +309,6 @@ class Attention(nn.Module):
                 "attention_out_proj")
 
 
-class SwiGLU(nn.Module):
-    """``W2 (silu(W1 x) * W3 x)``."""
-
-    config: Lfm2Config
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        with jax.named_scope("dense_ffn"):
-            gate = checkpoint_name(
-                _dense(cfg.intermediate_size, "w1", self.dtype)(x),
-                "ffn_gate")
-            up = checkpoint_name(
-                _dense(cfg.intermediate_size, "w3", self.dtype)(x), "ffn_up")
-            return _dense(cfg.hidden_size, "w2", self.dtype)(
-                nn.silu(gate) * up)
-
-
-class _Expert(nn.Module):
-    """One expert's three matrices, under its published index."""
-
-    hidden: int
-    width: int
-
-    @nn.compact
-    def __call__(self):
-        return (self.param("w1", _dense_init, (self.hidden, self.width)),
-                self.param("w3", _dense_init, (self.hidden, self.width)),
-                self.param("w2", _dense_init, (self.width, self.hidden)))
-
-
-def route(scores, bias, k: int, norm_topk: bool, scaling: float):
-    """The experts of each token and their weights: the top ``k`` of
-    ``scores + bias``, weighted by ``scores`` itself at those k."""
-    _, chosen = lax.top_k(scores + bias, k)
-    weights = jnp.take_along_axis(scores, chosen, axis=-1)
-    if norm_topk:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-6)
-    return chosen, weights * scaling
-
-
-def held_expert_outputs(x, chosen, weights, w1, w3, w2, first: int):
-    """What the experts held here (``first .. first + len(w1)``) give for
-    the tokens routed to them: ``[tokens, hidden]`` in ``x``'s dtype, and
-    the tokens each of them got.
-
-    The ``tokens x k`` slots are sorted by expert, the slots of absent
-    experts behind all others; the held ones form one run per expert, and
-    three grouped matrix products (``lax.ragged_dot``) go over the runs.
-    The buffer is the worst case, every slot on a held expert, so no
-    capacity bounds a run and no token is dropped; rows behind the last
-    run belong to no group and cost the grouped product nothing. The
-    sorted rows go back to their tokens by the inverse
-    permutation and are summed by their weights.
-    """
-    tokens, k = chosen.shape
-    count = w1.shape[0]
-    local = chosen - first
-    key = jnp.where((local >= 0) & (local < count), local, count).reshape(-1)
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
-                    dtype=jnp.int32)
-    # rows behind the last run are in no group: the grouped product on
-    # the chip leaves what it does not compute as it finds it (whatever
-    # the memory held), in the result and in the cotangents alike, so
-    # those rows are zeroed going in and coming out, which zeroes their
-    # cotangents too (a token's slot on an absent expert adds nothing to
-    # the token's gradient)
-    in_a_run = jnp.arange(tokens * k)[:, None] < jnp.sum(sizes)
-    rows = jnp.where(in_a_run, x[order // k], 0)
-    hidden = nn.silu(lax.ragged_dot(rows, w1, sizes)) \
-        * lax.ragged_dot(rows, w3, sizes)
-    out = lax.ragged_dot(jnp.where(in_a_run, hidden, 0), w2, sizes)
-    out = jnp.where(in_a_run, out, 0)
-    back = jnp.argsort(order)
-    out = out[back].reshape(tokens, k, -1)
-    mixed = jnp.einsum("tkh,tk->th", out, weights.astype(out.dtype),
-                       preferred_element_type=jnp.float32)
-    return mixed.astype(x.dtype), sizes
-
-
-class SparseExperts(nn.Module):
-    """The expert layer: routes over all experts, computes the held
-    ones' part."""
-
-    config: Lfm2Config
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        first, count = cfg.experts_here
-        batch, length, hidden = x.shape
-        flat = x.reshape(batch * length, hidden)
-        with jax.named_scope("router"):
-            gate = self.param("gate", _dense_init,
-                              (hidden, cfg.num_experts))
-            scores = jax.nn.sigmoid(jnp.matmul(
-                flat.astype(jnp.float32), gate,
-                precision=lax.Precision.HIGHEST))
-            bias = 0.0
-            if cfg.use_expert_bias:
-                bias = self.variable(
-                    "batch_stats", "expert_bias", jnp.zeros,
-                    (cfg.num_experts,), jnp.float32).value
-            chosen, weights = route(
-                scores, bias, cfg.num_experts_per_tok, cfg.norm_topk_prob,
-                cfg.routed_scaling_factor)
-        with jax.named_scope("experts"):
-            w1, w3, w2 = (
-                jnp.stack(ws).astype(self.dtype) for ws in zip(*(
-                    _Expert(hidden, cfg.moe_intermediate_size,
-                            name=f"experts_{first + e}")()
-                    for e in range(count))))
-            out, sizes = held_expert_outputs(
-                flat, chosen, weights, w1, w3, w2, first)
-        return out.reshape(x.shape), sizes
-
-
 class Block(nn.Module):
     """One layer: a mixer and a feed-forward, each behind its norm and
     on the residual path. Returns the tokens each held expert got (none
@@ -508,70 +331,80 @@ class Block(nn.Module):
             raise ValueError(f"unknown layer type {self.layer_type!r}")
         normed = RMSNorm(cfg.norm_eps, self.dtype, name="ffn_norm")(x)
         if self.dense:
-            return x + SwiGLU(cfg, self.dtype, name="feed_forward")(normed), \
-                jnp.zeros((0,), jnp.int32)
+            return x + token_model.SwiGLU(
+                cfg.intermediate_size, self.dtype,
+                name="feed_forward")(normed), jnp.zeros((0,), jnp.int32)
         out, sizes = SparseExperts(cfg, self.dtype,
                                    name="feed_forward")(normed)
         return x + out, sizes
 
 
-class Lfm2(nn.Module):
-    """The model. ``__call__(tokens)`` gives float32 logits
-    ``[B, S, vocab held]``; with ``labels`` and ``mask`` it gives the
-    sums of the per-token loss and accuracies over the kept tokens, and
-    the expert layers' load:
-
-    ``loss_sum, count, correct1, correct5`` (float32 scalars);
-    ``moe_counts`` ``[expert layers, experts held]`` int32, the tokens
-    each held expert got; ``moe_slots``, the slots routed in all (tokens
-    x k x expert layers, held or not); ``moe_dropped``, the tokens
-    dropped: a constant 0, there for the day a capacity scheme moves it;
-    ``kept_residual_mb``, the megabytes this step's blocks keep through
-    the rematerialisation: a constant of the traced program.
-
-    ``residual_budget``: the bytes the blocks may keep (``kept_residuals``);
-    0 keeps nothing.
-    """
+class Lfm2(token_model.TokenModel):
+    """The model: ``token_model.TokenModel`` says what ``__call__``
+    takes and gives. The head is the embedding again (tied)."""
 
     config: Lfm2Config
-    dtype: Any = jnp.float32
-    residual_budget: int = 0
 
-    task = "tokens"
+    step_headroom_bytes = STEP_HEADROOM_BYTES
 
-    def example_input(self):
-        """One row as ``init`` takes it."""
-        return jnp.zeros((1, self.config.sequence_length), jnp.int32)
+    def residual_classes(self, shape):
+        return residual_classes(self.config, shape, self.dtype)
 
-    def kept(self, rows: int) -> Kept:
-        """What a step on ``rows`` rows keeps."""
-        return kept_residuals(
-            self.config, (rows, self.config.sequence_length), self.dtype,
-            self.residual_budget)
-
-    def fitted_to(self, device_bytes: int, state_bytes: int) -> "Lfm2":
-        """This model with the budget a device of ``device_bytes`` leaves
-        once the train state (``state_bytes``) and the step's own room
-        are taken; 0 where the device reports no size."""
-        budget = device_bytes - state_bytes - STEP_HEADROOM_BYTES
-        return self.clone(
-            residual_budget=max(budget, 0) if device_bytes else 0)
+    @staticmethod
+    def torch_key_map(variables):
+        """The ``lfm2_moe`` checkpoint's names (``model.embed_tokens``,
+        ``model.layers.N.{operator_norm, ffn_norm}``, ``.conv.{in_proj, conv,
+        out_proj}``, ``.self_attn.{q_proj, k_proj, v_proj, out_proj,
+        q_layernorm, k_layernorm}``, ``.feed_forward.{w1, w2, w3}``,
+        ``.feed_forward.{gate, expert_bias}``,
+        ``.feed_forward.experts.E.{w1, w2, w3}``, ``model.embedding_norm``;
+        written from memory of the published layout, there is no network
+        here). This file names its modules after them, so the map
+        is mechanical: ``layers_N`` <-> ``layers.N``, ``experts_E`` <->
+        ``experts.E``; every matrix is a torch Linear (OI <-> IO), the short
+        convolution's taps are torch's depthwise ``[channels, 1, taps]``
+        <-> ``[taps, channels]``, and ``expert_bias`` is a buffer with no
+        ``.weight``."""
+        out = {}
+        for collection in ("params", "batch_stats"):
+            flat = jax.tree_util.tree_flatten_with_path(
+                variables.get(collection, {}))[0]
+            for path, leaf in flat:
+                names = tuple(p.key for p in path)
+                mods = [n.replace("layers_", "layers.").replace(
+                    "experts_", "experts.") for n in names]
+                module = "model." + ".".join(mods[:-1])  # the leaf's module
+                itself = "model." + ".".join(mods)  # a raw torch Parameter
+                if names[-1] == "expert_bias":
+                    key, kind = itself, "direct"
+                elif names[-1] == "kernel":
+                    key, kind = module + ".weight", "dense"
+                elif names[-1] in ("scale", "embedding"):
+                    key, kind = module + ".weight", "direct"
+                elif names[-2:] == ("conv", "conv"):
+                    key, kind = itself + ".weight", "conv1d_dw"
+                elif leaf.ndim == 2:  # gate, an expert's w1/w2/w3
+                    key, kind = itself + ".weight", "dense"
+                else:
+                    raise ValueError(f"no lfm2_moe key for {'/'.join(names)}")
+                assert key not in out, f"duplicate torch key {key}"
+                out[key] = (collection, names, kind)
+        return out
 
     @nn.compact
     def __call__(self, tokens, train: bool = False, labels=None, mask=None):
         del train  # no dropout, no statistics: the two modes are one
         cfg = self.config
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=self.dtype,
-                         embedding_init=_dense_init, name="embed_tokens")
+                         embedding_init=token_model.dense_init,
+                         name="embed_tokens")
         with jax.named_scope("embed"):
             x = embed(tokens)
-        kept = kept_residuals(cfg, tokens.shape, self.dtype,
-                              self.residual_budget)
-        policy = jax.checkpoint_policies.save_only_these_names(
-            *kept.names) if kept.names else None
+        kept = self.kept_on(tokens.shape)
+        block = token_model.rematerialised(Block, kept)
         counts = []
         for i, layer_type in enumerate(cfg.layer_types):
-            x, sizes = nn.remat(Block, policy=policy)(
+            x, sizes = block(
                 cfg, layer_type, i < cfg.num_dense_layers, self.dtype,
                 name=f"layers_{i}")(x)
             if sizes.shape[0]:
@@ -585,46 +418,12 @@ class Lfm2(nn.Module):
             sums = token_cross_entropy_sums(
                 x.reshape(-1, cfg.hidden_size), embed.embedding,
                 labels.reshape(-1), mask.reshape(-1))
-        if counts:
-            sums["moe_counts"] = jnp.stack(counts)
-            sums["moe_slots"] = jnp.asarray(
-                tokens.size * cfg.num_experts_per_tok * len(counts),
-                jnp.int32)
-            sums["moe_dropped"] = jnp.zeros((), jnp.int32)
-        sums["kept_residual_mb"] = jnp.asarray(kept.megabytes, jnp.int32)
-        return sums
+        return token_model.with_counters(
+            sums, counts,
+            tokens.size * cfg.num_experts_per_tok * len(counts), kept)
 
 
-def _pair(text: str, what: str) -> Tuple[int, int]:
-    try:
-        first, count = (int(part) for part in str(text).split(":"))
-    except ValueError:
-        raise ValueError(
-            f"{what} {text!r} must be FIRST:COUNT, two whole numbers "
-            f"(0:8 holds the first eight)") from None
-    return first, count
-
-
-def factory(name: str, published: Lfm2Config):
-    """A registry factory for ``published``, whole or a chip's share of
-    it: ``layers``, ``experts`` and ``vocab`` are ``"first:count"`` (the
-    trainer's ``--layers``, ``--experts``, ``--vocab-rows``),
-    ``sequence_length`` its ``--seq-len``. ``fit()`` reads ``task`` off
-    the factory before it builds anything: the data source, the step's
-    loss and the arguments a factory is handed follow from it."""
-
-    def make(dtype=jnp.float32, layers=None, experts=None, vocab=None,
-             sequence_length=None):
-        return Lfm2(published.held(
-            layers=_pair(layers, "--layers") if layers else None,
-            experts=_pair(experts, "--experts") if experts else None,
-            vocab=_pair(vocab, "--vocab-rows") if vocab else None,
-            sequence_length=sequence_length), dtype=dtype)
-
-    make.__name__ = name
-    make.task = Lfm2.task
-    return make
-
+factory = functools.partial(token_model.factory, Lfm2)
 
 # LFM2-8B-A1B as its config.json gives it
 register_model(factory("lfm2_8b_a1b", Lfm2Config()))

@@ -55,6 +55,8 @@ from typing import Dict, Tuple
 import jax
 import numpy as np
 
+from dptpu.models.registry import model_key_map
+
 _LEAF_TO_TORCH = {
     "kernel": "weight",
     "scale": "weight",
@@ -354,55 +356,15 @@ def _torch_module(arch: str, mod: Tuple[str, ...]) -> str:
     raise ValueError(f"no torchvision key mapping for arch {arch!r}")
 
 
-def _lfm2_key_map(variables):
-    """The ``lfm2_moe`` checkpoint's names (``model.embed_tokens``,
-    ``model.layers.N.{operator_norm, ffn_norm}``, ``.conv.{in_proj, conv,
-    out_proj}``, ``.self_attn.{q_proj, k_proj, v_proj, out_proj,
-    q_layernorm, k_layernorm}``, ``.feed_forward.{w1, w2, w3}``,
-    ``.feed_forward.{gate, expert_bias}``,
-    ``.feed_forward.experts.E.{w1, w2, w3}``, ``model.embedding_norm``;
-    written from memory of the published layout, there is no network
-    here). dptpu/models/lfm2.py names its modules after them, so the map
-    is mechanical: ``layers_N`` <-> ``layers.N``, ``experts_E`` <->
-    ``experts.E``; every matrix is a torch Linear (OI <-> IO), the short
-    convolution's taps are torch's depthwise ``[channels, 1, taps]``
-    <-> ``[taps, channels]``, and ``expert_bias`` is a buffer with no
-    ``.weight``."""
-    out = {}
-    for collection in ("params", "batch_stats"):
-        flat = jax.tree_util.tree_flatten_with_path(
-            variables.get(collection, {}))[0]
-        for path, leaf in flat:
-            names = tuple(p.key for p in path)
-            mods = [n.replace("layers_", "layers.").replace(
-                "experts_", "experts.") for n in names]
-            module = "model." + ".".join(mods[:-1])  # the leaf's module
-            itself = "model." + ".".join(mods)  # a raw torch Parameter
-            if names[-1] == "expert_bias":
-                key, kind = itself, "direct"
-            elif names[-1] == "kernel":
-                key, kind = module + ".weight", "dense"
-            elif names[-1] in ("scale", "embedding"):
-                key, kind = module + ".weight", "direct"
-            elif names[-2:] == ("conv", "conv"):
-                key, kind = itself + ".weight", "conv1d_dw"
-            elif leaf.ndim == 2:  # gate, an expert's w1/w2/w3
-                key, kind = itself + ".weight", "dense"
-            else:
-                raise ValueError(f"no lfm2_moe key for {'/'.join(names)}")
-            assert key not in out, f"duplicate torch key {key}"
-            out[key] = (collection, names, kind)
-    return out
-
-
 def torch_key_map(arch: str, variables) -> Dict[str, Tuple[str, Tuple[str, ...], str]]:
     """``{torch_key: (collection, dptpu_path, kind)}`` for every leaf.
 
     ``kind`` is ``conv`` (4-D kernel, needs OIHW->HWIO), ``dense`` (2-D
     kernel, needs OI->IO) or ``direct``.
     """
-    if arch.startswith("lfm2"):
-        return _lfm2_key_map(variables)
+    own = model_key_map(arch)
+    if own is not None:  # a model that owns its checkpoint's names
+        return own(variables)
     out = {}
     for collection in ("params", "batch_stats"):
         tree = variables.get(collection, {})

@@ -153,6 +153,15 @@ def model_task(name) -> str:
     return getattr(_REGISTRY[name], "task", "images")
 
 
+def model_key_map(name):
+    """The function that gives ``name``'s checkpoint names
+    (``variables -> {torch_key: (collection, path, kind)}``) where the
+    model owns them (a factory's ``torch_key_map``; the token models do),
+    else None: ``dptpu.models.pretrained`` then maps ``name`` by the
+    torchvision tables."""
+    return getattr(_REGISTRY.get(name), "torch_key_map", None)
+
+
 def token_model_kwargs(cfg, task: str) -> dict:
     """The arguments a token-sequence model's factory takes from the
     command line (``--seq-len``, ``--layers``, ``--experts``,

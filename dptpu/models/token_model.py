@@ -1,0 +1,392 @@
+"""What the token-sequence models share (``lfm2.py``, ``joyai.py``).
+
+Both are built from a configuration object, cut to one chip's share of
+an expert-parallel, vocabulary-parallel, pipelined deployment, trained by
+``fit()`` on ``tokens:<N>`` (``task = "tokens"``) with rematerialised
+blocks that keep named residuals within a budget. This module holds the
+pieces that are the same mathematics in both, each once:
+
+* ``RMSNorm``, ``SwiGLU``, the half-split ``rotary``;
+* the expert layer: ``route`` (the top k of score + bias, weighted by
+  the scores themselves), ``held_expert_outputs`` (the part of the
+  result the experts held here give, no capacity, no dropped token) and
+  ``SparseExperts``, which reads what it needs from a ``Routing``;
+* ``Kept`` / ``keep_within``: which classes of named residuals a step
+  keeps through the rematerialisation within a budget of bytes;
+* ``TokenModel``: the module both models extend (``example_input``,
+  ``kept``, ``fitted_to``), ``rematerialised`` (a block under the policy
+  that keeps), ``with_counters`` (what a step counts beside the loss),
+  and ``factory``, the registry's way to a chip's share.
+
+A model says what is its own: its blocks, its ``residual_classes`` (the
+order of keeping is milliseconds saved per byte, measured on the chip for
+that model) and its ``step_headroom_bytes`` (the room its fully
+rematerialised step takes on the device beside state and residuals).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from flax import linen as nn
+from jax import lax
+from jax.ad_checkpoint import checkpoint_name
+
+dense_init = nn.initializers.normal(0.02)
+
+
+def dense(features: int, name: str, dtype):
+    return nn.Dense(features, use_bias=False, dtype=dtype,
+                    kernel_init=dense_init, name=name)
+
+
+class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * weight`` over the last axis, the
+    statistics in float32."""
+
+    eps: float
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        return (x32 * lax.rsqrt(var + self.eps) * scale).astype(self.dtype)
+
+
+def rotary(x, theta: float):
+    """Rotary positions over the whole last axis of ``x`` ``[B, S, H, D]``
+    (the half-split convention: ``x * cos + rotate_half(x) * sin``),
+    in float32."""
+    d = x.shape[-1]
+    inv_freq = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float64) / d))
+    angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] \
+        * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = jnp.split(x32, 2, axis=-1)
+    rotated = jnp.concatenate([-x2, x1], axis=-1)
+    return (x32 * jnp.cos(angles) + rotated * jnp.sin(angles)).astype(x.dtype)
+
+
+class SwiGLU(nn.Module):
+    """``W2 (silu(W1 x) * W3 x)`` at ``width``, under the trace scope
+    ``trace_scope``; ``keep`` names the two products for a rematerialisation
+    that keeps them (None: they carry no name)."""
+
+    width: int
+    dtype: Any = jnp.float32
+    trace_scope: str = "dense_ffn"
+    keep: Optional[Tuple[str, str]] = ("ffn_gate", "ffn_up")
+
+    @nn.compact
+    def __call__(self, x):
+        with jax.named_scope(self.trace_scope):
+            gate = dense(self.width, "w1", self.dtype)(x)
+            up = dense(self.width, "w3", self.dtype)(x)
+            if self.keep:
+                gate, up = map(checkpoint_name, (gate, up), self.keep)
+            return dense(x.shape[-1], "w2", self.dtype)(nn.silu(gate) * up)
+
+
+# ------------------------------------------------------- the expert layer --
+
+
+@dataclasses.dataclass(frozen=True)
+class Routing:
+    """What the expert layer reads of a configuration: the router's
+    width (``experts``, all of them, held or not), the ``(first, count)``
+    held here, the experts a token takes, how their weights are
+    normalised (``norm_eps`` is the family's: added to the sum) and
+    scaled, whether a selection bias exists, an expert's width."""
+
+    experts: int
+    held: Tuple[int, int]
+    top_k: int
+    norm_topk: bool
+    norm_eps: float
+    scaling: float
+    use_bias: bool
+    width: int
+
+    def __post_init__(self):
+        first, count = self.held
+        if not (0 <= first and 0 < count and first + count <= self.experts):
+            raise ValueError(
+                f"experts held {first}:{count} are not among the "
+                f"{self.experts} experts")
+
+
+class _Expert(nn.Module):
+    """One expert's three matrices, under its published index."""
+
+    hidden: int
+    width: int
+
+    @nn.compact
+    def __call__(self):
+        return (self.param("w1", dense_init, (self.hidden, self.width)),
+                self.param("w3", dense_init, (self.hidden, self.width)),
+                self.param("w2", dense_init, (self.width, self.hidden)))
+
+
+def route(scores, bias, k: int, norm_topk: bool, scaling: float, *,
+          eps: float):
+    """The experts of each token and their weights: the top ``k`` of
+    ``scores + bias``, weighted by ``scores`` itself at those k (over
+    their sum + ``eps`` under ``norm_topk``)."""
+    _, chosen = lax.top_k(scores + bias, k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if norm_topk:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps)
+    return chosen, weights * scaling
+
+
+def held_expert_outputs(x, chosen, weights, w1, w3, w2, first: int):
+    """What the experts held here (``first .. first + len(w1)``) give for
+    the tokens routed to them: ``[tokens, hidden]`` in ``x``'s dtype, and
+    the tokens each of them got.
+
+    The ``tokens x k`` slots are sorted by expert, the slots of absent
+    experts behind all others; the held ones form one run per expert, and
+    three grouped matrix products (``lax.ragged_dot``) go over the runs.
+    The buffer is the worst case, every slot on a held expert, so no
+    capacity bounds a run and no token is dropped; rows behind the last
+    run belong to no group and cost the grouped product nothing. The
+    sorted rows go back to their tokens by the inverse
+    permutation and are summed by their weights.
+    """
+    tokens, k = chosen.shape
+    count = w1.shape[0]
+    local = chosen - first
+    key = jnp.where((local >= 0) & (local < count), local, count).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
+                    dtype=jnp.int32)
+    # rows behind the last run are in no group: the grouped product on
+    # the chip leaves what it does not compute as it finds it (whatever
+    # the memory held), in the result and in the cotangents alike, so
+    # those rows are zeroed going in and coming out, which zeroes their
+    # cotangents too (a token's slot on an absent expert adds nothing to
+    # the token's gradient)
+    in_a_run = jnp.arange(tokens * k)[:, None] < jnp.sum(sizes)
+    rows = jnp.where(in_a_run, x[order // k], 0)
+    hidden = nn.silu(lax.ragged_dot(rows, w1, sizes)) \
+        * lax.ragged_dot(rows, w3, sizes)
+    out = lax.ragged_dot(jnp.where(in_a_run, hidden, 0), w2, sizes)
+    out = jnp.where(in_a_run, out, 0)
+    back = jnp.argsort(order)
+    out = out[back].reshape(tokens, k, -1)
+    mixed = jnp.einsum("tkh,tk->th", out, weights.astype(out.dtype),
+                       preferred_element_type=jnp.float32)
+    return mixed.astype(x.dtype), sizes
+
+
+class SparseExperts(nn.Module):
+    """The expert layer: routes over all experts, computes the held
+    ones' part. ``config`` is a model's configuration; the layer reads
+    its ``routing`` (a ``Routing``)."""
+
+    config: Any
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        r = self.config.routing
+        first, count = r.held
+        batch, length, hidden = x.shape
+        flat = x.reshape(batch * length, hidden)
+        with jax.named_scope("router"):
+            gate = self.param("gate", dense_init, (hidden, r.experts))
+            scores = jax.nn.sigmoid(jnp.matmul(
+                flat.astype(jnp.float32), gate,
+                precision=lax.Precision.HIGHEST))
+            bias = 0.0
+            if r.use_bias:
+                bias = self.variable(
+                    "batch_stats", "expert_bias", jnp.zeros,
+                    (r.experts,), jnp.float32).value
+            chosen, weights = route(scores, bias, r.top_k, r.norm_topk,
+                                    r.scaling, eps=r.norm_eps)
+        with jax.named_scope("experts"):
+            w1, w3, w2 = (
+                jnp.stack(ws).astype(self.dtype) for ws in zip(*(
+                    _Expert(hidden, r.width, name=f"experts_{first + e}")()
+                    for e in range(count))))
+            out, sizes = held_expert_outputs(
+                flat, chosen, weights, w1, w3, w2, first)
+        return out.reshape(x.shape), sizes
+
+
+# --------------------------------- residuals kept through rematerialisation --
+
+
+@dataclasses.dataclass(frozen=True)
+class Kept:
+    """What the blocks of one step keep through the rematerialisation."""
+
+    classes: Tuple[str, ...] = ()
+    names: Tuple[str, ...] = ()
+    bytes: int = 0
+
+    @property
+    def megabytes(self) -> int:
+        return round(self.bytes / 1e6)
+
+    def notice(self, budget: int) -> str:
+        kept = ", ".join(self.classes) or "nothing (every block's forward " \
+            "is run again on the way back)"
+        return (f"=> residuals kept through the rematerialisation: {kept} "
+                f"({self.megabytes:,} MB a step of a budget of "
+                f"{round(budget / 1e6):,} MB)")
+
+
+def keep_within(classes, budget: int) -> Kept:
+    """The ``classes`` (``(what it is, the names it keeps, the bytes
+    they hold over all layers)``, in the order of keeping) that fit
+    ``budget`` bytes: whole classes (all layers or none), up to the first
+    that no longer fits. A class the share has no layer for holds nothing
+    and is not listed."""
+    kept, names, total = [], [], 0
+    for what, class_names, size in classes:
+        if total + size > budget:
+            break
+        if size:
+            kept.append(what)
+            names.extend(class_names)
+            total += size
+    return Kept(tuple(kept), tuple(names), total)
+
+
+class TokenModel(nn.Module):
+    """What ``fit()`` and the step builder ask of a token-sequence model.
+
+    ``__call__(tokens)`` gives float32 logits ``[B, S, vocab held]``;
+    with ``labels`` and ``mask`` it gives sums, not logits (the loss goes
+    over row blocks of the head, ``dptpu.ops.loss``):
+
+    ``loss_sum, count, correct1, correct5`` (float32 scalars; ``loss_sum``
+    is the whole objective); ``moe_counts`` ``[expert layers, experts
+    held]`` int32, the tokens each held expert got; ``moe_slots``, the
+    slots routed in all (tokens x k x expert layers, held or not);
+    ``moe_dropped``, the tokens dropped: a constant 0, there for the day
+    a capacity scheme moves it; ``kept_residual_mb``, the megabytes this
+    step's blocks keep through the rematerialisation: a constant of the
+    traced program.
+
+    ``residual_budget``: the bytes the blocks may keep (``keep_within``
+    over the model's ``residual_classes``); 0 keeps nothing. It is 0
+    unless someone who knows the device's memory sets it (``fit()`` does:
+    ``fitted_to``).
+    """
+
+    config: Any
+    dtype: Any = jnp.float32
+    residual_budget: int = 0
+
+    task = "tokens"
+    # What a step takes on the device beside the train state and the
+    # kept residuals; a model measures its own (``memory_analysis`` of
+    # its fully rematerialised step on the chip, and room for the
+    # allocator)
+    step_headroom_bytes = 0
+
+    @staticmethod
+    def torch_key_map(variables):
+        """``{torch_key: (collection, path, kind)}`` for every leaf of
+        ``variables``: the model's own checkpoint names, which
+        ``dptpu.models.pretrained`` asks the registry for."""
+        raise NotImplementedError
+
+    def residual_classes(self, shape):
+        """``keep_within``'s classes for a step on token rows of ``shape``
+        ``(rows, length)``."""
+        raise NotImplementedError
+
+    def example_input(self):
+        """One row as ``init`` takes it."""
+        return jnp.zeros((1, self.config.sequence_length), jnp.int32)
+
+    def kept_on(self, shape) -> Kept:
+        return keep_within(self.residual_classes(shape), self.residual_budget)
+
+    def kept(self, rows: int) -> Kept:
+        """What a step on ``rows`` rows keeps."""
+        return self.kept_on((rows, self.config.sequence_length))
+
+    def fitted_to(self, device_bytes: int, state_bytes: int):
+        """This model with the budget a device of ``device_bytes`` leaves
+        once the train state (``state_bytes``) and the step's own room
+        are taken; 0 where the device reports no size."""
+        budget = device_bytes - state_bytes - self.step_headroom_bytes
+        return self.clone(
+            residual_budget=max(budget, 0) if device_bytes else 0)
+
+
+def rematerialised(block, kept: Kept):
+    """``block`` (a module class) rematerialised on the way back but for
+    the names ``kept`` keeps."""
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *kept.names) if kept.names else None
+    return nn.remat(block, policy=policy)
+
+
+def with_counters(sums: dict, counts, slots: int, kept: Kept) -> dict:
+    """``sums`` with the expert layers' load (``counts``: one ``[experts
+    held]`` array per expert layer; ``slots``: the slots routed in all)
+    and the megabytes kept."""
+    if counts:
+        sums["moe_counts"] = jnp.stack(counts)
+        sums["moe_slots"] = jnp.asarray(slots, jnp.int32)
+        sums["moe_dropped"] = jnp.zeros((), jnp.int32)
+    sums["kept_residual_mb"] = jnp.asarray(kept.megabytes, jnp.int32)
+    return sums
+
+
+def _pair(text: str, what: str) -> Tuple[int, int]:
+    try:
+        first, count = (int(part) for part in str(text).split(":"))
+    except ValueError:
+        raise ValueError(
+            f"{what} {text!r} must be FIRST:COUNT, two whole numbers "
+            f"(0:8 holds the first eight)") from None
+    return first, count
+
+
+def held_range(given: Tuple[int, int], total: int, what: str):
+    """``given`` ``(first, count)`` if it lies among ``total``."""
+    first, count = given
+    if not (0 <= first and 0 < count and first + count <= total):
+        raise ValueError(
+            f"{what} {first}:{count} are not among the {total} {what}")
+    return first, count
+
+
+def factory(model, name: str, published):
+    """A registry factory for the ``model`` class at the configuration
+    ``published``, whole or a chip's share of it: ``layers``, ``experts``
+    and ``vocab`` are ``"first:count"`` (the trainer's ``--layers``,
+    ``--experts``, ``--vocab-rows``), ``sequence_length`` its
+    ``--seq-len``; the configuration's ``held`` makes the cut. ``fit()``
+    reads ``task`` off the factory before it builds anything: the data
+    source, the step's loss and the arguments a factory is handed follow
+    from it, and ``torch_key_map``, the model's own checkpoint names,
+    when it converts weights."""
+
+    def make(dtype=jnp.float32, layers=None, experts=None, vocab=None,
+             sequence_length=None):
+        return model(published.held(
+            layers=_pair(layers, "--layers") if layers else None,
+            experts=_pair(experts, "--experts") if experts else None,
+            vocab=_pair(vocab, "--vocab-rows") if vocab else None,
+            sequence_length=sequence_length), dtype=dtype)
+
+    make.__name__ = name
+    make.task = model.task
+    make.torch_key_map = model.torch_key_map
+    return make

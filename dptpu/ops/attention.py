@@ -21,6 +21,9 @@ query heads that share a key/value head are folded into the tile's query
 rows, so keys and values are never repeated. A length that is no multiple
 of the block is padded up; padded keys lie behind every real query, so
 the causal mask hides them, and padded queries are cut off the output.
+Queries and keys share one head size and the values may have another
+(latent attention: 192 against 128): the scores take the first, the
+accumulator, the output and ``dv`` the second.
 
 Plain XLA (matrix products, ``dynamic_slice``, one while loop each way):
 the same program runs on the CPU tests and on the chip. Scores, softmax
@@ -41,8 +44,18 @@ from jax.ad_checkpoint import checkpoint_name
 DEFAULT_BLOCK = 512
 _MASKED = -1e30  # finite: exp(masked - max) underflows to 0, never NaN
 # the residuals only the forward scan can make: the output in tile layout
-# ``[B, Hkv, S * G, D]`` and the float32 log-sum-exp ``[B, Hkv, S * G]``
+# ``[B, Hkv, S * G, Dv]`` and the float32 log-sum-exp ``[B, Hkv, S * G]``
 RESIDUAL_NAMES = ("attention_out", "attention_lse")
+
+
+def residual_bytes(rows: int, length: int, heads: int, v_head: int,
+                   dtype, block: int = DEFAULT_BLOCK) -> int:
+    """The bytes ``RESIDUAL_NAMES`` hold for one call on ``rows`` rows of
+    ``length`` tokens: ``out`` of the values' head size in ``dtype`` and
+    the float32 ``lse``, a row padded up to whole blocks."""
+    block = min(block, length)
+    padded = rows * -(-length // block) * block
+    return padded * heads * (v_head * jnp.dtype(dtype).itemsize + 4)
 
 
 def _tile_pairs(num_blocks: int):
@@ -98,10 +111,11 @@ def _from_tiles(x, block, groups):
 
 
 def _forward(q, k, v, block, groups, scale):
-    """``q`` in tile layout ``[B, Hkv, S * G, D]``, ``k``/``v``
-    ``[B, Hkv, S, D]``. Returns ``(out, lse)`` in tile layout, ``lse``
-    ``[B, Hkv, S * G]`` float32."""
-    b, h, rows, d = q.shape
+    """``q`` in tile layout ``[B, Hkv, S * G, D]``, ``k`` ``[B, Hkv, S,
+    D]``, ``v`` ``[B, Hkv, S, Dv]``. Returns ``(out, lse)`` in tile
+    layout, ``out`` of ``v``'s head size, ``lse`` ``[B, Hkv, S * G]``
+    float32."""
+    b, h, rows, _ = q.shape
     qb = groups * block
     ii, jj = _tile_pairs(k.shape[2] // block)
 
@@ -124,14 +138,13 @@ def _forward(q, k, v, block, groups, scale):
 
     init = (jnp.full((b, h, rows, 1), _MASKED, jnp.float32),
             jnp.zeros((b, h, rows, 1), jnp.float32),
-            jnp.zeros((b, h, rows, d), jnp.float32))
+            jnp.zeros((b, h, rows, v.shape[-1]), jnp.float32))
     (m, l, acc), _ = lax.scan(tile, init, (ii, jj))
     out = (acc / l).astype(q.dtype)
     return out, (m + jnp.log(l))[..., 0]
 
 
 def _backward(q, k, v, out, lse, d_out, block, groups, scale):
-    b, h, rows, d = q.shape
     qb = groups * block
     ii, jj = _tile_pairs(k.shape[2] // block)
     # rowsum(dO * O): the softmax Jacobian's diagonal term, once per query
@@ -160,7 +173,7 @@ def _backward(q, k, v, out, lse, d_out, block, groups, scale):
                 _put_block(dk, _block(dk, j, block) + dk_j, j, block),
                 _put_block(dv, _block(dv, j, block) + dv_j, j, block)), None
 
-    init = (jnp.zeros((b, h, rows, d), jnp.float32),
+    init = (jnp.zeros(q.shape, jnp.float32),
             jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32))
     (dq, dk, dv), _ = lax.scan(tile, init, (ii, jj))
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
@@ -189,13 +202,14 @@ _attend.defvjp(_attend_fwd, _attend_bwd)
 def causal_attention(q, k, v, *, scale: float, block: int = DEFAULT_BLOCK):
     """Causal softmax attention, blockwise.
 
-    ``q`` is ``[B, S, Hq, D]``, ``k`` and ``v`` are ``[B, S, Hkv, D]``
-    with ``Hq`` a multiple of ``Hkv`` (grouped queries); returns
-    ``[B, S, Hq, D]`` in ``q``'s dtype. Differentiable: the backward pass
-    is the tiled recomputation, not autodiff through the scan.
+    ``q`` is ``[B, S, Hq, D]``, ``k`` ``[B, S, Hkv, D]`` and ``v``
+    ``[B, S, Hkv, Dv]`` with ``Hq`` a multiple of ``Hkv`` (grouped
+    queries); returns ``[B, S, Hq, Dv]`` in ``q``'s dtype.
+    Differentiable: the backward pass is the tiled recomputation, not
+    autodiff through the scan.
     """
-    b, s, hq, d = q.shape
-    hkv = k.shape[2]
+    b, s, hq, _ = q.shape
+    hkv, dv = k.shape[2], v.shape[-1]
     if hq % hkv:
         raise ValueError(f"{hq} query heads are not whole groups over "
                          f"{hkv} key/value heads")
@@ -208,15 +222,15 @@ def causal_attention(q, k, v, *, scale: float, block: int = DEFAULT_BLOCK):
     out = _attend(_to_tiles(_fold(q, hkv), block),
                   k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
                   block, groups, float(scale))
-    out = _from_tiles(out, block, groups)  # [B, Hkv, G, S, D]
-    out = out.transpose(0, 3, 1, 2, 4).reshape(b, padded, hq, d)
+    out = _from_tiles(out, block, groups)  # [B, Hkv, G, S, Dv]
+    out = out.transpose(0, 3, 1, 2, 4).reshape(b, padded, hq, dv)
     return out[:, :s]
 
 
 def plain_causal_attention(q, k, v, *, scale: float):
     """The same result with the scores materialised: what the blockwise
     one is tested against (and fine at short lengths)."""
-    b, s, hq, d = q.shape
+    b, s, hq, _ = q.shape
     hkv = k.shape[2]
     qg = _fold(q, hkv).astype(jnp.float32)
     kt = k.transpose(0, 2, 1, 3).astype(jnp.float32)
@@ -225,4 +239,5 @@ def plain_causal_attention(q, k, v, *, scale: float):
     mask = jnp.tril(jnp.ones((s, s), bool))
     probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
     out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, vt)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, hq, d).astype(q.dtype)
+    return out.transpose(0, 3, 1, 2, 4).reshape(
+        b, s, hq, v.shape[-1]).astype(q.dtype)

@@ -237,6 +237,8 @@ def _epoch_scalars(train_stats, val_stats, obs_report, *, train_batch,
         ("Moe/load_mean", "moe_load_mean"),
         ("Moe/local_slot_share", "moe_local_slot_share"),
         ("Moe/dropped_tokens", "moe_dropped"),
+        # a model with a second loss term: it alone, before its weight
+        ("Loss/train_mtp", "mtp_loss"),
     ):
         if key in train_stats:
             scalars[tag] = train_stats[key]
@@ -1385,6 +1387,10 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
                     "held experts, {moe_dropped:.0f} tokens "
                     "dropped".format(**train_stats)
                 )
+            if verbose and "mtp_loss" in train_stats:
+                print("Mtp: multi-token-prediction loss {mtp_loss:.4f} "
+                      "(before its weight) in the epoch's loss "
+                      "{loss:.4f}".format(**train_stats))
             registry.set_scalars(_epoch_scalars(
                 train_stats, val_stats, obs_report,
                 # under a batch ramp a step consumes the PHASE batch

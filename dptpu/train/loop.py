@@ -99,6 +99,17 @@ class MoeLoad:
         }
 
 
+def loss_terms(fetched) -> dict:
+    """For a model whose step reports a second loss term (``mtp_loss``,
+    before its weight): its mean and the whole loss's over the steps one
+    fetch read, as the ``fetch`` span's attributes. Empty otherwise."""
+    steps = [m for m in fetched if "mtp_loss" in m]
+    if not steps:
+        return {}
+    return {key: float(np.mean([float(m[key]) for m in steps]))
+            for key in ("loss", "mtp_loss")}
+
+
 def _landed(x) -> bool:
     """Whether a device array has landed (host values always have)."""
     is_ready = getattr(x, "is_ready", None)
@@ -152,6 +163,7 @@ def train_one_epoch(
     losses = AverageMeter("Loss", ":.4e")
     top1 = AverageMeter("Acc@1", ":6.2f")
     top5 = AverageMeter("Acc@5", ":6.2f")
+    mtp_losses = AverageMeter("Mtp", ":.4e")  # a second loss term, if any
     progress = ProgressMeter(
         num_batches,
         [batch_time, data_time, losses, top1, top5],
@@ -165,6 +177,11 @@ def train_one_epoch(
     opt_last = {}
     _TRUST_KEYS = ("trust_min", "trust_mean", "trust_max")
     moe_load = MoeLoad()
+
+    def fetch_attrs(metrics):
+        """What the steps one fetch read put on its span (None: nothing)."""
+        return {**moe_load.take(metrics), **loss_terms(metrics)} or None
+
     steps_done = start_step  # batches of THIS epoch consumed so far
     preempted = False
     # step-phase spans (dptpu/obs): data_wait / step / fetch / ckpt plus
@@ -262,13 +279,14 @@ def train_one_epoch(
                     top1.update(float(m["top1"]), nb)
                     top5.update(float(m["top5"]), nb)
                     last_lr = float(m.get("lr", last_lr))
+                    if "mtp_loss" in m:
+                        mtp_losses.update(float(m["mtp_loss"]), nb)
                     for tk in _TRUST_KEYS:
                         if tk in m:
                             opt_last[tk] = float(m[tk])
                 tracer.record("fetch", t_fetch, pc() - t_fetch,
                               step=steps_done - 1,
-                              attrs=moe_load.take([m for m, _ in fetched])
-                              or None)
+                              attrs=fetch_attrs([m for m, _ in fetched]))
                 batch_time.update(time.time() - end)
                 if verbose:
                     progress.display(i + start_step)
@@ -316,6 +334,8 @@ def train_one_epoch(
         top1.update(float(m["top1"]), nb)
         top5.update(float(m["top5"]), nb)
         last_lr = float(m.get("lr", last_lr))
+        if "mtp_loss" in m:
+            mtp_losses.update(float(m["mtp_loss"]), nb)
         for tk in _TRUST_KEYS:
             if tk in m:
                 opt_last[tk] = float(m[tk])
@@ -323,7 +343,7 @@ def train_one_epoch(
         # the epoch-tail sync: the last un-fetched steps drain here
         tracer.record("fetch", t_fetch, pc() - t_fetch,
                       step=steps_done - 1,
-                      attrs=moe_load.take([m for m, _ in fetched]) or None)
+                      attrs=fetch_attrs([m for m, _ in fetched]))
     stats = {
         "loss": losses.avg,
         "top1": top1.avg,
@@ -341,6 +361,7 @@ def train_one_epoch(
         "preempted": preempted,
         **opt_last,
         **moe_load.stats(),
+        **({"mtp_loss": mtp_losses.avg} if mtp_losses.count else {}),
     }
     if feed_stats is not None:
         for k, v in feed_stats().items():

@@ -128,8 +128,11 @@ def token_loss_and_grads(state, batch, denom, gather_params=None,
     cross-entropy (float32, over row blocks of the head: dptpu/ops/loss.py)
     as the mean over this shard's rows, its gradient over ``denom``
     (the replicas, as the image loss), top-1/top-5 over kept tokens, and
-    the expert layers' load if the model has any. Returns
-    ``((loss, top1, top5, batch_stats, moe), grads)``."""
+    the expert layers' load if the model has any, and the terms of the
+    loss that a model reports beside the whole (``mtp_loss``: the
+    multi-token-prediction loss before its weight). Returns
+    ``((loss, top1, top5, batch_stats, moe, terms), grads)``: ``moe``
+    adds up over replicas, ``terms`` are means like the loss."""
     rows = batch["tokens"].shape[0]
     weights = token_row_weights(batch["mask"])
 
@@ -152,8 +155,10 @@ def token_loss_and_grads(state, batch, denom, gather_params=None,
     # the residuals its blocks keep
     moe = {k: v for k, v in sums.items()
            if k.startswith("moe_") or k == "kept_residual_mb"}
+    terms = {"mtp_loss": sums["mtp_loss_sum"] / rows} \
+        if "mtp_loss_sum" in sums else {}
     return (loss, sums["correct1"] / rows, sums["correct5"] / rows,
-            new_stats, moe), grads
+            new_stats, moe, terms), grads
 
 
 def train_step_body(state, batch, *, compute_dtype, lr_schedule, seed,
@@ -248,15 +253,16 @@ def train_step_body(state, batch, *, compute_dtype, lr_schedule, seed,
         )
         return aux, grads
 
-    moe = {}
+    moe, terms = {}, {}
     if task == "tokens":
         if accum_steps != 1 or label_smoothing:
             raise ValueError(
                 "a token-sequence model trains without --accum-steps and "
                 "--label-smoothing: the per-token loss has no microbatch "
                 "scan and no smoothed target yet")
-        (loss, top1, top5, new_stats, moe), grads = token_loss_and_grads(
-            state, batch, axis_size, gather_params, wrap_params)
+        (loss, top1, top5, new_stats, moe, terms), grads = \
+            token_loss_and_grads(state, batch, axis_size, gather_params,
+                                 wrap_params)
     elif accum_steps == 1:
         dropout_key = step_key
         if on_mesh:
@@ -333,8 +339,8 @@ def train_step_body(state, batch, *, compute_dtype, lr_schedule, seed,
     if on_mesh:
         # running BN stats + reported metrics: explicit cross-replica mean
         # (the reference's reduce_tensor, imagenet_ddp_apex.py:562-566)
-        new_stats, loss, top1, top5 = lax.pmean(
-            (new_stats, loss, top1, top5), pmean_axes
+        new_stats, loss, top1, top5, terms = lax.pmean(
+            (new_stats, loss, top1, top5, terms), pmean_axes
         )
         # counts add up over the replicas (the megabytes kept too: the
         # step's, on all its chips)
@@ -363,6 +369,7 @@ def train_step_body(state, batch, *, compute_dtype, lr_schedule, seed,
         "top5": top5 * 100.0,
         "lr": jnp.asarray(lr, jnp.float32),
         **moe,
+        **terms,
     }
     tstats = trust_ratio_stats(new_opt)
     if tstats is not None:

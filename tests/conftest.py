@@ -46,6 +46,10 @@ _FAST_MODULES = {
     # benchmark's copy with ONE fit()-driven case at the same size (train,
     # validate, resume), and the benchmark's seam cases (11 s)
     "test_lfm2", "test_tokens_feed", "test_benchmark_seams",
+    # a second token model (ISSUE 36): JoyAI-LLM-Flash against its plain
+    # reference at hidden 64, ONE case through main_apex (two fit() runs
+    # of 16 steps at 32 tokens); under a minute of one worker
+    "test_joyai",
     # fit()'s default train feed (ISSUE 31): ONE module fixture runs
     # fit() four times at the sizes above (resnet18@32 and the tiny
     # token model, 4-8 steps, default and thread mode); the rest drives

@@ -88,6 +88,28 @@ def test_blockwise_attention_compiles_for_the_chip_at_the_cells_shape(
     assert compiled.as_text().count("while(") >= 2
 
 
+def test_attention_with_two_head_sizes_compiles_for_the_chip_at_the_cells_shape(
+        one_chip):
+    """Latent attention at the size PR 36 measured on the chip: one row of
+    8,192 tokens, 32 heads, queries and keys of 192, values of 128."""
+    q = _shape((1, 8192, 32, 192), jnp.bfloat16, one_chip)
+    v = _shape((1, 8192, 32, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(causal_attention(q, k, v, scale=192 ** -0.5)
+                       .astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, q, v).compile()
+    text = compiled.as_text()
+    # never the [heads, S, S] scores (8.6 GB): two scans, the forward's
+    # accumulator at the values' head size, the backward's dq and dk at
+    # the queries' and dv at the values'
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+    assert text.count("while(") >= 2
+    assert "f32[1,32,8192,128]" in text and "f32[1,32,8192,192]" in text
+
+
 def test_a_rematerialised_attention_block_keeps_out_and_lse_on_the_chip(
         one_chip):
     """An attention layer at the cell's shape as the model wraps it (a

@@ -219,6 +219,12 @@ _REFUSED = [
                  id="tokens-tp"),
     pytest.param({}, {"arch": "lfm2_8b_a1b", "task": "tokens",
                       "ramp": _RAMP}, _TOKENS, id="tokens-ramp"),
+    # by task, not by name: the second token model is refused the same
+    pytest.param({"DPTPU_ZERO1": "1"}, {"arch": "joyai_llm_flash",
+                                        "task": "tokens"}, _TOKENS,
+                 id="tokens-zero1-joyai"),
+    pytest.param({}, {"arch": "joyai_llm_flash", "task": "tokens",
+                      "ramp": _RAMP}, _TOKENS, id="tokens-ramp-joyai"),
     pytest.param({"DPTPU_GSPMD": "1"}, {"ramp": _RAMP},
                  "DPTPU_BATCH_RAMP has no DPTPU_GSPMD composition",
                  id="ramp-gspmd"),
