@@ -482,7 +482,11 @@ class Joyai(token_model.TokenModel):
                     + cfg.mtp_loss_weight * mtp
         return token_model.with_counters(
             sums, counts,
-            tokens.size * cfg.num_experts_per_tok * len(counts), kept)
+            tokens.size * cfg.num_experts_per_tok * len(counts), kept,
+            cfg.attention_layers_here, attention_op.kernel_calls(
+                tokens.shape[1], cfg.num_attention_heads,
+                cfg.num_attention_heads, cfg.qk_head_dim, cfg.v_head_dim,
+                self.dtype))
 
 
 factory = functools.partial(token_model.factory, Joyai)

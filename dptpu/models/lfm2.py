@@ -420,7 +420,12 @@ class Lfm2(token_model.TokenModel):
                 labels.reshape(-1), mask.reshape(-1))
         return token_model.with_counters(
             sums, counts,
-            tokens.size * cfg.num_experts_per_tok * len(counts), kept)
+            tokens.size * cfg.num_experts_per_tok * len(counts), kept,
+            sum(t == "full_attention" for t in cfg.layer_types),
+            attention_op.kernel_calls(
+                tokens.shape[1], cfg.num_attention_heads,
+                cfg.num_key_value_heads, cfg.head_dim, cfg.head_dim,
+                self.dtype))
 
 
 factory = functools.partial(token_model.factory, Lfm2)

@@ -277,7 +277,11 @@ class TokenModel(nn.Module):
     ``moe_dropped``, the tokens dropped: a constant 0, there for the day
     a capacity scheme moves it; ``kept_residual_mb``, the megabytes this
     step's blocks keep through the rematerialisation: a constant of the
-    traced program.
+    traced program; ``attention_calls`` and ``attention_kernel_calls``,
+    the calls of ``dptpu.ops.attention`` in one forward pass and those of
+    them that take its kernels in the program being lowered (all on a
+    TPU where the shapes tile, none elsewhere): constants of the lowered
+    program.
 
     ``residual_budget``: the bytes the blocks may keep (``keep_within``
     over the model's ``residual_classes``); 0 keeps nothing. It is 0
@@ -336,15 +340,19 @@ def rematerialised(block, kept: Kept):
     return nn.remat(block, policy=policy)
 
 
-def with_counters(sums: dict, counts, slots: int, kept: Kept) -> dict:
+def with_counters(sums: dict, counts, slots: int, kept: Kept,
+                  attention_calls: int, on_kernel) -> dict:
     """``sums`` with the expert layers' load (``counts``: one ``[experts
-    held]`` array per expert layer; ``slots``: the slots routed in all)
-    and the megabytes kept."""
+    held]`` array per expert layer; ``slots``: the slots routed in all),
+    the megabytes kept and the attention's calls (``on_kernel``:
+    ``attention.kernel_calls`` of one of them, all being of one shape)."""
     if counts:
         sums["moe_counts"] = jnp.stack(counts)
         sums["moe_slots"] = jnp.asarray(slots, jnp.int32)
         sums["moe_dropped"] = jnp.zeros((), jnp.int32)
     sums["kept_residual_mb"] = jnp.asarray(kept.megabytes, jnp.int32)
+    sums["attention_calls"] = jnp.asarray(attention_calls, jnp.int32)
+    sums["attention_kernel_calls"] = attention_calls * on_kernel
     return sums
 
 

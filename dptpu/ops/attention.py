@@ -25,10 +25,25 @@ Queries and keys share one head size and the values may have another
 (latent attention: 192 against 128): the scores take the first, the
 accumulator, the output and ``dv`` the second.
 
-Plain XLA (matrix products, ``dynamic_slice``, one while loop each way):
-the same program runs on the CPU tests and on the chip. Scores, softmax
-statistics and the accumulators are float32; the two matrix products of
-a tile take their operands in the inputs' dtype (bfloat16 under O2).
+What runs where. The scan is plain XLA (matrix products,
+``dynamic_slice``, one while loop each way) and runs wherever a program
+is lowered for anything but a TPU: the CPU tests hold it to the plain
+reference. In a program lowered for a TPU both passes are the Pallas
+kernels of ``attention_kernel.py`` (the same tiles with scores, softmax
+statistics and accumulators in VMEM; the scan is HBM-bound on traffic
+they never make), for every call whose shapes they tile
+(``kernel_blocks``: a block of whole 128 lanes, head sizes in whole 64s,
+a row's float32 ``dq`` within the chip's VMEM); any other call keeps the
+scan there too. The choice is made where the program is LOWERED, by the
+platform it is lowered for (``_by_platform``: one primitive with two
+lowerings), never by ``jax.default_backend()``: the tests lower for a
+described v5e from a CPU process and get the kernels, the CPU gets the
+scan's own program (no ``case`` on the platform round it; a model's
+layers share one lowered body of it), and nothing imports Pallas until
+a TPU program with such a call is lowered. Either way scores, softmax
+statistics and the accumulators are float32, the scale multiplies the
+float32 scores, and the products of a tile take their operands in the
+inputs' dtype (bfloat16 under O2).
 """
 
 from __future__ import annotations
@@ -39,7 +54,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax._src import dispatch
 from jax.ad_checkpoint import checkpoint_name
+from jax.extend.core import Primitive
+from jax.interpreters import mlir
 
 DEFAULT_BLOCK = 512
 _MASKED = -1e30  # finite: exp(masked - max) underflows to 0, never NaN
@@ -179,21 +197,138 @@ def _backward(q, k, v, out, lse, d_out, block, groups, scale):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
+# what the kernels may count on of the chip's 128 MiB of VMEM (their
+# compiler limit is 100 MiB: ``attention_kernel.VMEM_LIMIT_BYTES``)
+KERNEL_VMEM_BYTES = 80 * 2 ** 20
+_LANES = 128
+
+
+def _vmem_bytes(rows: int, qk_head: int, v_head: int, itemsize: int,
+                block_q: int, block_kv: int) -> int:
+    """What the backward kernel (the larger of the two) holds in VMEM for
+    ``rows`` query rows a key/value head: ``dq`` whole in float32 and its
+    output block twice, every streamed tile twice (tokens along the
+    lanes, but for ``k`` and ``v``, whose head size is padded up to whole
+    128 lanes), the key block's two accumulators and the float32 tiles
+    of one step."""
+    padded = -(-qk_head // _LANES) * _LANES + -(-v_head // _LANES) * _LANES
+    dq = rows * qk_head * (4 + 2 * itemsize)
+    streamed = 2 * itemsize * (block_q * (qk_head + v_head) + block_kv * (
+        padded + 2 * qk_head + v_head))
+    accumulators = 4 * block_kv * (qk_head + v_head)
+    return dq + streamed + accumulators + 6 * block_q * block_kv * 4
+
+
+def kernel_blocks(length: int, groups: int, qk_head: int, v_head: int,
+                  dtype, block: int):
+    """``(block_q, block_kv)`` for the kernels on rows of ``length``
+    tokens (whole blocks), or None for a call they do not tile, which
+    keeps the scan. A rule on the shapes alone."""
+    if block % _LANES or qk_head % 64 or v_head % 64 \
+            or jnp.dtype(dtype) not in (jnp.bfloat16, jnp.float32):
+        return None
+    blocks = (block, block)
+    if _vmem_bytes(length * groups, qk_head, v_head,
+                   jnp.dtype(dtype).itemsize, *blocks) > KERNEL_VMEM_BYTES:
+        return None
+    return blocks
+
+
+def _by_platform(name: str, shapes, default, tpu) -> Primitive:
+    """A primitive that lowers to ``tpu(*args, **params)`` in a program
+    for the TPU and to ``default(*args, **params)`` in any other:
+    ``lax.platform_dependent``'s choice without its ``case`` round the
+    branch taken and without tracing the branch not taken (no Pallas off
+    the TPU)."""
+    prim = Primitive(name)
+    prim.multiple_results = True
+    prim.def_impl(functools.partial(dispatch.apply_primitive, prim))
+    prim.def_abstract_eval(
+        lambda *avals, **params: [jax.core.ShapedArray(shape, dtype)
+                                  for shape, dtype in shapes(*avals)])
+    mlir.register_lowering(prim, mlir.lower_fun(default,
+                                                multiple_results=True))
+    mlir.register_lowering(prim, mlir.lower_fun(tpu, multiple_results=True),
+                           platform="tpu")
+    return prim
+
+
+def _blocks_of(q, k, v, block, groups):
+    return kernel_blocks(k.shape[2], groups, q.shape[-1], v.shape[-1],
+                         q.dtype, block)
+
+
+def _kernel(name: str, **how):
+    """``attention_kernel.forward`` / ``backward`` under the scan's
+    signature, at the block sizes the shapes give."""
+    def run(q, k, v, *rest, block, groups, scale):
+        # Pallas and Mosaic load here: where a TPU program is lowered
+        from dptpu.ops import attention_kernel
+
+        block_q, block_kv = _blocks_of(q, k, v, block, groups)
+        return getattr(attention_kernel, name)(
+            q, k, v, *rest, block=block, groups=groups, scale=scale,
+            block_q=block_q, block_kv=block_kv, **how)
+
+    return run
+
+
+_forward_p = _by_platform(
+    "causal_attention_forward",
+    lambda q, k, v: ((q.shape[:-1] + v.shape[-1:], q.dtype),
+                     (q.shape[:-1], jnp.float32)),
+    _forward, _kernel("forward"))
+_backward_p = _by_platform(
+    "causal_attention_backward",
+    lambda q, k, v, *_: ((q.shape, q.dtype), (k.shape, k.dtype),
+                         (v.shape, v.dtype)),
+    _backward, _kernel("backward"))
+_on_tpu_p = _by_platform(
+    "lowered_for_tpu", lambda: (((), jnp.int32),),
+    lambda: [jnp.int32(0)], lambda: [jnp.int32(1)])
+
+
+def _here(scan, prim, *arrays, block, groups, scale):
+    """One pass by ``scan``, or by ``prim`` (the scan again, or the
+    kernel where the program is lowered for a TPU) for the shapes the
+    kernels take."""
+    if _blocks_of(*arrays[:3], block, groups) is None:
+        return scan(*arrays, block, groups, scale)
+    return prim.bind(*arrays, block=block, groups=groups, scale=scale)
+
+
+def kernel_calls(length: int, heads: int, kv_heads: int, qk_head: int,
+                 v_head: int, dtype, block: int = DEFAULT_BLOCK):
+    """1 where ``causal_attention`` on rows of ``length`` tokens takes
+    the kernels in the program being lowered, 0 where it takes the scan
+    (another platform, or a shape they do not tile): an int32 scalar of
+    the traced program, for a model to count its calls with."""
+    block = min(block, length)
+    blocks = kernel_blocks(-(-length // block) * block, heads // kv_heads,
+                           qk_head, v_head, dtype, block)
+    if blocks is None:
+        return jnp.zeros((), jnp.int32)
+    return _on_tpu_p.bind()[0]
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _attend(q, k, v, block, groups, scale):
-    return _forward(q, k, v, block, groups, scale)[0]
+    return _here(_forward, _forward_p, q, k, v, block=block, groups=groups,
+                 scale=scale)[0]
 
 
 def _attend_fwd(q, k, v, block, groups, scale):
     # the names sit HERE, on both residuals: a name on the call's result
-    # alone leaves ``lse`` to be made again, by the whole scan
+    # alone leaves ``lse`` to be made again, by the whole forward pass
     out, lse = map(checkpoint_name,
-                   _forward(q, k, v, block, groups, scale), RESIDUAL_NAMES)
+                   _here(_forward, _forward_p, q, k, v, block=block,
+                         groups=groups, scale=scale), RESIDUAL_NAMES)
     return out, (q, k, v, out, lse)
 
 
 def _attend_bwd(block, groups, scale, residuals, d_out):
-    return _backward(*residuals, d_out, block, groups, scale)
+    return _here(_backward, _backward_p, *residuals, d_out, block=block,
+                 groups=groups, scale=scale)
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)

@@ -52,9 +52,14 @@ class MoeLoad:
     tokens at the busiest held expert and at the mean one, the routed
     slots that fell on held experts, the tokens dropped. ``take`` gives
     what one fetch adds as the ``fetch`` span's attributes; ``stats``
-    the epoch's averages. ``kept_residual_mb`` (the megabytes the
-    model's blocks keep through their rematerialisation, a constant of
-    the step program) is passed on as it is."""
+    the epoch's averages. The step program's constants
+    (``STEP_CONSTANTS``) are passed on as they are."""
+
+    # the megabytes the model's blocks keep through their
+    # rematerialisation; the attention's calls in one forward pass and
+    # those of them that run as its kernels in this program
+    STEP_CONSTANTS = ("kept_residual_mb", "attention_calls",
+                      "attention_kernel_calls")
 
     def __init__(self):
         self.steps = 0
@@ -64,8 +69,8 @@ class MoeLoad:
     def take(self, fetched) -> dict:
         """``fetched``: the metrics of the steps one fetch read. Empty
         where the model has no experts."""
-        kept = {"kept_residual_mb": int(m["kept_residual_mb"])
-                for m in fetched[-1:] if "kept_residual_mb" in m}
+        kept = {key: int(m[key]) for m in fetched[-1:]
+                for key in self.STEP_CONSTANTS if key in m}
         steps = [m for m in fetched if "moe_counts" in m]
         if not steps:
             return kept

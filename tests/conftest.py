@@ -59,6 +59,9 @@ _FAST_MODULES = {
     # described v5e at the cell's widths (no chip; 25 s; skips where the
     # TPU's compiler cannot describe the chip)
     "test_tpu_compile",
+    # the attention's Pallas kernels against the plain attention in
+    # interpret mode (ISSUE 37): a dozen grids of a few steps, 20 s
+    "test_attention_kernel",
     "test_bench_logic", "test_config", "test_schedules", "test_metrics",
     "test_meters", "test_data", "test_tensorboard", "test_native",
     "test_cache", "test_shm_loader", "test_feed_knobs", "test_tv_template",

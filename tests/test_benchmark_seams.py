@@ -10,7 +10,11 @@ PR 36 appended its cell to ``kept_residual_mb``'s list in
 ``BENCHMARK.json`` and may not edit ``layer_metrics/kept_residual_mb
 .json``: that file's listing case (one cell, equal to the data file) is
 red outside tier-1 until a ``benchmark`` PR repairs the data file, and
-the case below that takes its place here holds what still has to hold."""
+the case below that takes its place here holds what still has to hold.
+PR 37 appended ``attention_kernel_share`` behind ``mtp_loss_share``,
+whose own listing case asks to be the LAST entry: the case below that
+takes its place holds the rest of it, and the new reader's cases
+(``test_attention_kernel_share.py``) run here too."""
 
 import json
 import os
@@ -28,8 +32,15 @@ from benchmark.tests.test_kept_residual_mb import (  # noqa: F401
     test_a_window_whose_only_carriers_were_warm_up_reads_nothing,
     test_reads_the_megabytes_off_the_timed_fetch_spans,
 )
-# the reader of the second loss's share, on the same fixture (6 cases)
-from benchmark.tests.test_mtp_loss_share import *  # noqa: F401,F403,E402
+# the reader of the second loss's share, on the same fixture (5 cases;
+# the sixth, its listing, is below)
+from benchmark.tests.test_mtp_loss_share import (  # noqa: F401,E402
+    test_a_parents_log_reads_no_second_loss,
+    test_reads_the_second_losss_share_off_the_timed_fetch_spans,
+)
+from benchmark.tests import test_mtp_loss_share as _mtp  # noqa: E402
+# the reader of the attention's share on the kernel (9 cases)
+from benchmark.tests.test_attention_kernel_share import *  # noqa: F401,F403,E402
 
 CELL = "lfm2moe-fit-8k-1chip"
 JOYAI_CELL = "joyai-fit-8k-1chip"
@@ -141,6 +152,28 @@ def test_kept_residual_mb_is_listed_for_the_token_cells_as_its_file_has_it():
         "expert_dropped_tokens")["layer"]
 
 
+def test_mtp_loss_share_is_listed_for_its_cell_as_its_file_has_it(tmp_path):
+    bench = cells.manifest()
+    spec = cells.layer_metric("mtp_loss_share")
+    (entry,) = [m for m in bench["per_layer"]
+                if m["name"] == "mtp_loss_share"]
+    assert entry == {k: spec[k] for k in entry}
+    assert set(entry) == {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+    assert entry["workloads"] == [JOYAI_CELL] and entry["unit"] == "%"
+    assert entry["source"] == "program_counter"
+    assert entry["moves"] == "train_img_s_chip"
+    assert entry["layer"] == cells.layer_metric(
+        "expert_dropped_tokens")["layer"]
+    # a configuration without the weight, or a loss of zero: nothing read
+    carrying = _mtp._log_with(tmp_path, {5: {"loss": 11.0, "mtp_loss": 10.0}})
+    assert cells.reader("mtp_loss_share").read(
+        _mtp._context(carrying, 2, None)) is None
+    zero = _mtp._log_with(tmp_path, {5: {"loss": 0.0, "mtp_loss": 0.0}})
+    assert cells.reader("mtp_loss_share").read(
+        _mtp._context(zero, 2)) is None
+
+
 def test_the_joyai_cell_lands_as_files_and_keeps_every_contract():
     cell = cells.load_cell(JOYAI_CELL)
     config, traffic = cell.config, cell.traffic
@@ -164,10 +197,10 @@ def test_the_joyai_cell_lands_as_files_and_keeps_every_contract():
     bench = cells.manifest()
     assert bench["workloads"][-1]["name"] == JOYAI_CELL
     assert bench["configs"][-1]["name"] == config["name"]
-    assert bench["per_layer"][-1]["name"] == "mtp_loss_share"
-    assert bench["per_layer"][-1]["workloads"] == [JOYAI_CELL]
-    for m in bench["end_to_end"] + bench["per_layer"][:-1]:
-        if JOYAI_CELL in m.get("workloads", ()):
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] == "mtp_loss_share":
+            assert m["workloads"] == [JOYAI_CELL]
+        elif JOYAI_CELL in m.get("workloads", ()):
             assert m["workloads"][-2:] == [CELL, JOYAI_CELL]
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     assert len(bench["workloads"]) == 5

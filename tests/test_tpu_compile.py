@@ -2,10 +2,14 @@
 cell's real widths, without a chip (the TPU's compiler is installed and
 compiles for a described v5e): the expert layer's grouped products over
 the worst-case buffer (``lax.ragged_dot`` lowers to the chip's own kernel
-there, not to the CPU's dense fallback) and the blockwise attention's
-scan, each forward and backward. What the chip's compiler would refuse
-(a shape it cannot tile, a program that does not fit) fails here, at no
-chip time. Nothing runs: no result and no time comes out of this file.
+there, not to the CPU's dense fallback) and the blockwise attention,
+each forward and backward. Lowered for the chip the attention is its two
+Pallas kernels (``tpu_custom_call``), chosen by the platform the program
+is lowered for and not by the process's backend (this one's is the CPU):
+no ``while`` with the scan's float32 carries is left. What the chip's
+compiler would refuse (a shape it cannot tile, more VMEM than a kernel
+may take, a program that does not fit) fails here, at no chip time.
+Nothing runs: no result and no time comes out of this file.
 
 The topology is described inside a fixture, never at import: one process
 at a time may load the TPU's library, and a worker that cannot skips.
@@ -72,6 +76,15 @@ def test_the_expert_layer_compiles_for_the_chip_at_the_cells_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 8e9
 
 
+def _attention_calls(text: str):
+    """The forward and the backward kernel's custom calls in a compiled
+    program's text."""
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    return ([c for c in calls if "%causal_attention_forward" in c],
+            [c for c in calls if "%causal_attention_backward" in c])
+
+
 def test_blockwise_attention_compiles_for_the_chip_at_the_cells_shape(
         one_chip):
     q = _shape((2, 8192, 32, 64), jnp.bfloat16, one_chip)
@@ -83,15 +96,23 @@ def test_blockwise_attention_compiles_for_the_chip_at_the_cells_shape(
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile()
-    # never the [heads, S, S] scores (8.6 GB a row): two scans' carries
-    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
-    assert compiled.as_text().count("while(") >= 2
+    text = compiled.as_text()
+    # one kernel each way where the scan had two loops, and every large
+    # operand with the tokens along the lanes: a head size of 64 is no
+    # padding up to 128 in HBM
+    forward, backward = _attention_calls(text)
+    assert len(forward) == len(backward) == 1 and " while(" not in text
+    assert "bf16[2,8,64,32768]" in forward[0] + backward[0]
+    assert "bf16[2,8,32768,64]" not in forward[0] + backward[0]
+    # the scan's program took 201.6 MB here (its float32 carries); the
+    # kernels' takes 201.9: q, d_out and dq in both layouts
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.21e9
 
 
 def test_attention_with_two_head_sizes_compiles_for_the_chip_at_the_cells_shape(
         one_chip):
-    """Latent attention at the size PR 36 measured on the chip: one row of
-    8,192 tokens, 32 heads, queries and keys of 192, values of 128."""
+    """Latent attention at the size of the JoyAI cell: one row of 8,192
+    tokens, 32 heads, queries and keys of 192, values of 128."""
     q = _shape((1, 8192, 32, 192), jnp.bfloat16, one_chip)
     v = _shape((1, 8192, 32, 128), jnp.bfloat16, one_chip)
 
@@ -102,12 +123,16 @@ def test_attention_with_two_head_sizes_compiles_for_the_chip_at_the_cells_shape(
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, q, v).compile()
     text = compiled.as_text()
-    # never the [heads, S, S] scores (8.6 GB): two scans, the forward's
-    # accumulator at the values' head size, the backward's dq and dk at
-    # the queries' and dv at the values'
-    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
-    assert text.count("while(") >= 2
-    assert "f32[1,32,8192,128]" in text and "f32[1,32,8192,192]" in text
+    forward, backward = _attention_calls(text)
+    assert len(forward) == len(backward) == 1 and " while(" not in text
+    # the output at the values' head size, dq and dk at the queries',
+    # and none of the scan's float32 carries in HBM
+    assert "bf16[1,32,128,8192]" in forward[0]
+    assert "bf16[1,32,16,192,512]" in backward[0]  # dq, a row block a slab
+    assert "f32[1,32,8192,128]" not in text and "f32[1,32,8192,192]" \
+        not in text
+    # half the scan's 537 MB: its dq, dk and dv carries were float32
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
 
 
 def test_a_rematerialised_attention_block_keeps_out_and_lse_on_the_chip(
@@ -115,7 +140,7 @@ def test_a_rematerialised_attention_block_keeps_out_and_lse_on_the_chip(
     """An attention layer at the cell's shape as the model wraps it (a
     dense feed-forward behind it: the experts have their own case
     above), ``out`` and ``lse`` kept through the rematerialisation: the
-    forward scan is in the program once, not twice."""
+    forward kernel is in the program once, not twice."""
     from flax import linen as nn
 
     share = lfm2.Lfm2Config().held(layers=(2, 1), sequence_length=8192)
@@ -136,9 +161,18 @@ def test_a_rematerialised_attention_block_keeps_out_and_lse_on_the_chip(
     params = variables.pop("params")
     compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
         params, variables, x).compile()
-    scans = [line for line in compiled.as_text().splitlines()
-             if " while(" in line and "f32[2,8,32768,64]" in line]
-    assert len(scans) == 2, scans  # forward and backward, no forward again
-    # one layer's activations and its weights' gradients: 1.06 GB when
-    # this was written (1.50 GB and three scans with nothing kept)
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
+    text = compiled.as_text()
+    forward, backward = _attention_calls(text)
+    # forward and backward, no forward again; no scan left
+    assert len(forward) == len(backward) == 1, (forward, backward)
+    assert not [line for line in text.splitlines()
+                if " while(" in line and "f32[2,8,32768,64]" in line]
+    # what is held between the passes is what ``residual_bytes`` reckons:
+    # the kernel's own ``out``, 64 wide with the tokens along the lanes
+    # (67.1 MB, not a [.., 64] array padded up to 128 lanes), and one
+    # float32 a query
+    assert "(bf16[2,8,64,32768]{" in forward[0]
+    assert "f32[2,8,1,32768]{" in forward[0]
+    # one layer's activations and its weights' gradients: 1.062 GB with
+    # the scan and with the kernels (1.50 GB with nothing kept)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1e9
