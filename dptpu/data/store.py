@@ -199,6 +199,20 @@ class Store:
     def put_bytes(self, name: str, data: bytes) -> None:
         self._io(f"put {name}", lambda: self._put_bytes(name, data))
 
+    def put_stream(self, name: str, produce) -> None:
+        """``put_bytes`` of an object that is never held whole:
+        ``produce(write)`` hands its bytes to ``write`` piece by piece
+        (anything with a buffer: ``bytes``, a ``memoryview`` of an array),
+        in order. A retry calls ``produce`` again from the start, so it
+        has to give the same bytes each time. A backend without a
+        streaming write gathers the pieces and puts them at once."""
+        self._io(f"put {name}", lambda: self._put_stream(name, produce))
+
+    def _put_stream(self, name: str, produce) -> None:
+        pieces = []
+        produce(pieces.append)
+        self._put_bytes(name, b"".join(pieces))
+
     def copy(self, src: str, dst: str) -> None:
         self._io(f"copy {src} -> {dst}", lambda: self._copy(src, dst))
 
@@ -251,15 +265,20 @@ class LocalStore(Store):
         return os.path.getsize(self.path_for(name))
 
     def _put_bytes(self, name: str, data: bytes) -> None:
+        self._put_stream(name, lambda write: write(data))
+
+    def _put_stream(self, name: str, produce) -> None:
         # the checkpoint writer's durability discipline, verbatim
         # (dptpu/train/checkpoint.py): tmp + flush + fsync + atomic
         # rename + best-effort dirent fsync — a power loss can yield the
-        # old object or the new one, never a torn mix
+        # old object or the new one, never a torn mix. The pieces go
+        # straight into the temporary file: the object is never held
+        # whole beside what it was made from
         os.makedirs(self.root, exist_ok=True)
         path = self.path_for(name)
         tmp = path + ".tmp"
         with open(tmp, "wb") as f:
-            f.write(data)
+            produce(f.write)
             f.flush()
             os.fsync(f.fileno())
         os.replace(tmp, path)

@@ -10,6 +10,7 @@ from dptpu.models import convnext as _convnext  # noqa: F401
 from dptpu.models import densenet as _densenet  # noqa: F401
 from dptpu.models import efficientnet as _efficientnet  # noqa: F401
 from dptpu.models import googlenet as _googlenet  # noqa: F401
+from dptpu.models import granite as _granite  # noqa: F401
 from dptpu.models import inception as _inception  # noqa: F401
 from dptpu.models import joyai as _joyai  # noqa: F401
 from dptpu.models import lfm2 as _lfm2  # noqa: F401

@@ -1,10 +1,11 @@
-"""What the token-sequence models share (``lfm2.py``, ``joyai.py``).
+"""What the token-sequence models share (``lfm2.py``, ``joyai.py``,
+``granite.py``).
 
-Both are built from a configuration object, cut to one chip's share of
+Each is built from a configuration object, cut to one chip's share of
 an expert-parallel, vocabulary-parallel, pipelined deployment, trained by
 ``fit()`` on ``tokens:<N>`` (``task = "tokens"``) with rematerialised
 blocks that keep named residuals within a budget. This module holds the
-pieces that are the same mathematics in both, each once:
+pieces that are the same mathematics in more than one, each once:
 
 * ``RMSNorm``, ``SwiGLU``, the half-split ``rotary``;
 * the expert layer: ``route`` (the top k of score + bias, weighted by
@@ -15,8 +16,9 @@ pieces that are the same mathematics in both, each once:
   keeps through the rematerialisation within a budget of bytes;
 * ``TokenModel``: the module both models extend (``example_input``,
   ``kept``, ``fitted_to``), ``rematerialised`` (a block under the policy
-  that keeps), ``with_counters`` (what a step counts beside the loss),
-  and ``factory``, the registry's way to a chip's share.
+  that keeps), ``with_counters`` and ``with_scan_counters`` (what a step
+  counts beside the loss), and ``factory``, the registry's way to a
+  chip's share.
 
 A model says what is its own: its blocks, its ``residual_classes`` (the
 order of keeping is milliseconds saved per byte, measured on the chip for
@@ -281,7 +283,9 @@ class TokenModel(nn.Module):
     the calls of ``dptpu.ops.attention`` in one forward pass and those of
     them that take its kernels in the program being lowered (all on a
     TPU where the shapes tile, none elsewhere): constants of the lowered
-    program.
+    program. A model with state-space layers adds ``ssd_calls``,
+    ``ssd_kernel_calls`` and ``ssd_chunks`` (``with_scan_counters``); a
+    model without experts carries none of the ``moe_`` sums.
 
     ``residual_budget``: the bytes the blocks may keep (``keep_within``
     over the model's ``residual_classes``); 0 keeps nothing. It is 0
@@ -353,6 +357,19 @@ def with_counters(sums: dict, counts, slots: int, kept: Kept,
     sums["kept_residual_mb"] = jnp.asarray(kept.megabytes, jnp.int32)
     sums["attention_calls"] = jnp.asarray(attention_calls, jnp.int32)
     sums["attention_kernel_calls"] = attention_calls * on_kernel
+    return sums
+
+
+def with_scan_counters(sums: dict, calls: int, on_kernel, chunks: int) -> dict:
+    """``sums`` with the state-space scan's calls in one forward pass
+    (``dptpu.ops.ssd``), those of them that run as a fused kernel in the
+    program being lowered (``on_kernel``: ``ssd.kernel_calls`` of one of
+    them, all being of one shape) and the chunks a row is walked in:
+    constants of the lowered program. A model without the scan does not
+    call this and its step carries none of the three."""
+    sums["ssd_calls"] = jnp.asarray(calls, jnp.int32)
+    sums["ssd_kernel_calls"] = jnp.asarray(calls * on_kernel, jnp.int32)
+    sums["ssd_chunks"] = jnp.asarray(chunks, jnp.int32)
     return sums
 
 

@@ -34,14 +34,18 @@ from dptpu.utils.sync import OrderedLock
 
 
 class _SpanCM:
-    """Context-manager form of a span; ``record()`` is the hot-path API."""
+    """Context-manager form of a span; ``record()`` is the hot-path API.
+    What the work inside measures of itself goes into ``attrs`` (``with
+    tracer.span("ckpt") as span: ... span.attrs[...] = ...``) and is
+    recorded with the span; left empty, the span carries none."""
 
-    __slots__ = ("_tracer", "_name", "_step", "_t0")
+    __slots__ = ("_tracer", "_name", "_step", "_t0", "attrs")
 
     def __init__(self, tracer: "Tracer", name: str, step: int):
         self._tracer = tracer
         self._name = name
         self._step = step
+        self.attrs = {}
 
     def __enter__(self):
         self._t0 = time.perf_counter()
@@ -50,12 +54,16 @@ class _SpanCM:
     def __exit__(self, *exc):
         t1 = time.perf_counter()
         self._tracer.record(self._name, self._t0, t1 - self._t0,
-                            step=self._step)
+                            step=self._step, attrs=self.attrs or None)
         return False
 
 
 class _NullCM:
     __slots__ = ()
+
+    @property
+    def attrs(self) -> dict:
+        return {}  # written to and dropped
 
     def __enter__(self):
         return self

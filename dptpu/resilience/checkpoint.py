@@ -249,7 +249,7 @@ class CheckpointManager:
         write_span = "ckpt_write" if run_async else "ckpt"
 
         def _write():
-            with tracer.span(write_span, step=span_step):
+            with tracer.span(write_span, step=span_step) as span:
                 save_checkpoint(
                     state,
                     epoch=epoch,
@@ -266,6 +266,7 @@ class CheckpointManager:
                     ),
                     geometry=self.geometry,
                     sharding=self.sharding,
+                    report=span.attrs,
                 )
                 if self.fault_plan is not None and not remote:
                     # fault hooks (ckpt_truncate@save=N) count ACTUAL

@@ -27,10 +27,13 @@ from __future__ import annotations
 import queue
 import struct
 import threading
+import time
 import zlib
 from typing import Optional
 
 import jax
+import msgpack
+import numpy as np
 from flax import serialization
 
 from dptpu.models.pretrained import QKV_LAYOUT, qkv_needs_migration
@@ -82,6 +85,108 @@ def split_payload(raw: bytes, path: str = "<bytes>") -> tuple:
             )
         return payload, True
     return raw, False
+
+
+# ``serialization.to_bytes`` leaf by leaf. flax packs an array as the
+# msgpack extension ``ndarray`` (1) around ``packb((shape, dtype name,
+# raw bytes))`` (a zero-rank numpy scalar as ``npscalar``, 3), an array
+# over ``MAX_CHUNK_SIZE`` bytes as a dict of flat chunks, and makes three
+# copies of every leaf on the way (``tobytes``, the inner ``packb``, the
+# outer one). The same bytes are produced here as the few header bytes
+# msgpack would write and then the array's own memory.
+_EXT_NDARRAY, _EXT_COMPLEX, _EXT_NPSCALAR = 1, 2, 3
+_FIXEXT = {1: 0xD4, 2: 0xD5, 4: 0xD6, 8: 0xD7, 16: 0xD8}
+
+
+def _sized(length: int, codes: tuple) -> bytes:
+    """msgpack's header of a ``bin`` or ``ext`` of ``length`` bytes: the
+    code of the narrowest of the three widths (8, 16, 32 bits), then the
+    length, big-endian."""
+    for code, fmt, limit in zip(codes, (">B", ">H", ">I"),
+                                (0xFF, 0xFFFF, 0xFFFFFFFF)):
+        if length <= limit:
+            return bytes([code]) + struct.pack(fmt, length)
+    raise ValueError(f"{length} bytes do not fit one msgpack object")
+
+
+def _ext_header(code: int, length: int) -> bytes:
+    if length in _FIXEXT:
+        return bytes([_FIXEXT[length], code])
+    return _sized(length, (0xC7, 0xC8, 0xC9)) + bytes([code])
+
+
+def _array_pieces(array, code: int = _EXT_NDARRAY):
+    """``_msgpack_ext_pack(array)`` as pieces: the headers, then the
+    array's memory itself (a copy only of an array that is not
+    C-contiguous)."""
+    if array.dtype.hasobject or array.dtype.isalignedstruct:
+        raise ValueError("Object and structured dtypes not supported "
+                         "for serialization of ndarrays.")
+    if not array.flags.c_contiguous:
+        array = np.array(array, order="C")
+    raw = memoryview(array.reshape(-1).view(np.uint8))
+    inner = b"\x93" + msgpack.packb(array.shape) \
+        + msgpack.packb(array.dtype.name) \
+        + _sized(raw.nbytes, (0xC4, 0xC5, 0xC6))
+    yield _ext_header(code, len(inner) + raw.nbytes) + inner
+    if raw.nbytes:
+        yield raw
+
+
+def _chunked(array) -> dict:
+    """flax's ``_chunk``: an oversized array as a dict of flat chunks
+    (views, not copies)."""
+    per_chunk = max(1, int(serialization.MAX_CHUNK_SIZE
+                           / array.dtype.itemsize))
+    flat = array.reshape(-1)
+    as_dict = lambda items: {str(i): v for i, v in enumerate(items)}  # noqa: E731
+    return {"__msgpack_chunked_array__": True,
+            "shape": as_dict(array.shape),
+            "chunks": as_dict(flat[i:i + per_chunk]
+                              for i in range(0, flat.size, per_chunk))}
+
+
+def state_dict_pieces(tree):
+    """The bytes of ``serialization.msgpack_serialize(tree)`` (``tree``: a
+    state dict, as ``serialization.to_state_dict`` gives it) as a stream
+    of pieces, in order; an array leaf is yielded as a view of its own
+    memory."""
+    if isinstance(tree, dict):
+        yield msgpack.Packer().pack_map_header(len(tree))
+        for key, value in tree.items():
+            yield msgpack.packb(key, strict_types=True)
+            yield from state_dict_pieces(value)
+    elif isinstance(tree, (np.ndarray, jax.Array)):
+        array = np.asarray(tree)
+        if array.nbytes > serialization.MAX_CHUNK_SIZE:
+            yield from state_dict_pieces(_chunked(array))
+        else:
+            yield from _array_pieces(array)
+    elif isinstance(tree, np.generic):
+        yield from _array_pieces(np.asarray(tree), _EXT_NPSCALAR)
+    elif isinstance(tree, complex):
+        packed = msgpack.packb((tree.real, tree.imag))
+        yield _ext_header(_EXT_COMPLEX, len(packed)) + packed
+    else:
+        yield msgpack.packb(tree, strict_types=True)
+
+
+def stream_sealed(payload, write) -> tuple:
+    """``write`` every piece of ``seal_payload(serialization.to_bytes(
+    payload))``, in order, without ever holding those bytes: the
+    serialized leaves one at a time under a running CRC, then the footer.
+    Returns ``(bytes written, seconds spent encoding and summing)``: the
+    rest of the caller's time went into ``write``."""
+    crc, total, spent = 0, 0, 0.0
+    t = time.perf_counter()
+    for piece in state_dict_pieces(serialization.to_state_dict(payload)):
+        crc = zlib.crc32(piece, crc)
+        total += len(piece)
+        spent += time.perf_counter() - t
+        write(piece)
+        t = time.perf_counter()
+    write(CRC_MAGIC + struct.pack("<I", crc & 0xFFFFFFFF))
+    return total + _FOOTER_LEN, spent
 
 
 class AsyncCheckpointWriter:
@@ -187,8 +292,19 @@ def save_checkpoint(
     data_position: Optional[int] = None,
     geometry: Optional[tuple] = None,
     sharding: str = "",
+    report: Optional[dict] = None,
 ) -> Optional[str]:
     """Serialize state; copy to model_best when ``is_best``. Chief-only.
+
+    The file's bytes are ``seal_payload(serialization.to_bytes(payload))``
+    and are never held: the state is fetched to the host once and goes
+    into the store's temporary file leaf by leaf under a running CRC
+    (``stream_sealed``; ``Store.put_stream``), so a save holds the
+    fetched state and no second or third copy of it. ``report``, a dict
+    (a ``ckpt`` span's ``attrs``), is given the ``bytes`` written and the
+    seconds of the save's three parts: ``fetch_s`` (device to host),
+    ``encode_s`` (headers and checksum), ``store_s`` (write, sync,
+    rename).
 
     ``step_in_epoch``/``data_position`` are the mid-epoch resume
     coordinates (dptpu/resilience): batches already consumed from epoch
@@ -215,6 +331,7 @@ def save_checkpoint(
         return None
     geom = tuple(int(g) for g in geometry) if geometry is not None \
         else (-1, -1, -1)
+    t_fetch = time.perf_counter()
     payload = {
         "epoch": epoch,
         "arch": arch,
@@ -239,16 +356,27 @@ def save_checkpoint(
     }
     # EVERY checkpoint write goes through the Store abstraction
     # (dptpu/data/store.py): a plain directory routes to LocalStore —
-    # whose put_bytes is the exact tmp+flush+fsync+rename+dirent-fsync
+    # whose put_stream is the exact tmp+flush+fsync+rename+dirent-fsync
     # discipline this function used to inline, bit-for-bit — and a
     # store URL (--ckpt-dir file:///... or http(s)://...) routes to the
-    # matching backend with retry/backoff. The CRC footer is sealed
-    # into the bytes BEFORE the store sees them, so the verify/fallback
-    # contract is backend-independent.
+    # matching backend with retry/backoff. The CRC footer is the last
+    # piece the store is handed, so the verify/fallback contract is
+    # backend-independent.
     from dptpu.data.store import open_store
 
+    t_store = time.perf_counter()
+    done = {}
+
+    def produce(write):  # again from the start if the store retries
+        done["bytes"], done["encode_s"] = stream_sealed(payload, write)
+
     store = open_store(directory or ".")
-    store.put_bytes(filename, seal_payload(serialization.to_bytes(payload)))
+    store.put_stream(filename, produce)
+    if report is not None:
+        report.update(
+            bytes=done["bytes"], fetch_s=t_store - t_fetch,
+            encode_s=done["encode_s"],
+            store_s=time.perf_counter() - t_store - done["encode_s"])
     if is_best:
         store.copy(filename, BEST_NAME)
     return store.path_for(filename)

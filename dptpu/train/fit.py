@@ -562,6 +562,10 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
     if pretrained_vars is None:
         # create_train_state ran ``model.init``: that was model_init
         _setup_phase("state_commit")
+    # the state holds the loaded leaves now: once it is placed on the
+    # device the host's copy goes, and does not ride along to the end of
+    # the run (3 GB beside a 9 GB save for a 770 M-parameter model)
+    del pretrained_vars
     if hasattr(model, "fitted_to"):
         # a model that can keep residuals through its rematerialisation
         # learns what the chip has beside the train state; a device that
@@ -1366,7 +1370,7 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
                 **{f"val_{k}": v for k, v in val_stats.items()},
                 **({"obs": obs_report} if obs_report is not None else {}),
             })
-            with tracer.span("ckpt"):
+            with tracer.span("ckpt") as ckpt_span:
                 boundary_path = save_checkpoint(
                     gathered,
                     epoch=epoch + 1,
@@ -1377,6 +1381,7 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
                     directory=ckpt_dir,
                     geometry=manager.geometry,
                     sharding=plan.fingerprint,
+                    report=ckpt_span.attrs,
                 )
             _boundary_saved(boundary_path)
             if verbose and "moe_load_max" in train_stats:

@@ -57,9 +57,11 @@ class MoeLoad:
 
     # the megabytes the model's blocks keep through their
     # rematerialisation; the attention's calls in one forward pass and
-    # those of them that run as its kernels in this program
+    # those of them that run as its kernels in this program; the same
+    # of the state-space scan, and the chunks it walks a row in
     STEP_CONSTANTS = ("kept_residual_mb", "attention_calls",
-                      "attention_kernel_calls")
+                      "attention_kernel_calls", "ssd_calls",
+                      "ssd_kernel_calls", "ssd_chunks")
 
     def __init__(self):
         self.steps = 0

@@ -152,9 +152,11 @@ def token_loss_and_grads(state, batch, denom, gather_params=None,
     (_, (loss, sums, new_stats)), grads = jax.value_and_grad(
         loss_fn, has_aux=True)(state.params)
     # what the model counts beside the loss: the expert layers' load,
-    # the residuals its blocks keep, its attention calls on the kernel
+    # the residuals its blocks keep, its attention's and its state-space
+    # scan's calls and those of them on a kernel
     moe = {k: v for k, v in sums.items()
-           if k.startswith(("moe_", "attention_")) or k == "kept_residual_mb"}
+           if k.startswith(("moe_", "attention_", "ssd_"))
+           or k == "kept_residual_mb"}
     terms = {"mtp_loss": sums["mtp_loss_sum"] / rows} \
         if "mtp_loss_sum" in sums else {}
     return (loss, sums["correct1"] / rows, sums["correct5"] / rows,

@@ -50,6 +50,11 @@ _FAST_MODULES = {
     # reference at hidden 64, ONE case through main_apex (two fit() runs
     # of 16 steps at 32 tokens); under a minute of one worker
     "test_joyai",
+    # granite-4.0-h-micro (ISSUE 39): the model, the scan and the
+    # reference at toy widths, two minutes; the cell's rehearsal at the
+    # published widths on two layers and 96 tokens in a one-device child,
+    # a minute and a half more, in a file of its own
+    "test_granite", "test_granite_cell",
     # fit()'s default train feed (ISSUE 31): ONE module fixture runs
     # fit() four times at the sizes above (resnet18@32 and the tiny
     # token model, 4-8 steps, default and thread mode); the rest drives

@@ -14,7 +14,13 @@ the case below that takes its place here holds what still has to hold.
 PR 37 appended ``attention_kernel_share`` behind ``mtp_loss_share``,
 whose own listing case asks to be the LAST entry: the case below that
 takes its place holds the rest of it, and the new reader's cases
-(``test_attention_kernel_share.py``) run here too."""
+(``test_attention_kernel_share.py``) run here too. PR 39 appended
+``granite-ssm-fit-1chip`` (``granite-4.0-h-micro-vp8-bf16``) behind
+JoyAI's cell: the pins that asked JoyAI's to be the last say what can
+stay true (the accepted cells come first and in their order, JoyAI's own
+metric lists only JoyAI, four-chip cells stay within a quarter), the new
+cell has its own case, and its reader's (``test_ssd_kernel_share.py``)
+and its limits' (``test_granite_limits.py``) run here too."""
 
 import json
 import os
@@ -42,8 +48,18 @@ from benchmark.tests import test_mtp_loss_share as _mtp  # noqa: E402
 # the reader of the attention's share on the kernel (9 cases)
 from benchmark.tests.test_attention_kernel_share import *  # noqa: F401,F403,E402
 
+# the reader of the state-space scan's share on a kernel (9 cases)
+from benchmark.tests.test_ssd_kernel_share import *  # noqa: F401,F403,E402
+# the granite configuration's limits on faulty programs (4 cases)
+from benchmark.tests.test_granite_limits import *  # noqa: F401,F403,E402
+from benchmark.tests.test_granite_limits import sound  # noqa: F401,E402
+
 CELL = "lfm2moe-fit-8k-1chip"
 JOYAI_CELL = "joyai-fit-8k-1chip"
+GRANITE_CELL = "granite-ssm-fit-1chip"
+# the cells the benchmark accepted, in its order: a later one is appended
+ACCEPTED = ["rn50-fit-1chip", "vitb16-fit-1chip", "rn50-ddp-4chip", CELL,
+            JOYAI_CELL]
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 
 
@@ -147,7 +163,9 @@ def test_kept_residual_mb_is_listed_for_the_token_cells_as_its_file_has_it():
     # (an appended cell, never an edit)
     assert {k: v for k, v in entry.items() if k != "workloads"} == {
         k: spec[k] for k in entry if k != "workloads"}
-    assert entry["workloads"] == spec["workloads"] + [JOYAI_CELL]
+    # ... then JoyAI's, then whatever came later
+    listed = spec["workloads"] + [JOYAI_CELL]
+    assert entry["workloads"][:len(listed)] == listed
     assert entry["layer"] == cells.layer_metric(
         "expert_dropped_tokens")["layer"]
 
@@ -193,17 +211,26 @@ def test_the_joyai_cell_lands_as_files_and_keeps_every_contract():
         assert callable(cells.reader(metric).read)
     assert {m["name"] for m in cell.end_to_end} == {
         "train_img_s_chip", "step_ms_p95", "setup_s"}
-    # appended: the cells the benchmark had come first in every list
+    # appended: the accepted cells come first and in their order, in the
+    # list of cells and in every metric's; JoyAI's own metric lists JoyAI
+    # alone; four-chip cells stay within a quarter, rounded down (one
+    # always may)
     bench = cells.manifest()
-    assert bench["workloads"][-1]["name"] == JOYAI_CELL
-    assert bench["configs"][-1]["name"] == config["name"]
+    names = [w["name"] for w in bench["workloads"]]
+    assert names[:len(ACCEPTED)] == ACCEPTED
+    (entry,) = [c for c in bench["configs"] if c["name"] == config["name"]]
+    assert bench["configs"].index(entry) == 3
     for m in bench["end_to_end"] + bench["per_layer"]:
         if m["name"] == "mtp_loss_share":
             assert m["workloads"] == [JOYAI_CELL]
         elif JOYAI_CELL in m.get("workloads", ()):
-            assert m["workloads"][-2:] == [CELL, JOYAI_CELL]
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
-    assert len(bench["workloads"]) == 5
+            at = m["workloads"].index(JOYAI_CELL)
+            assert m["workloads"][at - 1] == CELL
+            assert m["workloads"][:at + 1] == [
+                c for c in ACCEPTED if c in m["workloads"]]
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) \
+        <= max(1, len(names) // 4)
+    assert len(names) >= 5 and len(set(names)) == len(names)
 
     # the trainer's arguments: what the traffic file adds is what
     # create_kwargs mirrors, and the model both build is the `model` group
@@ -242,13 +269,12 @@ def test_the_joyai_cell_lands_as_files_and_keeps_every_contract():
     # but for the keys `reduced` names; no width among them
     reduced = set(config["reduced"])
     assert reduced == {"num_hidden_layers", "n_routed_experts", "vocab_size"}
-    assert bench["configs"][-1]["reduced"] == config["reduced"]
+    assert entry["reduced"] == config["reduced"]
     if os.path.exists(CATALOG):
         with open(CATALOG) as f:
             row = next(r for r in map(json.loads, f)
                        if r["name"] == "JoyAI-LLM-Flash")
-        assert config["source"] == row["source_url"] \
-            == bench["configs"][-1]["source"]
+        assert config["source"] == row["source_url"] == entry["source"]
         for key, value in row["config"].items():
             if key in reduced:
                 assert config["published"][key] == value
@@ -273,6 +299,133 @@ def test_the_joyai_cell_lands_as_files_and_keeps_every_contract():
     assert params == model["parameters"] == 491_696_128
     assert cell.family.train_flops(model, 1) == pytest.approx(
         27.55e12, rel=2e-3)
+    example = cell.family.example_input(model)
+    assert example.shape == (1, 8192) and example.dtype == np.int32
+
+    # two seeds: other rows, the same number of steps (one step program)
+    assert traffic["dataset_images"] % cell.feed.SEED_ROWS == 0
+    a, b = (cell.feed.epoch_order(drive.dataset_images(traffic, s), 0, 5)
+            for s in (1, 130))
+    assert len(a) == len(b) == traffic["dataset_images"]
+    assert np.array_equal(b - a, np.full(len(a), 1))
+
+
+def test_the_granite_cell_lands_as_files_and_keeps_every_contract():
+    cell = cells.load_cell(GRANITE_CELL)
+    config, traffic = cell.config, cell.traffic
+    assert cell.chips == 1 and cell.global_batch == 1
+    assert cell.feed.__name__ == "benchmark.feeds.tokens"
+    assert cell.family.__name__ == "benchmark.reference.granitemoehybrid"
+    assert cell.optimizer.__name__ == "benchmark.reference.optimizers.adamw"
+    for kind, module in (("feeds", cell.feed), ("reference", cell.family),
+                         ("reference/optimizers", cell.optimizer)):
+        assert all(hasattr(module, a) for a in cells.CONTRACTS[kind])
+    # every per-layer metric the LFM2 cell reports but the experts' three
+    # (it has none), and its own
+    other = {m["name"] for m in cells.load_cell(CELL).per_layer}
+    mine = {m["name"] for m in cell.per_layer}
+    assert mine == {m for m in other if not m.startswith("expert_")} \
+        | {"ssd_kernel_share"}
+    assert {"kept_residual_mb", "attention_kernel_share", "device_mfu"} <= mine
+    for metric in mine:
+        assert callable(cells.reader(metric).read)
+    assert {m["name"] for m in cell.end_to_end} == {
+        "train_img_s_chip", "step_ms_p95", "setup_s"}
+    # appended: behind the accepted cells, in every list it is on
+    bench = cells.manifest()
+    names = [w["name"] for w in bench["workloads"]]
+    assert names.index(GRANITE_CELL) == len(ACCEPTED)
+    (entry,) = [c for c in bench["configs"] if c["name"] == config["name"]]
+    assert bench["configs"].index(entry) == 4
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] == "ssd_kernel_share":
+            assert m["workloads"][0] == GRANITE_CELL
+        elif GRANITE_CELL in m.get("workloads", ()):
+            at = m["workloads"].index(GRANITE_CELL)
+            assert m["workloads"][at - 1] == JOYAI_CELL
+    (cell_entry,) = [w for w in bench["workloads"]
+                     if w["name"] == GRANITE_CELL]
+    assert (cell_entry["config"], cell_entry["traffic"], cell_entry["chips"]
+            ) == (config["name"], traffic["name"], 1)
+
+    # the trainer's arguments: what the traffic file adds is what
+    # create_kwargs mirrors, and the model both build is the `model` group
+    from dptpu.config import parse_config
+    from dptpu.models import create_model, model_task
+    from dptpu.models.registry import token_model_kwargs
+
+    argv = drive.fit_argv(cell, drive.dataset_images(traffic, 2**31 + 130))
+    assert argv[0] == "tokens:8192@2" and argv[argv.index("-b") + 1] == "1"
+    assert "--experts" not in argv
+    parsed = parse_config(argv, variant="apex")
+    assert model_task(parsed.arch) == "tokens"
+    assert token_model_kwargs(parsed, "tokens") == config["create_kwargs"]
+    assert (parsed.optimizer, parsed.beta1, parsed.beta2, parsed.eps,
+            parsed.weight_decay) == ("adamw", 0.9, 0.95, 1e-8, 0.1)
+    held = create_model(config["arch"], **config["create_kwargs"]).config
+    model = config["model"]
+    for key in ("hidden_size", "shared_intermediate_size",
+                "num_attention_heads", "num_key_value_heads", "head_dim",
+                "attention_multiplier", "embedding_multiplier",
+                "residual_multiplier", "logits_scaling", "mamba_n_heads",
+                "mamba_d_head", "mamba_d_state", "mamba_d_conv",
+                "mamba_expand", "mamba_n_groups", "mamba_chunk_size",
+                "rms_norm_eps", "vocab_size", "sequence_length"):
+        assert getattr(held, key) == model[key], key
+    assert held.layers_here == (model["layers_first"],
+                                model["layers_held"]) == (0, 10)
+    assert [kind for _, kind in held.types_here] == model["layer_types"] \
+        == config["layer_types"]
+    assert model["layer_types"].count("mamba") == 9 \
+        and model["layer_types"][5] == "attention"
+    assert traffic["check_steps"] == 2 <= traffic["warmup_iters"]
+    assert set(config["precision"]) >= {"ssd_decay", "ssd_state"}
+    assert config["precision"]["ssd_decay"] \
+        == config["precision"]["ssd_state"] == "float32"
+
+    # the file beside the catalog: every published number under its key,
+    # but for the keys `reduced` names; no width among them
+    reduced = set(config["reduced"])
+    assert reduced == {"num_hidden_layers", "layer_types", "vocab_size"}
+    assert entry["reduced"] == config["reduced"]
+    if os.path.exists(CATALOG):
+        with open(CATALOG) as f:
+            row = next(r for r in map(json.loads, f)
+                       if r["name"] == "granite-4.0-h-micro")
+        assert config["source"] == row["source_url"] == entry["source"]
+        for key, value in row["config"].items():
+            if key in reduced:
+                assert config["published"][key] == value
+            else:
+                assert config[key] == value, key
+        assert config["layer_types"] == row["config"]["layer_types"][:10]
+    assert config["num_hidden_layers"] == model["layers_held"]
+    assert config["vocab_size"] == model["vocab_size"] == 12544 == 100352 // 8
+    assert len(config["assumed"]) >= 8 and "N = 8" in config["deployment"]
+
+    # the family's shapes are the program's, leaf for leaf, and its count
+    # of operations is the issue's arithmetic
+    template = drive.program_template(config)
+    spec = {name: tuple(shape)
+            for name, shape, _, _ in cell.family.weight_spec(model)}
+    from dptpu.models.pretrained import torch_key_map
+
+    assert set(torch_key_map(config["arch"], template)) == set(spec)
+    assert set(cell.family.trainable(model)) == set(spec)
+    params = sum(int(np.prod(s)) for s in spec.values())
+    assert params == model["parameters"] == 772_160_448
+    assert params == sum(int(np.prod(leaf.shape)) for leaf in
+                         jax.tree_util.tree_leaves(template["params"]))
+    # 1,606 MFLOP a token forward, 39.5 TFLOP a step: the scan's products
+    # are counted over the causal half of a chunk, as the attention's are
+    # over half the row (3.18 MFLOP a token and Mamba-2 layer; ISSUE 39's
+    # 4.3 and 1,617 take the whole chunk, which the program computes and
+    # half of which the mask throws away)
+    assert cell.family.scan_flops_per_token(model) == 3_178_496
+    assert cell.family.forward_flops_per_token(model) == pytest.approx(
+        1606e6, rel=1e-3)
+    assert cell.family.train_flops(model, 1) == pytest.approx(
+        39.47e12, rel=1e-3)
     example = cell.family.example_input(model)
     assert example.shape == (1, 8192) and example.dtype == np.int32
 

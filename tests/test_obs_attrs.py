@@ -86,6 +86,23 @@ def test_null_tracer_takes_and_drops_attrs():
     assert t.snapshot() == [] and t.drain() == [] and not t.enabled
 
 
+@pytest.mark.parametrize("written", [{"bytes": 7, "store_s": 0.5}, {}],
+                         ids=["filled", "left-empty"])
+def test_a_span_records_what_the_work_inside_wrote_into_its_attrs(written):
+    """``with tracer.span(...) as span``: the work measures itself into
+    ``span.attrs`` (a save's bytes and parts); left empty the record has
+    none; the null tracer's span takes the writes and drops them."""
+    t = obs.Tracer(capacity=8)
+    with t.span("ckpt", step=3) as span:
+        span.attrs.update(written)
+    (rec,) = t.snapshot()
+    assert (rec["name"], rec["step"]) == ("ckpt", 3)
+    assert rec.get("attrs") == (written or None)
+    with obs.NullTracer().span("ckpt") as span:
+        span.attrs.update(written)
+        assert span.attrs == {}
+
+
 # ------------------------------------------------------------- the loop ----
 
 

@@ -236,6 +236,132 @@ def test_legacy_footerless_checkpoint_still_loads(tmp_path):
     )
 
 
+# -- the streamed save ---------------------------------------------------------
+
+def _mixed_payload():
+    """A payload as ``save_checkpoint`` builds one: plain values beside
+    trees whose leaves have 0, 1 and over 2**20 entries, in mixed dtypes
+    and layouts, scalars of numpy's, a tuple and a list among the nodes."""
+    import ml_dtypes
+
+    rng = np.random.RandomState(0)
+    return {
+        "epoch": 3, "arch": "toy", "best_acc1": 1.5, "flag": True,
+        "none": None, "cplx": 1 + 2j,
+        # a float32 scalar packs to 16 bytes, one of msgpack's fixed-size
+        # extensions (1, 2, 4, 8, 16), which have a header of their own
+        "step": np.asarray(7, np.int32), "generation": np.float32(2.5),
+        "params": {
+            "empty": rng.randn(0).astype(np.float32),
+            "hollow": rng.randn(2, 0, 3).astype(np.float16),
+            "one": rng.randn(1).astype(np.float32),
+            "big": rng.randn(1025, 1031).astype(np.float32),  # 2**20 + 7,351
+            "bf16": rng.randn(4, 5).astype(ml_dtypes.bfloat16),
+            "flags": rng.randint(0, 2, (300,)).astype(bool),
+            "int8": rng.randint(0, 9, (70000,)).astype(np.int8),
+            "fortran": np.asfortranarray(rng.randn(7, 9)),
+            "device": jnp.arange(6, dtype=jnp.float32).reshape(2, 3),
+            "i8x1": np.zeros((1,), np.int8), "i8x8": np.zeros((8,), np.int8),
+        },
+        "opt_state": ({"mu": rng.randn(3, 3).astype(np.float32)}, (), [1, 2]),
+    }
+
+
+@pytest.mark.parametrize("chunk_bytes", [None, 1000],
+                         ids=["whole-leaves", "chunked-leaves"])
+def test_the_streamed_save_is_the_old_forms_bytes(monkeypatch, chunk_bytes):
+    import io
+
+    from flax import serialization
+
+    from dptpu.train import checkpoint as ckpt
+
+    if chunk_bytes:  # flax cuts a leaf over this into a dict of chunks
+        monkeypatch.setattr(serialization, "MAX_CHUNK_SIZE", chunk_bytes)
+    payload = _mixed_payload()
+    want = ckpt.seal_payload(serialization.to_bytes(payload))
+    out = io.BytesIO()
+    written, encode_s = ckpt.stream_sealed(payload, out.write)
+    assert out.getvalue() == want
+    assert written == len(want) and encode_s >= 0
+    body, verified = ckpt.split_payload(out.getvalue())
+    assert verified and body == want[:-ckpt._FOOTER_LEN]
+
+
+def test_save_checkpoint_writes_the_old_forms_file_and_reports(tmp_path,
+                                                               monkeypatch):
+    from flax import serialization
+
+    from dptpu.train import checkpoint as ckpt
+
+    seen = []
+    stream = ckpt.stream_sealed
+    monkeypatch.setattr(
+        ckpt, "stream_sealed",
+        lambda payload, write: seen.append(payload) or stream(payload, write))
+    report = {}
+    path = save_checkpoint(tiny_state(2.5), epoch=3, arch="toy",
+                           best_acc1=12.5, is_best=True,
+                           directory=str(tmp_path), report=report)
+    (payload,) = seen
+    want = ckpt.seal_payload(serialization.to_bytes(payload))
+    assert open(path, "rb").read() == want
+    assert open(tmp_path / "model_best.pth.tar", "rb").read() == want
+    assert not os.path.exists(path + ".tmp")
+    assert report["bytes"] == len(want)
+    assert min(report["fetch_s"], report["encode_s"], report["store_s"]) >= 0
+
+
+def test_a_truncated_stream_fails_its_crc():
+    from dptpu.train import checkpoint as ckpt
+
+    pieces = []
+    ckpt.stream_sealed(_mixed_payload(), lambda p: pieces.append(bytes(p)))
+    whole = b"".join(pieces)
+    assert ckpt.split_payload(whole)[1]
+    longest = max(range(len(pieces)), key=lambda i: len(pieces[i]))
+    # a leaf that never reached the file, and a leaf cut short
+    for torn in (b"".join(pieces[:longest] + pieces[longest + 1:]),
+                 b"".join(pieces[:longest] + [pieces[longest][:-64]]
+                          + pieces[longest + 1:])):
+        with pytest.raises(CorruptCheckpointError, match="checksum"):
+            ckpt.split_payload(torn)
+
+
+def test_the_streamed_save_holds_less_than_two_leaves_beside_the_state(
+        tmp_path):
+    """Six leaves of 4 MB: the old form held the state twice more
+    (``to_bytes`` and the sealed copy); the stream holds headers."""
+    import tracemalloc
+
+    from flax import serialization
+
+    from dptpu.data.store import open_store
+    from dptpu.train import checkpoint as ckpt
+
+    leaf = 4 * 2 ** 20
+    payload = {"params": {f"w{i}": np.full((leaf // 4,), i, np.float32)
+                          for i in range(6)}, "epoch": 1}
+
+    def peak_of(save):
+        tracemalloc.start()
+        try:
+            save()
+            return tracemalloc.get_traced_memory()[1]
+        finally:
+            tracemalloc.stop()
+
+    store = open_store(str(tmp_path))
+    streamed = peak_of(lambda: store.put_stream(
+        "new.bin", lambda write: ckpt.stream_sealed(payload, write)))
+    old = peak_of(lambda: store.put_bytes(
+        "old.bin", ckpt.seal_payload(serialization.to_bytes(payload))))
+    assert streamed < 2 * leaf, streamed
+    assert old > 2 * 6 * leaf, old
+    assert open(tmp_path / "new.bin", "rb").read() \
+        == open(tmp_path / "old.bin", "rb").read()
+
+
 # -- rotation + fallback -----------------------------------------------------
 
 def test_rotation_keeps_last_k(tmp_path):

@@ -57,6 +57,31 @@ def test_local_store_roundtrip(tmp_path):
         s.get_bytes("missing.bin")
 
 
+@pytest.mark.parametrize("kind", ["local", "http"])
+def test_put_stream_writes_the_pieces_in_order(served, kind):
+    """An object handed over piece by piece (bytes, a view of an array's
+    memory) is the pieces joined; a directory takes them straight into
+    its temporary file, a backend without a streaming write gathers
+    them."""
+    import numpy as np
+
+    root, url = served
+    s = LocalStore(root) if kind == "local" else HTTPStore(url)
+    array = np.arange(1000, dtype=np.float32)
+    calls = []
+
+    def produce(write):
+        calls.append(1)
+        write(b"head")
+        write(memoryview(array.view(np.uint8)))
+        write(b"")
+        write(b"tail")
+
+    s.put_stream("piecewise.bin", produce)
+    assert s.get_bytes("piecewise.bin") == b"head" + array.tobytes() + b"tail"
+    assert calls == [1] and os.listdir(root) == ["piecewise.bin"]
+
+
 def test_http_store_roundtrip_and_ranges(served):
     root, url = served
     s = HTTPStore(url)

@@ -176,3 +176,45 @@ def test_a_rematerialised_attention_block_keeps_out_and_lse_on_the_chip(
     # one layer's activations and its weights' gradients: 1.062 GB with
     # the scan and with the kernels (1.50 GB with nothing kept)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.1e9
+
+
+def test_a_state_space_and_an_attention_layer_step_compiles_for_the_chip(
+        one_chip):
+    """The whole train step (``make_train_step``, AdamW, O2) of
+    granite-4.0-h-micro's published layers 4 and 5, a Mamba-2 layer and
+    the attention layer, at the cell's row of 8,192 tokens and its
+    vocabulary rows: the chunked scan's groups fit beside the block being
+    run again, and the one attention call is the two kernels."""
+    from dptpu.models import create_model
+    from dptpu.ops import optimizers, ssd
+    from dptpu.train.state import create_train_state
+    from dptpu.train.step import make_train_step
+
+    length = 8192
+    model = create_model("granite_4_0_h_micro", dtype=jnp.bfloat16,
+                         layers="4:2", vocab="0:12544",
+                         sequence_length=length)
+    assert [kind for _, kind in model.config.types_here] == [
+        "mamba", "attention"]
+    tx = optimizers.adamw(0.9, 0.95, 1e-8, 0.1)
+    placed = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda leaf: _shape(leaf.shape, leaf.dtype, one_chip), tree)
+    state = placed(jax.eval_shape(lambda: create_train_state(
+        jax.random.PRNGKey(0), model, tx, input_shape=(1, length),
+        input_dtype=jnp.int32)))
+    batch = placed({"tokens": jax.ShapeDtypeStruct((1, length), jnp.int32),
+                    "labels": jax.ShapeDtypeStruct((1, length), jnp.int32),
+                    "mask": jax.ShapeDtypeStruct((1, length), jnp.bool_)})
+    compiled = make_train_step(None, jnp.bfloat16, task="tokens").lower(
+        state, batch).compile()
+    text = compiled.as_text()
+    forward, backward = _attention_calls(text)
+    # nothing is kept at a budget of 0: the forward kernel runs again
+    assert (len(forward), len(backward)) == (2, 1), (forward, backward)
+    # a row is walked in groups of 8 chunks (128 MB of decay matrices a
+    # group): no array of all 32 chunks' [64, 256, 256] float32 is made
+    assert ssd._group_size(1, ssd.chunks_of(length), 64, 256) == 8
+    assert "f32[8,64,256,256]" in text
+    assert "f32[32,64,256,256]" not in text
+    # 1.40 GB: the scan's group and the feed-forward's 8,192 x 16,384
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.6e9
