@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import math
 import os
 from typing import Optional
 
@@ -306,8 +305,16 @@ class DerivedConfig:
     * ``world_size = ngpus_per_node * nnodes``  (imagenet_ddp.py:76-81)
     * ``rank = node_rank * ngpus + gpu``        (imagenet_ddp.py:103)
     * ``batch_size //= ngpus``                  (imagenet_ddp.py:125)
-    * ``workers = ceil(workers / ngpus)``       (imagenet_ddp.py:126)
     * ``lr *= global_batch/256`` (apex only)    (imagenet_ddp_apex.py:161-162)
+
+    Not here: ``workers = ceil(workers / ngpus)`` (imagenet_ddp.py:126).
+    One process drives all local chips, so a host's feed has ONE pool,
+    and ``dptpu.data.feed.pool_size`` sizes it from ``-j``, the local
+    chips, the cores and the workers mode. It keeps the reference's
+    rule as its floor (``ceil(workers / chips) x chips``, the sum of
+    what the per-GPU loaders would spawn) and not the division itself:
+    on a host with cores for ``-j`` workers a chip that left four chips
+    with the four workers that feed one (PERF.md section 6, PR 40).
     """
 
     num_processes: int  # hosts (JAX processes), = reference's nnodes
@@ -317,11 +324,6 @@ class DerivedConfig:
     per_device_batch_size: int
     global_batch_size: int
     per_host_batch_size: int
-    # ceil(workers / local devices), imagenet_ddp.py:126 — one host
-    # process drives all local chips, so its loader runs
-    # workers_per_device * local_device_count decode threads (the sum of
-    # what the reference's per-GPU DataLoaders would spawn)
-    workers_per_device: int
     scaled_lr: float
     use_bf16: bool
     sync_bn: bool
@@ -369,7 +371,6 @@ def derive(cfg: Config, *, local_device_count: int,
         per_device_batch_size=per_device,
         global_batch_size=global_batch,
         per_host_batch_size=per_device * n_local,
-        workers_per_device=int(math.ceil(cfg.workers / n_local)),
         scaled_lr=scaled_lr,
         use_bf16=use_bf16,
         sync_bn=cfg.sync_bn,

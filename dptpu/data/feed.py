@@ -131,6 +131,63 @@ def host_cores() -> int:
         return os.cpu_count() or 1
 
 
+# cores a process pool leaves to the loop's own process: its thread,
+# the runtime's transfer threads, the ring's pump. On the four-chip host
+# that process took 1.6 cores beside four workers and 1.9, 2.1, 2.1
+# beside eight, twelve, sixteen (512 rows and a 77 MB put a step, twenty
+# steps a second; PERF.md section 6, PR 40). Workers that are ahead of
+# the loop sleep on a full ring, so the count need not be generous.
+POOL_RESERVE_CORES = 2
+
+
+def pool_size(workers: int, local_chips: int, cores: int,
+              workers_mode: str) -> int:
+    """How many workers this host's feed gets for ``-j workers``.
+
+    The reference gives each of a node's GPU processes
+    ``ceil(workers / ngpus)`` loader workers (imagenet_ddp.py:126); one
+    process drives all local chips here, so the host's pool is at least
+    that times the chips: the FLOOR, what every host got before PR 40, and
+    the whole answer in thread mode, where pool threads share the
+    interpreter with the loop (four of them held its dispatch call for
+    65 ms of 69, PERF.md section 6, PR 31) and more would cost more.
+
+    A pool of worker PROCESSES gives a local chip the ``workers`` it
+    has when it is alone on a host: ``workers`` for each local chip, as
+    far as the host has cores for them beside ``POOL_RESERVE_CORES``,
+    and never below the floor. Four workers for four chips made 512
+    rows in 58.9 ms against a device step of 49.6 (PERF.md section 6,
+    PR 40); launched as four processes of one chip each the same host
+    always got sixteen. With one local chip it is ``workers`` whatever
+    the cores; ``-j 0`` stays 0 (the loader then keeps one worker)."""
+    floor = -(-workers // local_chips) * local_chips
+    if workers_mode != "process":
+        return floor
+    return max(floor, min(workers * local_chips,
+                          cores - POOL_RESERVE_CORES))
+
+
+def pool_notice(workers: int, local_chips: int, cores: int,
+                workers_mode: str) -> str:
+    """``workers=N (how N came about)`` for the ``=> input pipeline:``
+    line: what was asked a chip, what bounded it."""
+    size = pool_size(workers, local_chips, cores, workers_mode)
+    asked = workers * local_chips
+    chips = f"{local_chips} chip" + ("s" if local_chips != 1 else "")
+    if workers_mode != "process":
+        how = (f"thread mode keeps ceil({workers} / {local_chips}) a chip "
+               f"x {chips}")
+    elif size == asked:
+        how = f"{workers} a chip x {chips}; {cores} cores"
+    elif size == cores - POOL_RESERVE_CORES:
+        how = (f"{workers} a chip x {chips} asks {asked}; {cores} cores "
+               f"less {POOL_RESERVE_CORES} kept for the loop")
+    else:
+        how = (f"the floor ceil({workers} / {local_chips}) a chip x "
+               f"{chips}; {cores} cores")
+    return f"workers={size} ({how})"
+
+
 def feed_knobs() -> tuple:
     """The input-pipeline env knobs, under the locked fail-fast contract:
     every explicit-but-invalid value raises with the accepted values.
@@ -188,9 +245,14 @@ def build_feed(cfg, derived, *, task: str, model_config, image_size: int,
     from dptpu.envknob import env_bool
 
     workers_mode, cache_bytes, cache_scope, leased = feed_knobs()
+    # this host's pool, train and validation alike (they feed the same
+    # chips): the one place that decides it
+    pool = (cfg.workers, derived.local_device_count, host_cores(),
+            workers_mode)
+    num_workers = pool_size(*pool)
     notices = [
         f"=> input pipeline: workers_mode={workers_mode}, "
-        f"decode cache "
+        f"{pool_notice(*pool)}, decode cache "
         + (f"{cache_bytes / 1e6:.0f} MB per dataset ({cache_scope})"
            if cache_bytes else "off")
         + (", leased slots" if leased and workers_mode == "process"
@@ -244,10 +306,6 @@ def build_feed(cfg, derived, *, task: str, model_config, image_size: int,
             shuffle=True,
             seed=seed,
         )
-    # the sum of the reference's per-GPU worker pools: each of the
-    # n_local device-slots gets ceil(workers / n_local) decode workers
-    # (imagenet_ddp.py:126), pooled per host
-    num_workers = derived.workers_per_device * derived.local_device_count
 
     def make_train_loader(batch: int) -> DataLoader:
         return DataLoader(

@@ -286,13 +286,15 @@ class DataLoader:
         # process path's equivalent wait is spanned around collect).
         # ``step`` is the batch's index in the epoch: the step that will
         # consume it. The span says whether the workers were done when
-        # the loop came for the batch, and what the batch cost them.
+        # the loop came for the batch, what the batch cost them, and how
+        # many of them the pool has.
         tracer = obs.get_tracer()
         if not tracer.enabled:
             for f in futs:
                 f.result()  # wait + propagate decode errors
             return self._assemble(imgs, labels, n_valid, valid)
-        attrs = {"ready": all(f.done() for f in futs), "rows": n_valid}
+        attrs = {"ready": all(f.done() for f in futs), "rows": n_valid,
+                 "workers": self.num_workers}
         if self._degraded:
             # these threads stand in for a process pool that gave up: a
             # log that shows it tells a run that gained nothing from the
@@ -513,7 +515,9 @@ class DataLoader:
                     tracer.record(
                         "collect", t_collect,
                         time.perf_counter() - t_collect, step=first + b,
-                        attrs={"rows": n_valid, **pipe.last_collect},
+                        attrs={"rows": n_valid,
+                               "workers": self.num_workers,
+                               **pipe.last_collect},
                     )
                 batch = self._assemble(imgs, labels, n_valid,
                                        valid=chunks[b][1])

@@ -78,7 +78,12 @@ def test_derive_ddp_batch_split():
     assert d.per_host_batch_size == 1024
     assert d.global_device_count == 16
     assert d.global_batch_size == 4096
-    assert d.workers_per_device == 1  # ceil(4/4)
+    # imagenet_ddp.py:126's ceil(workers / ngpus) a chip is the FLOOR of
+    # the host's one pool, which the feed sizes (dptpu.data.feed)
+    from dptpu.data.feed import pool_size
+
+    assert pool_size(cfg.workers, d.local_device_count, 2, "thread") == 4
+    assert pool_size(cfg.workers, d.local_device_count, 30, "process") == 16
     assert not d.is_chief
 
 

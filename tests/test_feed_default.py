@@ -65,13 +65,16 @@ def _digest(batch: dict) -> tuple:
 # ------------------------------------------------------------ through fit --
 
 _IMAGE_CFG = dict(data="synthetic:96", arch="resnet18", epochs=1,
-                  batch_size=24, lr=0.02, workers=2, print_freq=100, seed=1,
+                  batch_size=24, lr=0.02, workers=1, print_freq=100, seed=1,
                   gpu=0)  # one device: 4 steps of 24, then 9 rows to validate
 _TOKEN_ARGS = ["tokens:64", "-a", "lfm2_test_tiny", "--optimizer", "adamw",
                "--beta2", "0.95", "--wd", "0.1", "--lr", "0.08", "-b", "2",
                "--layers", "1:3", "--experts", "0:4", "--vocab-rows", "0:128",
                "--seq-len", "32", "--opt-level", "O2", "-p", "100",
-               "--epochs", "1"]  # the fake pod's 8 devices: 4 steps of 16
+               "--epochs", "1",  # the fake pod's 8 devices: 4 steps of 16
+               # these runs only need A pool: one worker a chip, on any
+               # host, not the -j 4 a chip its cores would allow
+               "-j", "1"]
 
 
 _RING_KNOBS = ("DPTPU_DECODE_AHEAD", "DPTPU_RING_DEPTH", "DPTPU_LEASE_DEPTH",
@@ -206,8 +209,8 @@ def test_fit_starts_the_train_pool_in_its_data_phase_once(runs, source):
     # epoch() spawned nothing of its own
     assert run["pools"] == 2
     (start,) = [s for s in run["spans"] if s["name"] == "feed_start"]
-    # -j is per chip (ceil(workers / local chips)), the pool per host:
-    # one worker for each of the fake pod's eight devices
+    # -j is per chip, the pool per host (dptpu.data.feed.pool_size):
+    # -j 1, one worker for each of the fake pod's eight devices
     assert start["attrs"] == {"mode": "process", "workers": 8, "slots": 7}
     (data,) = [s for s in run["spans"] if s["name"] == "setup.data"]
     assert data["ts"] <= start["ts"]
@@ -239,6 +242,7 @@ def test_default_collect_spans_feed_the_benchmarks_readers(runs, source, rows):
     assert [s for s in collects if s["attrs"]["rows"] == rows]
     for s in collects:
         assert {"ready", "rows", "cpu_s", "wall_s"} <= set(s["attrs"])
+        assert s["attrs"]["workers"] == 8  # the pool's size, on each
         assert "degraded" not in s["attrs"]
     context = {"window": window}
     cpu, wall = feed_row_cpu_us.read(context), feed_row_wall_us.read(context)
@@ -339,13 +343,23 @@ def test_a_degraded_run_shows_in_the_span_log(tracer, monkeypatch):
     ``collect`` span: a run that gained nothing from the pool can be told
     from a run that never had one."""
     monkeypatch.setenv("DPTPU_FAULT", "worker_hang@index=13")
-    monkeypatch.setenv("DPTPU_WORKER_TIMEOUT_S", "1")
     monkeypatch.setenv("DPTPU_POOL_RESTARTS", "0")
+    monkeypatch.delenv("DPTPU_WORKER_TIMEOUT_S", raising=False)
     ds = SyntheticDataset(32, 8, 10)
     loader = DataLoader(ds, 4, num_workers=2, seed=3,
                         workers_mode="process")
     try:
-        assert len(list(loader.epoch(0))) == 8
+        batches = loader.epoch(0)
+        # the pool's two interpreters start and import under the default
+        # watchdog (two minutes), and batch 0 says they deliver: on a
+        # loaded host that alone has taken over a second, and a watchdog
+        # of one second then broke the pool before it had made a batch.
+        # Only then is the watchdog drawn in to a second, counted from a
+        # warm pool; row 13, in batch 3, is where a worker hangs.
+        taken = [next(batches)]
+        loader._pipeline.timeout_s = 1.0
+        taken.extend(batches)
+        assert len(taken) == 8
         assert loader.feed_stats()["degraded"] is True
     finally:
         loader.close()

@@ -10,7 +10,8 @@ import os
 
 import pytest
 
-from dptpu.data.feed import feed_knobs, host_cores
+from dptpu.data import feed as feed_mod
+from dptpu.data.feed import feed_knobs, host_cores, pool_notice, pool_size
 from dptpu.envknob import env_axis, env_int
 
 
@@ -67,6 +68,105 @@ def test_an_explicit_workers_mode_wins_on_any_host(monkeypatch, cores, asked):
     _host_with(monkeypatch, cores)
     monkeypatch.setenv("DPTPU_WORKERS_MODE", asked)
     assert feed_knobs()[0] == asked
+
+
+@pytest.mark.parametrize("workers,chips,cores,mode,size,says", [
+    # one local chip: -j, whatever the cores (the parent's number)
+    (4, 1, 13, "process", 4, "workers=4 (4 a chip x 1 chip; 13 cores)"),
+    (4, 1, 3, "process", 4, "workers=4 (4 a chip x 1 chip; 3 cores)"),
+    (16, 1, 4, "process", 16, "workers=16 (16 a chip x 1 chip; 4 cores)"),
+    # the four-chip host: every chip keeps the four it has alone
+    (4, 4, 30, "process", 16, "workers=16 (4 a chip x 4 chips; 30 cores)"),
+    # ... as far as the host has cores beside the loop's
+    (4, 4, 8, "process", 6,
+     "workers=6 (4 a chip x 4 chips asks 16; 8 cores less 2 kept for the "
+     "loop)"),
+    (4, 8, 30, "process", 28,
+     "workers=28 (4 a chip x 8 chips asks 32; 30 cores less 2 kept for "
+     "the loop)"),
+    (8, 4, 30, "process", 28,
+     "workers=28 (8 a chip x 4 chips asks 32; 30 cores less 2 kept for "
+     "the loop)"),
+    # ... and never below the parent's ceil(j / chips) x chips
+    (4, 4, 3, "process", 4,
+     "workers=4 (the floor ceil(4 / 4) a chip x 4 chips; 3 cores)"),
+    (4, 8, 8, "process", 8,
+     "workers=8 (the floor ceil(4 / 8) a chip x 8 chips; 8 cores)"),
+    (6, 4, 9, "process", 8,
+     "workers=8 (the floor ceil(6 / 4) a chip x 4 chips; 9 cores)"),
+    (1, 4, 30, "process", 4, "workers=4 (1 a chip x 4 chips; 30 cores)"),
+    (1, 8, 4, "process", 8, "workers=8 (1 a chip x 8 chips; 4 cores)"),
+    (0, 4, 30, "process", 0, "workers=0 (0 a chip x 4 chips; 30 cores)"),
+    # pool threads share the loop's interpreter: the parent's count on
+    # any host (a host of two cores is thread mode by default)
+    (4, 4, 2, "thread", 4,
+     "workers=4 (thread mode keeps ceil(4 / 4) a chip x 4 chips)"),
+    (4, 4, 30, "thread", 4,
+     "workers=4 (thread mode keeps ceil(4 / 4) a chip x 4 chips)"),
+    (6, 4, 30, "thread", 8,
+     "workers=8 (thread mode keeps ceil(6 / 4) a chip x 4 chips)"),
+    (4, 1, 30, "thread", 4,
+     "workers=4 (thread mode keeps ceil(4 / 1) a chip x 1 chip)"),
+    (0, 4, 30, "thread", 0,
+     "workers=0 (thread mode keeps ceil(0 / 4) a chip x 4 chips)"),
+])
+def test_pool_size_is_j_a_chip_within_the_cores_and_over_the_floor(
+        workers, chips, cores, mode, size, says):
+    assert pool_size(workers, chips, cores, mode) == size
+    assert pool_notice(workers, chips, cores, mode) == says
+    # what the parent gave every host: never less, and exactly that
+    # with one local chip or in thread mode
+    parent = -(-workers // chips) * chips
+    assert size >= parent
+    if chips == 1 or mode == "thread":
+        assert size == parent
+    else:
+        assert size <= max(parent, workers * chips)
+
+
+def test_build_feed_gives_both_loaders_the_pool_and_says_so(monkeypatch):
+    """Through ``build_feed`` on the fake pod's eight devices: the train
+    pool (started) and the validation loader get ``pool_size``'s number,
+    the ``=> input pipeline:`` line says it with its inputs, and every
+    ``collect`` span carries it."""
+    import jax
+
+    from dptpu import obs
+    from dptpu.config import Config, derive
+
+    for k in ("DPTPU_WORKERS_MODE", "DPTPU_CACHE_BYTES",
+              "DPTPU_CACHE_SCOPE", "DPTPU_LEASE", "DPTPU_DECODE_AHEAD",
+              "DPTPU_RING_DEPTH", "DPTPU_LEASE_DEPTH"):
+        monkeypatch.delenv(k, raising=False)
+    # a host with room for -j a chip, whatever this machine has; the
+    # test says -j itself (1 a chip) so that the pool stays small
+    monkeypatch.setattr(feed_mod, "host_cores", lambda: 30)
+    chips = jax.local_device_count()
+    cfg = Config(data="synthetic:64", arch="resnet18", batch_size=2 * chips,
+                 workers=1, seed=1)
+    derived = derive(cfg, local_device_count=chips)
+    tracer = obs.set_tracer(obs.Tracer(capacity=1024))
+    feed = None
+    try:
+        feed = feed_mod.build_feed(cfg, derived, task="images",
+                                   model_config=None, image_size=8)
+        assert feed.workers_mode == "process"
+        assert feed.train_loader.num_workers == chips
+        assert feed.val_loader.num_workers == chips
+        assert (f"workers={chips} (1 a chip x {chips} chips; 30 cores)"
+                in feed.notices[0])
+        assert len(list(feed.train_loader.epoch(0))) == 64 // (2 * chips)
+        spans = tracer.drain()
+    finally:
+        obs.reset()
+        if feed is not None:
+            feed.train_loader.close()
+            feed.val_loader.close()
+    (start,) = [s for s in spans if s["name"] == "feed_start"]
+    assert start["attrs"]["workers"] == chips
+    collects = [s for s in spans if s["name"] == "collect"]
+    assert len(collects) == 64 // (2 * chips)
+    assert all(s["attrs"]["workers"] == chips for s in collects)
 
 
 def test_host_cores_without_an_affinity_call(monkeypatch):

@@ -292,6 +292,7 @@ def test_collect_and_h2d_carry_the_consuming_step(tracer):
     for s in collects:
         a = s["attrs"]
         assert a["rows"] == 4 and isinstance(a["ready"], bool)
+        assert a["workers"] == loader.num_workers
         assert 0.0 <= a["cpu_s"] <= a["wall_s"] + 0.0101 * 2
     row_bytes = 4 * 8 * 8 * 3 + 4 * 4  # uint8 images + int32 labels
     assert [s["attrs"]["bytes"] for s in h2ds] == [row_bytes] * 4
@@ -340,8 +341,9 @@ def test_process_mode_collect_reads_what_the_acks_carry(tracer):
         a = s["attrs"]
         # the workers' acks carry their own wall and CPU seconds: the
         # thread path's attributes exactly
-        assert set(a) == {"rows", "ready", "cpu_s", "wall_s"}
+        assert set(a) == {"rows", "ready", "workers", "cpu_s", "wall_s"}
         assert a["rows"] == 4 and isinstance(a["ready"], bool)
+        assert a["workers"] == 2  # the pool's size, beside what it made
         assert a["wall_s"] > 0.0 and 0.0 <= a["cpu_s"] <= a["wall_s"] + 1e-3
 
 

@@ -586,3 +586,26 @@ def test_affinity_off_still_bit_identical(jpeg_folder):
     finally:
         th.close()
         pr.close()
+
+
+def test_a_pools_size_leaves_the_bytes_alone(jpeg_folder):
+    """What lets ``build_feed`` size the pool by the host: process pools
+    of 1, 2 and 5 workers (5 divides neither the batch of 4 nor its
+    tail of 2) yield the same bytes for the same seed, the bytes of the
+    thread pool. A row's randomness is ``(seed, epoch, index)``, never
+    the worker that made it."""
+    ds = ImageFolderDataset(jpeg_folder, train_transform(48))  # 18 samples
+    th = DataLoader(ds, 4, num_workers=2, seed=11)
+    pools = [DataLoader(ds, 4, num_workers=n, seed=11,
+                        workers_mode="process") for n in (1, 2, 5)]
+    try:
+        for epoch in (0, 1):
+            want = list(th.epoch(epoch))
+            assert len(want) == 5
+            for pr in pools:
+                _assert_batches_equal(want, list(pr.epoch(epoch)))
+        assert [pr.feed_stats()["num_workers"] for pr in pools] == [1, 2, 5]
+    finally:
+        th.close()
+        for pr in pools:
+            pr.close()
