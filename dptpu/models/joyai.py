@@ -340,9 +340,9 @@ class Block(nn.Module):
             if self.dense:
                 x = x + SwiGLU(cfg.intermediate_size, self.dtype,
                                name="mlp")(normed)
-                sizes = jnp.zeros((0,), jnp.int32)
+                load = token_model.no_experts()
             else:
-                routed, sizes = SparseExperts(cfg, self.dtype,
+                routed, *load = SparseExperts(cfg, self.dtype,
                                               name="mlp")(normed)
                 shared = SwiGLU(
                     cfg.moe_intermediate_size * cfg.n_shared_experts,
@@ -351,7 +351,7 @@ class Block(nn.Module):
                 x = x + (shared + routed)
             if self.nextn:
                 x = norm("shared_head_norm")(x)
-        return x, sizes
+        return (x, *load)
 
 
 def shifted(x):
@@ -442,23 +442,23 @@ class Joyai(token_model.TokenModel):
             x = embed(tokens)
         kept = self.kept_on(tokens.shape)
         block = token_model.rematerialised(Block, kept)
-        counts = []
+        loads = []
         first, count = cfg.layers_here
         for i in range(first, first + count):
-            x, sizes = block(cfg, cfg.is_dense(i), self.dtype,
+            x, *load = block(cfg, cfg.is_dense(i), self.dtype,
                              name=f"layers_{i}")(x)
-            if sizes.shape[0]:
-                counts.append(sizes)
+            if not cfg.is_dense(i):
+                loads.append(load)
         nextn = None
         if cfg.num_nextn_predict_layers:
             # t_{i+1} is the label of position i
             following = shifted(tokens) if labels is None else labels
             with jax.named_scope("embed"):
                 after = embed(following)
-            nextn, sizes = block(cfg, False, self.dtype, True,
+            nextn, *load = block(cfg, False, self.dtype, True,
                                  name=f"layers_{cfg.num_hidden_layers}")(
                                      x, after)
-            counts.append(sizes)
+            loads.append(load)
         x = RMSNorm(cfg.rms_norm_eps, self.dtype, name="norm")(x)
         head = self.param("lm_head", token_model.dense_init,
                           (cfg.vocab_size, cfg.hidden_size))
@@ -481,8 +481,8 @@ class Joyai(token_model.TokenModel):
                 sums["loss_sum"] = sums["loss_sum"] \
                     + cfg.mtp_loss_weight * mtp
         return token_model.with_counters(
-            sums, counts,
-            tokens.size * cfg.num_experts_per_tok * len(counts), kept,
+            sums, loads,
+            tokens.size * cfg.num_experts_per_tok * len(loads), kept,
             cfg.attention_layers_here, attention_op.kernel_calls(
                 tokens.shape[1], cfg.num_attention_heads,
                 cfg.num_attention_heads, cfg.qk_head_dim, cfg.v_head_dim,

@@ -333,10 +333,10 @@ class Block(nn.Module):
         if self.dense:
             return x + token_model.SwiGLU(
                 cfg.intermediate_size, self.dtype,
-                name="feed_forward")(normed), jnp.zeros((0,), jnp.int32)
-        out, sizes = SparseExperts(cfg, self.dtype,
-                                   name="feed_forward")(normed)
-        return x + out, sizes
+                name="feed_forward")(normed), *token_model.no_experts()
+        out, sizes, compact = SparseExperts(cfg, self.dtype,
+                                            name="feed_forward")(normed)
+        return x + out, sizes, compact
 
 
 class Lfm2(token_model.TokenModel):
@@ -402,13 +402,13 @@ class Lfm2(token_model.TokenModel):
             x = embed(tokens)
         kept = self.kept_on(tokens.shape)
         block = token_model.rematerialised(Block, kept)
-        counts = []
+        loads = []
         for i, layer_type in enumerate(cfg.layer_types):
-            x, sizes = block(
+            x, *load = block(
                 cfg, layer_type, i < cfg.num_dense_layers, self.dtype,
                 name=f"layers_{i}")(x)
-            if sizes.shape[0]:
-                counts.append(sizes)
+            if i >= cfg.num_dense_layers:
+                loads.append(load)
         x = RMSNorm(cfg.norm_eps, self.dtype, name="embedding_norm")(x)
         with jax.named_scope("head"):
             if labels is None:
@@ -419,8 +419,8 @@ class Lfm2(token_model.TokenModel):
                 x.reshape(-1, cfg.hidden_size), embed.embedding,
                 labels.reshape(-1), mask.reshape(-1))
         return token_model.with_counters(
-            sums, counts,
-            tokens.size * cfg.num_experts_per_tok * len(counts), kept,
+            sums, loads,
+            tokens.size * cfg.num_experts_per_tok * len(loads), kept,
             sum(t == "full_attention" for t in cfg.layer_types),
             attention_op.kernel_calls(
                 tokens.shape[1], cfg.num_attention_heads,

@@ -10,8 +10,10 @@ pieces that are the same mathematics in more than one, each once:
 * ``RMSNorm``, ``SwiGLU``, the half-split ``rotary``;
 * the expert layer: ``route`` (the top k of score + bias, weighted by
   the scores themselves), ``held_expert_outputs`` (the part of the
-  result the experts held here give, no capacity, no dropped token) and
-  ``SparseExperts``, which reads what it needs from a ``Routing``;
+  result the experts held here give: a compact buffer for the slots
+  they hold, the worst-case buffer where a step's routing passes it, no
+  capacity, no dropped token) and ``SparseExperts``, which reads what
+  it needs from a ``Routing``;
 * ``Kept`` / ``keep_within``: which classes of named residuals a step
   keeps through the rematerialisation within a budget of bytes;
 * ``TokenModel``: the module both models extend (``example_input``,
@@ -29,6 +31,8 @@ rematerialised step takes on the device beside state and residuals).
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Any, Optional, Tuple
 
 import jax
@@ -149,50 +153,156 @@ def route(scores, bias, k: int, norm_topk: bool, scaling: float, *,
     return chosen, weights * scaling
 
 
-def held_expert_outputs(x, chosen, weights, w1, w3, w2, first: int):
-    """What the experts held here (``first .. first + len(w1)``) give for
-    the tokens routed to them: ``[tokens, hidden]`` in ``x``'s dtype, and
-    the tokens each of them got.
+# A held-expert buffer's rows over what uniform routing sends the held
+# experts (``tokens x k x held / experts``). A performance constant only:
+# a layer whose held slots pass the buffer in some step takes the
+# worst-case path for that step and loses nothing, so it trades rows that
+# the gathers, masks and products move every step against how often a
+# step pays the worst case. Measured on the chip (PERF.md section 6, PR
+# 42): at 8 of 32 experts held (LFM2's cell) the held total stays within
+# 3% of the uniform share and a layer costs 0.2 ms for each 1,024 rows of
+# buffer; at 8 of 256 (JoyAI's) the total of one layer in one step is
+# 0.4 to 1.5 times the share with a long tail: 1.5 let one layer step in
+# fourteen overflow and 2 one in eighty, and an overflow costs ten
+# compact layers. 2 is half and a sixteenth of the worst case's rows.
+CAP_OVER_UNIFORM = 2.0
+# the buffer is whole row tiles of the grouped product (the chip's kernel
+# walks the rows 512 at a time: ``ragged_dot_tiling="512,..."`` in the
+# program compiled for a v5e)
+ROW_TILE = 512
+# what the compact path keeps for its way back beside its inputs: the
+# gathered rows and the three grouped products. Everything else between
+# them is elementwise over ``[cap, *]`` and cheaper to compute again than
+# to write out through the ``cond`` and read back
+COMPACT_RESIDUALS = ("expert_rows", "expert_gate", "expert_up", "expert_out")
 
-    The ``tokens x k`` slots are sorted by expert, the slots of absent
-    experts behind all others; the held ones form one run per expert, and
-    three grouped matrix products (``lax.ragged_dot``) go over the runs.
-    The buffer is the worst case, every slot on a held expert, so no
-    capacity bounds a run and no token is dropped; rows behind the last
-    run belong to no group and cost the grouped product nothing. The
-    sorted rows go back to their tokens by the inverse
-    permutation and are summed by their weights.
-    """
-    tokens, k = chosen.shape
-    count = w1.shape[0]
+
+def held_row_cap(tokens: int, k: int, count: int, experts: int) -> int:
+    """The rows of the held experts' buffer: ``CAP_OVER_UNIFORM`` times
+    their share of the ``tokens x k`` slots under uniform routing, in
+    whole ``ROW_TILE``s, and never more than the worst case (every slot
+    on a held expert), which is what a layer that holds all its experts
+    gets."""
+    worst = tokens * k
+    share = CAP_OVER_UNIFORM * worst * count / experts
+    return min(worst, math.ceil(share / ROW_TILE) * ROW_TILE)
+
+
+def sorted_slots(chosen, first: int, count: int):
+    """The ``tokens x k`` slots of ``chosen`` sorted by expert (stable),
+    those of the experts held (``first .. first + count``) in one run
+    each before all others: the order, and the runs' sizes (the tokens
+    each held expert got)."""
     local = chosen - first
     key = jnp.where((local >= 0) & (local < count), local, count).reshape(-1)
-    order = jnp.argsort(key, stable=True)
     sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
                     dtype=jnp.int32)
-    # rows behind the last run are in no group: the grouped product on
-    # the chip leaves what it does not compute as it finds it (whatever
-    # the memory held), in the result and in the cotangents alike, so
-    # those rows are zeroed going in and coming out, which zeroes their
-    # cotangents too (a token's slot on an absent expert adds nothing to
-    # the token's gradient)
+    return jnp.argsort(key, stable=True), sizes
+
+
+def _grouped_swiglu(rows, in_a_run, w1, w3, w2, sizes):
+    """``W2 (silu(W1 x) * W3 x)`` of each run of ``rows`` under its own
+    expert's matrices: three grouped products (named, with the rows that
+    go in, for a rematerialisation that keeps them:
+    ``COMPACT_RESIDUALS``). Rows behind the last run are in no group: the
+    grouped product on the chip leaves what it does not compute as it
+    finds it (whatever the memory held), in the result and in the
+    cotangents alike, so those rows are zeroed going in and coming out,
+    which zeroes their cotangents too (a token's slot on an absent expert
+    adds nothing to the token's gradient)."""
+    rows = checkpoint_name(jnp.where(in_a_run, rows, 0), "expert_rows")
+    gate = checkpoint_name(lax.ragged_dot(rows, w1, sizes), "expert_gate")
+    up = checkpoint_name(lax.ragged_dot(rows, w3, sizes), "expert_up")
+    out = checkpoint_name(lax.ragged_dot(
+        jnp.where(in_a_run, nn.silu(gate) * up, 0), w2, sizes), "expert_out")
+    return jnp.where(in_a_run, out, 0)
+
+
+def worst_case_outputs(x, weights, w1, w3, w2, order, sizes):
+    """The held experts' part of the result over a buffer of all
+    ``tokens x k`` slots (``order``: the slots sorted by expert, the
+    held ones' runs first; ``sizes``: the runs): no capacity bounds a
+    run, and rows behind the last run cost the grouped product nothing,
+    but every gather, mask and product beside it moves all the rows. The
+    sorted rows go back to their tokens by the inverse permutation and
+    are summed by their weights."""
+    tokens, k = weights.shape
     in_a_run = jnp.arange(tokens * k)[:, None] < jnp.sum(sizes)
-    rows = jnp.where(in_a_run, x[order // k], 0)
-    hidden = nn.silu(lax.ragged_dot(rows, w1, sizes)) \
-        * lax.ragged_dot(rows, w3, sizes)
-    out = lax.ragged_dot(jnp.where(in_a_run, hidden, 0), w2, sizes)
-    out = jnp.where(in_a_run, out, 0)
+    out = _grouped_swiglu(x[order // k], in_a_run, w1, w3, w2, sizes)
     back = jnp.argsort(order)
     out = out[back].reshape(tokens, k, -1)
     mixed = jnp.einsum("tkh,tk->th", out, weights.astype(out.dtype),
                        preferred_element_type=jnp.float32)
-    return mixed.astype(x.dtype), sizes
+    return mixed.astype(x.dtype)
+
+
+def compact_outputs(x, weights, w1, w3, w2, order, sizes, cap: int):
+    """The same over the first ``cap`` sorted slots only, which is all
+    of the held ones' runs where ``sum(sizes) <= cap``: every array is
+    ``[cap, *]``. On the way back each row times its slot's weight is
+    added into its token (float32 sums, as the worst case's), so neither
+    the inverse permutation nor a ``[tokens x k, hidden]`` gather is
+    made; its transpose is a gather of ``cap`` rows, and ``x``'s
+    cotangent a scatter-add of ``cap`` rows."""
+    tokens, k = weights.shape
+    slot = order[:cap]
+    token = slot // k
+    in_a_run = jnp.arange(cap)[:, None] < jnp.sum(sizes)
+    out = _grouped_swiglu(x[token], in_a_run, w1, w3, w2, sizes)
+    weight = weights.reshape(-1)[slot].astype(out.dtype)
+    mixed = jnp.zeros((tokens, x.shape[-1]), jnp.float32).at[token].add(
+        out.astype(jnp.float32) * weight.astype(jnp.float32)[:, None])
+    return mixed.astype(x.dtype)
+
+
+def held_expert_outputs(x, chosen, weights, w1, w3, w2, first: int,
+                        experts: int):
+    """What the experts held here (``first .. first + len(w1)`` of
+    ``experts``) give for the tokens routed to them: ``[tokens, hidden]``
+    in ``x``'s dtype, the tokens each of them got, and whether the
+    compact buffer held them (an int32 0 or 1).
+
+    The ``tokens x k`` slots are sorted by expert, the slots of absent
+    experts behind all others; the held ones form one run per expert, and
+    three grouped matrix products (``lax.ragged_dot``) go over the runs.
+    The rows that go through them are a buffer of ``held_row_cap`` rows,
+    a static function of the shapes (``compact_outputs``); a step whose
+    routing puts more slots than that on the held experts takes
+    ``worst_case_outputs`` instead, under a ``lax.cond`` on this step's
+    ``sum(sizes)``: every slot on a held expert fits there, so no
+    capacity bounds a run and no token is dropped on either path. Both
+    branches are rematerialised: under reverse mode a ``cond`` returns the
+    union of its branches' residuals, and the compact branch would
+    otherwise write every worst-case residual (as zeros). The fallback's
+    residuals are its inputs, so only a step that overflows pays its
+    forward again; the compact branch keeps ``COMPACT_RESIDUALS`` beside
+    its inputs and computes the elementwise steps between them again. A
+    layer that holds all its experts has no smaller buffer than the worst
+    case and no ``cond``.
+    """
+    tokens, k = chosen.shape
+    count = w1.shape[0]
+    order, sizes = sorted_slots(chosen, first, count)
+    cap = held_row_cap(tokens, k, count, experts)
+    operands = (x, weights, w1, w3, w2, order, sizes)
+    if cap == tokens * k:
+        return worst_case_outputs(*operands), sizes, jnp.ones((), jnp.int32)
+    fits = jnp.sum(sizes) <= cap
+    compact = jax.checkpoint(
+        functools.partial(compact_outputs, cap=cap),
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *COMPACT_RESIDUALS))
+    out = lax.cond(fits, compact, jax.checkpoint(worst_case_outputs),
+                   *operands)
+    return out, sizes, fits.astype(jnp.int32)
 
 
 class SparseExperts(nn.Module):
     """The expert layer: routes over all experts, computes the held
     ones' part. ``config`` is a model's configuration; the layer reads
-    its ``routing`` (a ``Routing``)."""
+    its ``routing`` (a ``Routing``). Gives the part, the tokens each
+    held expert got and whether the compact buffer held them
+    (``held_expert_outputs``)."""
 
     config: Any
     dtype: Any = jnp.float32
@@ -220,9 +330,9 @@ class SparseExperts(nn.Module):
                 jnp.stack(ws).astype(self.dtype) for ws in zip(*(
                     _Expert(hidden, r.width, name=f"experts_{first + e}")()
                     for e in range(count))))
-            out, sizes = held_expert_outputs(
-                flat, chosen, weights, w1, w3, w2, first)
-        return out.reshape(x.shape), sizes
+            out, sizes, compact = held_expert_outputs(
+                flat, chosen, weights, w1, w3, w2, first, r.experts)
+        return out.reshape(x.shape), sizes, compact
 
 
 # --------------------------------- residuals kept through rematerialisation --
@@ -277,7 +387,13 @@ class TokenModel(nn.Module):
     held]`` int32, the tokens each held expert got; ``moe_slots``, the
     slots routed in all (tokens x k x expert layers, held or not);
     ``moe_dropped``, the tokens dropped: a constant 0, there for the day
-    a capacity scheme moves it; ``kept_residual_mb``, the megabytes this
+    a capacity scheme moves it (the compact buffer of
+    ``held_expert_outputs`` is none: a layer whose held slots pass it
+    takes the worst-case buffer for that step, which holds every slot);
+    ``moe_compact``, the expert layers whose held slots fitted the compact
+    buffer in this step, of ``moe_layers`` expert layers (int32; the
+    first moves with the routing, the second is a constant);
+    ``kept_residual_mb``, the megabytes this
     step's blocks keep through the rematerialisation: a constant of the
     traced program; ``attention_calls`` and ``attention_kernel_calls``,
     the calls of ``dptpu.ops.attention`` in one forward pass and those of
@@ -344,16 +460,26 @@ def rematerialised(block, kept: Kept):
     return nn.remat(block, policy=policy)
 
 
-def with_counters(sums: dict, counts, slots: int, kept: Kept,
+def no_experts():
+    """What a block without an expert layer gives where ``SparseExperts``
+    gives its load: no sizes, no buffer."""
+    return jnp.zeros((0,), jnp.int32), jnp.zeros((), jnp.int32)
+
+
+def with_counters(sums: dict, loads, slots: int, kept: Kept,
                   attention_calls: int, on_kernel) -> dict:
-    """``sums`` with the expert layers' load (``counts``: one ``[experts
-    held]`` array per expert layer; ``slots``: the slots routed in all),
-    the megabytes kept and the attention's calls (``on_kernel``:
+    """``sums`` with the expert layers' load (``loads``: what
+    ``SparseExperts`` gave beside its output, one ``(sizes, compact)``
+    per expert layer; ``slots``: the slots routed in all), the megabytes
+    kept and the attention's calls (``on_kernel``:
     ``attention.kernel_calls`` of one of them, all being of one shape)."""
-    if counts:
+    if loads:
+        counts, compact = zip(*loads)
         sums["moe_counts"] = jnp.stack(counts)
         sums["moe_slots"] = jnp.asarray(slots, jnp.int32)
         sums["moe_dropped"] = jnp.zeros((), jnp.int32)
+        sums["moe_compact"] = sum(compact)
+        sums["moe_layers"] = jnp.asarray(len(loads), jnp.int32)
     sums["kept_residual_mb"] = jnp.asarray(kept.megabytes, jnp.int32)
     sums["attention_calls"] = jnp.asarray(attention_calls, jnp.int32)
     sums["attention_kernel_calls"] = attention_calls * on_kernel

@@ -237,6 +237,7 @@ def _epoch_scalars(train_stats, val_stats, obs_report, *, train_batch,
         ("Moe/load_mean", "moe_load_mean"),
         ("Moe/local_slot_share", "moe_local_slot_share"),
         ("Moe/dropped_tokens", "moe_dropped"),
+        ("Moe/compact_share", "moe_compact_share"),
         # a model with a second loss term: it alone, before its weight
         ("Loss/train_mtp", "mtp_loss"),
     ):
@@ -1429,8 +1430,9 @@ def _fit(cfg: Config, *, image_size: int, verbose: Optional[bool]):
                     "Moe: busiest held expert {moe_load_max:.1f} tokens a "
                     "layer a step (mean {moe_load_mean:.1f}), "
                     "{moe_local_slot_share:.2f}% of the routed slots on "
-                    "held experts, {moe_dropped:.0f} tokens "
-                    "dropped".format(**train_stats)
+                    "held experts ({moe_compact_share:.1f}% of the layer "
+                    "steps in the compact buffer), {moe_dropped:.0f} "
+                    "tokens dropped".format(**train_stats)
                 )
             if verbose and "mtp_loss" in train_stats:
                 print("Mtp: multi-token-prediction loss {mtp_loss:.4f} "

@@ -48,9 +48,13 @@ MAX_IN_FLIGHT = 2
 class MoeLoad:
     """The expert layers' load over the steps fetched so far, for a
     model whose step reports it (``moe_counts`` ``[layers, experts
-    held]``, ``moe_slots``, ``moe_dropped``): per step and layer the
-    tokens at the busiest held expert and at the mean one, the routed
-    slots that fell on held experts, the tokens dropped. ``take`` gives
+    held]``, ``moe_slots``, ``moe_dropped``, ``moe_compact``,
+    ``moe_layers``): per step and layer the tokens at the busiest held
+    expert and at the mean one, the routed slots that fell on held
+    experts, the tokens dropped, and of the expert layers run
+    (``moe_layers``, a layer counted once a step) those whose held slots
+    fitted the compact buffer (``moe_compact_layers``; the others took
+    the worst-case path for that step). ``take`` gives
     what one fetch adds as the ``fetch`` span's attributes; ``stats``
     the epoch's averages. The step program's constants
     (``STEP_CONSTANTS``) are passed on as they are."""
@@ -67,6 +71,7 @@ class MoeLoad:
         self.steps = 0
         self.load_max = self.load_mean = 0.0
         self.local = self.slots = self.dropped = 0
+        self.compact = self.layers = 0
 
     def take(self, fetched) -> dict:
         """``fetched``: the metrics of the steps one fetch read. Empty
@@ -85,6 +90,9 @@ class MoeLoad:
             "moe_local_slots": int(sum(c.sum() for c in counts)),
             "moe_slots": int(sum(int(m["moe_slots"]) for m in steps)),
             "moe_dropped": int(sum(int(m["moe_dropped"]) for m in steps)),
+            "moe_compact_layers": int(sum(int(m["moe_compact"])
+                                          for m in steps)),
+            "moe_layers": int(sum(int(m["moe_layers"]) for m in steps)),
         }
         n = len(steps)
         self.steps += n
@@ -93,6 +101,8 @@ class MoeLoad:
         self.local += add["moe_local_slots"]
         self.slots += add["moe_slots"]
         self.dropped += add["moe_dropped"]
+        self.compact += add["moe_compact_layers"]
+        self.layers += add["moe_layers"]
         return add
 
     def stats(self) -> dict:
@@ -103,6 +113,7 @@ class MoeLoad:
             "moe_load_mean": self.load_mean / self.steps,
             "moe_local_slot_share": 100.0 * self.local / max(self.slots, 1),
             "moe_dropped": float(self.dropped),
+            "moe_compact_share": 100.0 * self.compact / max(self.layers, 1),
         }
 
 

@@ -46,6 +46,9 @@ _FAST_MODULES = {
     # benchmark's copy with ONE fit()-driven case at the same size (train,
     # validate, resume), and the benchmark's seam cases (11 s)
     "test_lfm2", "test_tokens_feed", "test_benchmark_seams",
+    # the expert layer's compact buffer against its worst-case path
+    # (ISSUE 42): 1,024 tokens at hidden 16, a dozen small jits, 20 s
+    "test_expert_buffer",
     # a second token model (ISSUE 36): JoyAI-LLM-Flash against its plain
     # reference at hidden 64, ONE case through main_apex (two fit() runs
     # of 16 steps at 32 tokens); under a minute of one worker

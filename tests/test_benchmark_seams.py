@@ -23,7 +23,10 @@ cell has its own case, and its reader's (``test_ssd_kernel_share.py``)
 and its limits' (``test_granite_limits.py``) run here too. PR 41 appended
 six metrics that read the run's set-up account off the ``fetch`` spans:
 their readers' cases (``test_setup_account.py``, every name with ``setup``
-in it) run here too, all but the one that makes the CPU rehearsal's run."""
+in it) run here too, all but the one that makes the CPU rehearsal's run.
+PR 42 appended ``expert_compact_share`` (the expert layers' runs that
+fitted the compact buffer): its reader's cases
+(``test_expert_compact_share.py``) run here too."""
 
 import json
 import os
@@ -69,6 +72,8 @@ from benchmark.tests.test_setup_account import (  # noqa: F401,E402
     test_the_setup_cache_share_is_hits_over_answers,
     test_the_six_setup_metrics_are_listed_together_for_every_cell,
 )
+# the reader of the expert layers' share in the compact buffer (11 cases)
+from benchmark.tests.test_expert_compact_share import *  # noqa: F401,F403,E402
 
 CELL = "lfm2moe-fit-8k-1chip"
 JOYAI_CELL = "joyai-fit-8k-1chip"

@@ -540,6 +540,7 @@ def test_main_apex_trains_a_share_of_it_through_fit_and_resumes(
         # how a row was walked is the step's to say (``ssd_chunks`` in
         # its sums, held above); no reader wanted it on the span
         and "ssd_chunks" not in a and "moe_slots" not in a
+        and "moe_compact_layers" not in a and "moe_layers" not in a
         for a in carrying)
     saves = [s["attrs"] for s in spans if s["name"] == "ckpt"
              and "bytes" in s.get("attrs", {})]

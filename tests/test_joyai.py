@@ -167,6 +167,9 @@ def test_loss_every_gradient_leaf_and_three_adamw_steps_match_the_reference(
     assert metrics["moe_counts"].shape == (2 if share else 3,
                                            config.experts_here[1])
     assert int(metrics["moe_dropped"]) == 0
+    # every expert layer ran in its buffer (at this size the worst case's)
+    assert int(metrics["moe_compact"]) == int(metrics["moe_layers"]) \
+        == (2 if share else 3)
     checked, idle = 0, []
     for key, (collection, names, kind) in torch_key_map(
             ARCH, variables).items():
@@ -230,7 +233,7 @@ def test_the_shares_add_up_to_the_uncut_layer_the_shared_expert_once():
             params = {"gate": whole["mlp"]["gate"],
                       **{f"experts_{e}": whole["mlp"][f"experts_{e}"]
                          for e in range(first, first + 2)}}
-            out, sizes = token_model.SparseExperts(
+            out, sizes, _ = token_model.SparseExperts(
                 TINY.held(experts=(first, 2))).apply(
                     {"params": params, "batch_stats": stats}, x)
             total = total + out
@@ -633,6 +636,7 @@ def test_main_apex_trains_a_share_of_it_through_fit(tmp_path, monkeypatch,
         1.1 * epoch["train_mtp_loss"], rel=0.1)
     assert np.isfinite(epoch["val_loss"])
     assert epoch["train_moe_dropped"] == 0
+    assert epoch["train_moe_compact_share"] == 100.0
     assert 40 < epoch["train_moe_local_slot_share"] < 60  # 4 of 8 held
     params = result["state"].params
     assert {k for k in params if k.startswith("layers_")} == {
@@ -649,4 +653,5 @@ def test_main_apex_trains_a_share_of_it_through_fit(tmp_path, monkeypatch,
                 if "mtp_loss" in r.get("attrs", {})]
     assert carrying and all(
         a["loss"] > a["mtp_loss"] > 0 and a["moe_dropped"] == 0
+        and a["moe_compact_layers"] == a["moe_layers"] > 0
         and a["kept_residual_mb"] == 0 for a in carrying)

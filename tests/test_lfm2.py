@@ -24,7 +24,7 @@ import pytest
 from benchmark.reference import common as reference_common
 from benchmark.reference import lfm2_moe as reference
 from benchmark.reference.optimizers import adamw as reference_adamw
-from dptpu.models import lfm2
+from dptpu.models import lfm2, token_model
 from dptpu.models.pretrained import (
     _to_torch,
     convert_state_dict,
@@ -180,6 +180,7 @@ def test_three_adamw_steps_match_the_reference():
                 reference_adamw.program_trace1(state.opt_state))
     assert losses == pytest.approx(want["loss"], abs=1e-5)
     assert int(metrics["moe_dropped"]) == 0
+    assert int(metrics["moe_compact"]) == int(metrics["moe_layers"]) == 2
     for key, (collection, names, kind) in torch_key_map(
             ARCH, variables).items():
         leaf = lambda tree: _to_torch(np.asarray(functools.reduce(  # noqa: E731
@@ -246,10 +247,13 @@ def test_four_shares_of_the_experts_add_up_to_the_uncut_layer():
         params = {"gate": whole["gate"],
                   **{f"experts_{e}": whole[f"experts_{e}"]
                      for e in range(first, first + 2)}}
-        out, sizes = lfm2.SparseExperts(config).apply(
+        out, sizes, compact = lfm2.SparseExperts(config).apply(
             {"params": params, "batch_stats": stats}, jnp.asarray(x))
         total = total + np.asarray(out)
         counts += list(np.asarray(sizes))
+        # a quarter of the experts got a quarter of the slots, give or
+        # take: the compact buffer (two quarters) held them
+        assert int(compact) == 1
     np.testing.assert_allclose(total, want, atol=2e-6)
     # every slot of every token is on exactly one chip's experts
     assert sum(counts) == x.shape[0] * x.shape[1] * 2
@@ -291,21 +295,40 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_experts():
     x = _layer_input(TINY)
     tokens = x.shape[0] * x.shape[1]
     whole = variables["params"]["layers_3"]["feed_forward"]
-    out, sizes = lfm2.SparseExperts(TINY).apply(
-        {"params": whole, "batch_stats": {"expert_bias": jnp.asarray(bias)}},
-        jnp.asarray(x))
+    stats = {"expert_bias": jnp.asarray(bias)}
+    out, sizes, compact = lfm2.SparseExperts(TINY).apply(
+        {"params": whole, "batch_stats": stats}, jnp.asarray(x))
     assert list(np.asarray(sizes)) == [tokens, tokens, 0, 0, 0, 0, 0, 0]
     want = np.stack([np.asarray(reference.expert_layer(
         model, weights, f, row, "f32")) for row in x])
     np.testing.assert_allclose(np.asarray(out), want, atol=2e-6)
+    # a layer that holds all its experts has one buffer, the worst case
+    assert int(compact) == 1 and token_model.held_row_cap(
+        tokens, 2, 8, 8) == 2 * tokens
+
+    def share(first, count, x):
+        return lfm2.SparseExperts(TINY.held(experts=(first, count))).apply(
+            {"params": {"gate": whole["gate"],
+                        **{f"experts_{e}": whole[f"experts_{e}"]
+                           for e in range(first, first + count)}},
+             "batch_stats": stats}, jnp.asarray(x))
+
+    # the chip that holds just those two gets every slot, four times its
+    # even share and all its worst case: no compact buffer holds that (on
+    # rows enough for one to exist: a buffer is whole row tiles), the
+    # fallback was the path taken, and the result is the uncut layer's
+    many = np.concatenate([_layer_input(TINY, seed) for seed in range(8)])
+    tokens = many.shape[0] * many.shape[1]
+    assert token_model.held_row_cap(tokens, 2, 2, 8) == tokens < 2 * tokens
+    out, sizes, compact = share(0, 2, many)
+    assert int(compact) == 0 and list(np.asarray(sizes)) == [tokens, tokens]
+    want = np.stack([np.asarray(reference.expert_layer(
+        model, weights, f, row, "f32")) for row in many])
+    np.testing.assert_allclose(np.asarray(out), want, atol=2e-6)
     # and a share that holds neither of them computes nothing, drops nothing
-    config = TINY.held(experts=(4, 4))
-    out, sizes = lfm2.SparseExperts(config).apply(
-        {"params": {"gate": whole["gate"],
-                    **{f"experts_{e}": whole[f"experts_{e}"]
-                       for e in range(4, 8)}},
-         "batch_stats": {"expert_bias": jnp.asarray(bias)}}, jnp.asarray(x))
+    out, sizes, compact = share(4, 4, many)
     assert not np.asarray(sizes).any() and not np.asarray(out).any()
+    assert int(compact) == 1
 
 
 def test_the_selection_takes_the_bias_and_the_weights_do_not():
@@ -585,14 +608,20 @@ def test_the_loop_passes_the_megabytes_kept_on_as_they_are(experts):
     step = {"loss": 1.0, "kept_residual_mb": np.int32(1793)}
     if experts:
         step.update(moe_counts=np.full((4, 8), 2048), moe_slots=262144,
-                    moe_dropped=0)
+                    moe_dropped=0, moe_compact=np.int32(3),
+                    moe_layers=np.int32(4))
     load = MoeLoad()
     attrs = load.take([step] * 3)
     assert attrs["kept_residual_mb"] == 1793
     assert type(attrs["kept_residual_mb"]) is int
-    assert ("moe_slots" in attrs) is experts
+    for name in ("moe_slots", "moe_compact_layers", "moe_layers"):
+        assert (name in attrs) is experts
     if experts:
         assert attrs["moe_slots"] == 3 * 262144  # a sum over the steps
+        # one layer of the four overflowed in each step
+        assert (attrs["moe_compact_layers"], attrs["moe_layers"]) == (9, 12)
+        assert type(attrs["moe_compact_layers"]) is int
+        assert load.stats()["moe_compact_share"] == 75.0
     assert "kept_residual_mb" not in load.stats()
     # a model that counts neither, and a fetch that read nothing
     assert load.take([{"loss": 1.0}]) == {} and load.take([]) == {}

@@ -129,8 +129,10 @@ def test_fit_trains_validates_and_resumes_a_token_model(
     assert np.isfinite(epoch["val_loss"]) and 0 <= epoch["val_top1"] <= 100
     assert epoch["val_count"] == pytest.approx(6 * 32, abs=6 * 2)  # tokens
     assert "Moe: busiest held expert" in out and "0 tokens dropped" in out
+    assert "(100.0% of the layer steps in the compact buffer)" in out
     assert 40 < epoch["train_moe_local_slot_share"] < 60  # 4 of 8 held
     assert epoch["train_moe_dropped"] == 0
+    assert epoch["train_moe_compact_share"] == 100.0
     # one epoch, saved; resumed for the second: the loss goes on as in the
     # run that was never stopped (parameters, both moments, the step)
     main_apex(["tokens:64", *_ARGS, "--epochs", "1", "--ckpt-dir", ckpt])

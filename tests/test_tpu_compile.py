@@ -1,10 +1,11 @@
 """The token step's two heaviest pieces compiled for the real chip at the
 cell's real widths, without a chip (the TPU's compiler is installed and
 compiles for a described v5e): the expert layer's grouped products over
-the worst-case buffer (``lax.ragged_dot`` lowers to the chip's own kernel
-there, not to the CPU's dense fallback) and the blockwise attention,
-each forward and backward. Lowered for the chip the attention is its two
-Pallas kernels (``tpu_custom_call``), chosen by the platform the program
+the compact buffer and, under the same conditional, over the worst-case
+one (``lax.ragged_dot`` lowers to the chip's own kernel there, not to
+the CPU's dense fallback), at both expert cells' shapes, and the
+blockwise attention, each forward and backward. Lowered for the chip
+the attention is its two Pallas kernels (``tpu_custom_call``), chosen by the platform the program
 is lowered for and not by the process's backend (this one's is the CPU):
 no ``while`` with the scan's float32 carries is left. What the chip's
 compiler would refuse (a shape it cannot tile, more VMEM than a kernel
@@ -15,12 +16,14 @@ The topology is described inside a fixture, never at import: one process
 at a time may load the TPU's library, and a worker that cannot skips.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from dptpu.models import lfm2
+from dptpu.models import lfm2, token_model
 from dptpu.ops.attention import causal_attention
 
 TOKENS, HIDDEN, WIDTH, HELD, TOP_K = 16384, 2048, 1792, 8, 4
@@ -56,24 +59,90 @@ def _shape(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_the_expert_layer_compiles_for_the_chip_at_the_cells_widths(one_chip):
-    def loss(x, w1, w3, w2, chosen, weights):
-        out, sizes = lfm2.held_expert_outputs(x, chosen, weights, w1, w3, w2,
-                                              first=0)
-        return jnp.sum(out.astype(jnp.float32)), sizes
+# tokens, experts a token, experts, held, expert width; the compact
+# buffer's rows; the bytes of temporaries the layer's forward and backward
+# take alone (the parent's worst-case program took 1.24 and 0.84 GB)
+EXPERT_CELLS = {
+    "lfm2moe-fit-8k-1chip": (TOKENS, TOP_K, 32, HELD, WIDTH, 32768, 2.58e9),
+    "joyai-fit-8k-1chip": (8192, 8, 256, HELD, 768, 4096, 1.28e9),
+}
 
-    args = (_shape((TOKENS, HIDDEN), jnp.bfloat16, one_chip),
-            _shape((HELD, HIDDEN, WIDTH), jnp.bfloat16, one_chip),
-            _shape((HELD, HIDDEN, WIDTH), jnp.bfloat16, one_chip),
-            _shape((HELD, WIDTH, HIDDEN), jnp.bfloat16, one_chip),
-            _shape((TOKENS, TOP_K), jnp.int32, one_chip),
-            _shape((TOKENS, TOP_K), jnp.float32, one_chip))
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3),
+
+def _computations(text: str) -> dict:
+    """A compiled program's computations by name, each as its lines."""
+    out, name = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            out[name.lstrip("%")] = []
+        elif name is not None:
+            out[name.lstrip("%")].append(line)
+    return out
+
+
+def _reached(computations: dict, root: str) -> list:
+    """The lines of ``root`` and of every computation it calls."""
+    seen, todo, lines = set(), [root], []
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in computations:
+            continue
+        seen.add(name)
+        lines += computations[name]
+        for line in computations[name]:
+            todo += re.findall(r"%([\w.\-]+)", line.split(" = ", 1)[-1])
+    return lines
+
+
+@pytest.mark.parametrize("cell", EXPERT_CELLS)
+def test_the_expert_layer_compiles_for_the_chip_at_the_cells_widths(
+        one_chip, cell):
+    tokens, top_k, experts, held, width, cap, temp = EXPERT_CELLS[cell]
+    assert token_model.held_row_cap(tokens, top_k, held, experts) == cap
+
+    def loss(x, w1, w3, w2, chosen, weights):
+        out, sizes, compact = token_model.held_expert_outputs(
+            x, chosen, weights, w1, w3, w2, 0, experts)
+        return jnp.sum(out.astype(jnp.float32) ** 2), (sizes, compact)
+
+    args = (_shape((tokens, HIDDEN), jnp.bfloat16, one_chip),
+            _shape((held, HIDDEN, width), jnp.bfloat16, one_chip),
+            _shape((held, HIDDEN, width), jnp.bfloat16, one_chip),
+            _shape((held, width, HIDDEN), jnp.bfloat16, one_chip),
+            _shape((tokens, top_k), jnp.int32, one_chip),
+            _shape((tokens, top_k), jnp.float32, one_chip))
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 5),
                                 has_aux=True)).lower(*args).compile()
     text = compiled.as_text()
-    # the chip's grouped-product kernel, forward and both backward forms
-    assert text.count("ragged-dot") >= 9 and "tpu_custom_call" in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 8e9
+    # the chip's grouped-product kernel, forward and both backward forms,
+    # on the compact rows and on the fallback's
+    assert text.count("ragged-dot") >= 18 and "tpu_custom_call" in text
+    # one conditional each way, and the branch a step takes while its held
+    # slots fit moves no array of all 65,536 slots wider than an int32
+    # column (the sort's order and what indexes it): every gather, mask
+    # and product is [cap, *]
+    computations = _computations(text)
+    conditionals = [line for lines in computations.values()
+                    for line in lines if " conditional(" in line]
+    assert len(conditionals) == 2
+    worst_rows = re.compile(rf"\w+\[{tokens * top_k},\d+")
+    for line in conditionals:
+        branches = re.search(
+            r"branch_computations=\{%([\w.\-]+), %([\w.\-]+)\}", line)
+        # a cond on a boolean: the false branch (the fallback) comes first
+        fallback, compact = (_reached(computations, name)
+                             for name in branches.groups())
+        assert any(worst_rows.search(ln) for ln in fallback)
+        wide = [ln for ln in compact if worst_rows.search(ln)]
+        assert not wide, wide[:3]
+        assert any(f"[{cap},{HIDDEN}]" in ln for ln in compact)
+    # the chosen form, both branches rematerialised, the compact one
+    # keeping its rows and grouped products: those beside the fallback's
+    # inputs. At a buffer of 1.5 shares it took 2.33 and 1.26 GB, 3.47 and
+    # 1.34 with the compact branch kept whole, and a plain cond's union of
+    # residuals 5.24 and 3.11 (PERF.md section 6, PR 42)
+    assert 0.9 * temp < compiled.memory_analysis().temp_size_in_bytes \
+        < 1.1 * temp
 
 
 def _attention_calls(text: str):
@@ -155,8 +224,8 @@ def test_a_rematerialised_attention_block_keeps_out_and_lse_on_the_chip(
         jax.eval_shape(block.init, jax.random.PRNGKey(0), x))
 
     def loss(params, buffers, x):
-        out, sizes = block.apply({"params": params, **buffers}, x)
-        return jnp.sum(out.astype(jnp.float32)), sizes
+        out, *load = block.apply({"params": params, **buffers}, x)
+        return jnp.sum(out.astype(jnp.float32)), load
 
     params = variables.pop("params")
     compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
