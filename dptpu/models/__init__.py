@@ -23,6 +23,7 @@ from dptpu.models import resnet as _resnet  # noqa: F401
 from dptpu.models import shufflenet as _shufflenet  # noqa: F401
 from dptpu.models import squeezenet as _squeezenet  # noqa: F401
 from dptpu.models import swin as _swin  # noqa: F401
+from dptpu.models import trinity as _trinity  # noqa: F401
 from dptpu.models import vgg as _vgg  # noqa: F401
 from dptpu.models import vit as _vit  # noqa: F401
 from dptpu.models.registry import (

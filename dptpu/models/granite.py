@@ -426,8 +426,8 @@ class Granite(token_model.TokenModel):
                 x.reshape(-1, cfg.hidden_size), embed.embedding,
                 labels.reshape(-1), mask.reshape(-1))
         sums = token_model.with_counters(
-            sums, [], 0, kept, cfg.count_here("attention"),
-            attention_op.kernel_calls(
+            sums, [], 0, kept, (None,) * cfg.count_here("attention"),
+            tokens.shape[1], attention_op.kernel_calls(
                 tokens.shape[1], cfg.num_attention_heads,
                 cfg.num_key_value_heads, cfg.head_dim, cfg.head_dim,
                 self.dtype))

@@ -483,7 +483,8 @@ class Joyai(token_model.TokenModel):
         return token_model.with_counters(
             sums, loads,
             tokens.size * cfg.num_experts_per_tok * len(loads), kept,
-            cfg.attention_layers_here, attention_op.kernel_calls(
+            (None,) * cfg.attention_layers_here, tokens.shape[1],
+            attention_op.kernel_calls(
                 tokens.shape[1], cfg.num_attention_heads,
                 cfg.num_attention_heads, cfg.qk_head_dim, cfg.v_head_dim,
                 self.dtype))

@@ -421,8 +421,8 @@ class Lfm2(token_model.TokenModel):
         return token_model.with_counters(
             sums, loads,
             tokens.size * cfg.num_experts_per_tok * len(loads), kept,
-            sum(t == "full_attention" for t in cfg.layer_types),
-            attention_op.kernel_calls(
+            (None,) * sum(t == "full_attention" for t in cfg.layer_types),
+            tokens.shape[1], attention_op.kernel_calls(
                 tokens.shape[1], cfg.num_attention_heads,
                 cfg.num_key_value_heads, cfg.head_dim, cfg.head_dim,
                 self.dtype))

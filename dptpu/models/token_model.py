@@ -42,6 +42,8 @@ from flax import linen as nn
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from dptpu.ops import attention as attention_op
+
 dense_init = nn.initializers.normal(0.02)
 
 
@@ -398,7 +400,10 @@ class TokenModel(nn.Module):
     traced program; ``attention_calls`` and ``attention_kernel_calls``,
     the calls of ``dptpu.ops.attention`` in one forward pass and those of
     them that take its kernels in the program being lowered (all on a
-    TPU where the shapes tile, none elsewhere): constants of the lowered
+    TPU where the shapes tile, none elsewhere), ``attention_window_calls``,
+    those with a window shorter than the row, and ``attention_tiles`` of
+    ``attention_tiles_causal``, the key tiles they walk of what the
+    causal triangle holds (``with_counters``): constants of the lowered
     program. A model with state-space layers adds ``ssd_calls``,
     ``ssd_kernel_calls`` and ``ssd_chunks`` (``with_scan_counters``); a
     model without experts carries none of the ``moe_`` sums.
@@ -466,13 +471,23 @@ def no_experts():
     return jnp.zeros((0,), jnp.int32), jnp.zeros((), jnp.int32)
 
 
-def with_counters(sums: dict, loads, slots: int, kept: Kept,
-                  attention_calls: int, on_kernel) -> dict:
+def with_counters(sums: dict, loads, slots: int, kept: Kept, windows,
+                  length: int, on_kernel) -> dict:
     """``sums`` with the expert layers' load (``loads``: what
     ``SparseExperts`` gave beside its output, one ``(sizes, compact)``
     per expert layer; ``slots``: the slots routed in all), the megabytes
-    kept and the attention's calls (``on_kernel``:
-    ``attention.kernel_calls`` of one of them, all being of one shape)."""
+    kept and the attention's calls in one forward pass on rows of
+    ``length`` tokens. ``windows`` has one entry a call: its window, None
+    for a causal one. A model's calls are of one shape and may be of two
+    KINDS: a windowed call walks a band of the tiles a causal one walks
+    (``attention.tiles_walked``), so the tiles are counted call by call
+    (``attention_tiles``, of one row under one key/value head, beside
+    what the causal triangle would walk, ``attention_tiles_causal``: a
+    model without windows reports the two equal); which calls take the
+    kernels is a rule on the shapes alone (``attention.kernel_blocks``
+    does not read the window), so ``on_kernel``,
+    ``attention.kernel_calls`` of one of them, answers for all. A rule
+    that read the window would be asked once a kind."""
     if loads:
         counts, compact = zip(*loads)
         sums["moe_counts"] = jnp.stack(counts)
@@ -481,8 +496,17 @@ def with_counters(sums: dict, loads, slots: int, kept: Kept,
         sums["moe_compact"] = sum(compact)
         sums["moe_layers"] = jnp.asarray(len(loads), jnp.int32)
     sums["kept_residual_mb"] = jnp.asarray(kept.megabytes, jnp.int32)
-    sums["attention_calls"] = jnp.asarray(attention_calls, jnp.int32)
-    sums["attention_kernel_calls"] = attention_calls * on_kernel
+    calls = len(windows)
+    sums["attention_calls"] = jnp.asarray(calls, jnp.int32)
+    sums["attention_kernel_calls"] = calls * on_kernel
+    sums["attention_window_calls"] = jnp.asarray(
+        sum(attention_op.band(w, length) is not None for w in windows),
+        jnp.int32)
+    sums["attention_tiles"] = jnp.asarray(
+        sum(attention_op.tiles_walked(length, w) for w in windows),
+        jnp.int32)
+    sums["attention_tiles_causal"] = jnp.asarray(
+        calls * attention_op.tiles_walked(length), jnp.int32)
     return sums
 
 

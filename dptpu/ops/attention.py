@@ -16,7 +16,12 @@ run a second time on the way back.
 One ``lax.scan`` walks the tiles ``(i, j)`` with ``j <= i`` only (the
 causal lower triangle, 136 of 256 tiles at 16 blocks), so no tile that
 the mask would zero is computed; the diagonal tiles are masked by
-absolute position. Grouped-query attention is native: the ``G = Hq / Hkv``
+absolute position. With a ``window`` (sliding-window attention: key ``j``
+is visible to query ``i`` where ``0 <= i - j < window``) the tiles walked
+are a BAND of that triangle, those that hold a visible pair (70 of the
+136 at a window of 2,048 and blocks of 512), and the tiles the window's
+far side crosses are masked on that edge too; a window no shorter than
+the row is the causal program itself. Grouped-query attention is native: the ``G = Hq / Hkv``
 query heads that share a key/value head are folded into the tile's query
 rows, so keys and values are never repeated. A length that is no multiple
 of the block is padded up; padded keys lie behind every real query, so
@@ -76,11 +81,34 @@ def residual_bytes(rows: int, length: int, heads: int, v_head: int,
     return padded * heads * (v_head * jnp.dtype(dtype).itemsize + 4)
 
 
-def _tile_pairs(num_blocks: int):
-    """The causal lower triangle of tiles, row by row: ``(i, j <= i)``."""
-    pairs = [(i, j) for i in range(num_blocks) for j in range(i + 1)]
+def band(window, length: int):
+    """``window`` as the passes take it: None for a causal call, which a
+    window no shorter than the row is too."""
+    if window is not None and window < 1:
+        raise ValueError(f"a window of {window} tokens shows a query "
+                         f"nothing, not even itself")
+    return None if window is None or window >= length else int(window)
+
+
+def _tile_pairs(num_blocks: int, block: int = 0, window=None):
+    """The causal lower triangle of tiles, row by row: ``(i, j <= i)``;
+    under a window those of them that hold a visible pair (the nearest
+    pair of tiles ``i`` and ``j`` lies ``(i - j - 1) * block + 1``
+    apart)."""
+    reach = num_blocks if window is None else (window - 2) // block + 1
+    pairs = [(i, j) for i in range(num_blocks)
+             for j in range(max(i - reach, 0), i + 1)]
     return (np.asarray([p[0] for p in pairs], np.int32),
             np.asarray([p[1] for p in pairs], np.int32))
+
+
+def tiles_walked(length: int, window=None, block: int = DEFAULT_BLOCK) -> int:
+    """The key tiles one pass of ``causal_attention`` walks over a row
+    of ``length`` tokens (of one key/value head): a count from the
+    shapes, for a model to report beside its calls."""
+    block = min(block, length)
+    return len(_tile_pairs(-(-length // block), block,
+                           band(window, length))[0])
 
 
 def _block(x, index, size):
@@ -92,15 +120,26 @@ def _put_block(x, update, index, size):
     return lax.dynamic_update_slice_in_dim(x, update, index * size, axis=2)
 
 
-def _tile_scores(q_i, k_j, i, j, block, groups, scale):
+def _tile_scores(q_i, k_j, i, j, block, groups, scale, window):
     """``[B, Hkv, G * block, block]`` float32 scores of one tile, the
     diagonal tile masked by position (query row r of the tile is query
-    ``r % block`` of its block: the G heads are stacked along the rows)."""
+    ``r % block`` of its block: the G heads are stacked along the rows),
+    and under a window every tile by how far behind its query a key
+    lies. A tile the window leaves nothing of for some query row (its
+    first walked tile, where the window is no whole blocks) reads
+    ``_MASKED`` all along that row: the running maximum stays at it, what
+    is summed under it is wiped by ``exp(_MASKED - m)`` = 0 at the row's
+    first visible score, and the backward pass's ``exp(_MASKED - lse)``
+    is 0."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q_i, k_j,
                    preferred_element_type=jnp.float32) * scale
     q_pos = jnp.tile(jnp.arange(block), groups)[:, None]
     k_pos = jnp.arange(block)[None, :]
-    visible = jnp.logical_or(j < i, k_pos <= q_pos)
+    if window is None:
+        visible = jnp.logical_or(j < i, k_pos <= q_pos)
+    else:
+        behind = (i - j) * block + q_pos - k_pos
+        visible = jnp.logical_and(behind >= 0, behind < window)
     return jnp.where(visible, s, _MASKED)
 
 
@@ -128,20 +167,20 @@ def _from_tiles(x, block, groups):
     return x.reshape(b, h, groups, n * block, d)
 
 
-def _forward(q, k, v, block, groups, scale):
+def _forward(q, k, v, block, groups, scale, window=None):
     """``q`` in tile layout ``[B, Hkv, S * G, D]``, ``k`` ``[B, Hkv, S,
     D]``, ``v`` ``[B, Hkv, S, Dv]``. Returns ``(out, lse)`` in tile
     layout, ``out`` of ``v``'s head size, ``lse`` ``[B, Hkv, S * G]``
     float32."""
     b, h, rows, _ = q.shape
     qb = groups * block
-    ii, jj = _tile_pairs(k.shape[2] // block)
+    ii, jj = _tile_pairs(k.shape[2] // block, block, window)
 
     def tile(carry, ij):
         m, l, acc = carry
         i, j = ij
         s = _tile_scores(_block(q, i, qb), _block(k, j, block), i, j,
-                         block, groups, scale)
+                         block, groups, scale, window)
         m_i, l_i, acc_i = (_block(m, i, qb), _block(l, i, qb),
                            _block(acc, i, qb))
         m_new = jnp.maximum(m_i, jnp.max(s, axis=-1, keepdims=True))
@@ -162,9 +201,9 @@ def _forward(q, k, v, block, groups, scale):
     return out, (m + jnp.log(l))[..., 0]
 
 
-def _backward(q, k, v, out, lse, d_out, block, groups, scale):
+def _backward(q, k, v, out, lse, d_out, block, groups, scale, window=None):
     qb = groups * block
-    ii, jj = _tile_pairs(k.shape[2] // block)
+    ii, jj = _tile_pairs(k.shape[2] // block, block, window)
     # rowsum(dO * O): the softmax Jacobian's diagonal term, once per query
     delta = jnp.sum(d_out.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)
@@ -176,7 +215,7 @@ def _backward(q, k, v, out, lse, d_out, block, groups, scale):
         q_i, k_j, v_j = _block(q, i, qb), _block(k, j, block), \
             _block(v, j, block)
         do_i = _block(d_out, i, qb)
-        s = _tile_scores(q_i, k_j, i, j, block, groups, scale)
+        s = _tile_scores(q_i, k_j, i, j, block, groups, scale, window)
         p = jnp.exp(s - _block(lse, i, qb))
         dv_j = jnp.einsum("bhqk,bhqd->bhkd", p.astype(do_i.dtype), do_i,
                           preferred_element_type=jnp.float32)
@@ -261,14 +300,14 @@ def _blocks_of(q, k, v, block, groups):
 def _kernel(name: str, **how):
     """``attention_kernel.forward`` / ``backward`` under the scan's
     signature, at the block sizes the shapes give."""
-    def run(q, k, v, *rest, block, groups, scale):
+    def run(q, k, v, *rest, block, groups, scale, window=None):
         # Pallas and Mosaic load here: where a TPU program is lowered
         from dptpu.ops import attention_kernel
 
         block_q, block_kv = _blocks_of(q, k, v, block, groups)
         return getattr(attention_kernel, name)(
             q, k, v, *rest, block=block, groups=groups, scale=scale,
-            block_q=block_q, block_kv=block_kv, **how)
+            window=window, block_q=block_q, block_kv=block_kv, **how)
 
     return run
 
@@ -288,13 +327,15 @@ _on_tpu_p = _by_platform(
     lambda: [jnp.int32(0)], lambda: [jnp.int32(1)])
 
 
-def _here(scan, prim, *arrays, block, groups, scale):
+def _here(scan, prim, *arrays, block, groups, scale, window):
     """One pass by ``scan``, or by ``prim`` (the scan again, or the
     kernel where the program is lowered for a TPU) for the shapes the
-    kernels take."""
+    kernels take. The window changes which tiles a pass walks and not
+    what it holds: the rule on the shapes does not read it."""
     if _blocks_of(*arrays[:3], block, groups) is None:
-        return scan(*arrays, block, groups, scale)
-    return prim.bind(*arrays, block=block, groups=groups, scale=scale)
+        return scan(*arrays, block, groups, scale, window)
+    return prim.bind(*arrays, block=block, groups=groups, scale=scale,
+                     window=window)
 
 
 def kernel_calls(length: int, heads: int, kv_heads: int, qk_head: int,
@@ -302,7 +343,8 @@ def kernel_calls(length: int, heads: int, kv_heads: int, qk_head: int,
     """1 where ``causal_attention`` on rows of ``length`` tokens takes
     the kernels in the program being lowered, 0 where it takes the scan
     (another platform, or a shape they do not tile): an int32 scalar of
-    the traced program, for a model to count its calls with."""
+    the traced program, for a model to count its calls with. One answer
+    for causal and windowed calls alike: the rule reads the shapes."""
     block = min(block, length)
     blocks = kernel_blocks(-(-length // block) * block, heads // kv_heads,
                            qk_head, v_head, dtype, block)
@@ -311,37 +353,41 @@ def kernel_calls(length: int, heads: int, kv_heads: int, qk_head: int,
     return _on_tpu_p.bind()[0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _attend(q, k, v, block, groups, scale):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _attend(q, k, v, block, groups, scale, window):
     return _here(_forward, _forward_p, q, k, v, block=block, groups=groups,
-                 scale=scale)[0]
+                 scale=scale, window=window)[0]
 
 
-def _attend_fwd(q, k, v, block, groups, scale):
+def _attend_fwd(q, k, v, block, groups, scale, window):
     # the names sit HERE, on both residuals: a name on the call's result
     # alone leaves ``lse`` to be made again, by the whole forward pass
     out, lse = map(checkpoint_name,
                    _here(_forward, _forward_p, q, k, v, block=block,
-                         groups=groups, scale=scale), RESIDUAL_NAMES)
+                         groups=groups, scale=scale, window=window),
+                   RESIDUAL_NAMES)
     return out, (q, k, v, out, lse)
 
 
-def _attend_bwd(block, groups, scale, residuals, d_out):
+def _attend_bwd(block, groups, scale, window, residuals, d_out):
     return _here(_backward, _backward_p, *residuals, d_out, block=block,
-                 groups=groups, scale=scale)
+                 groups=groups, scale=scale, window=window)
 
 
 _attend.defvjp(_attend_fwd, _attend_bwd)
 
 
-def causal_attention(q, k, v, *, scale: float, block: int = DEFAULT_BLOCK):
+def causal_attention(q, k, v, *, scale: float, window=None,
+                     block: int = DEFAULT_BLOCK):
     """Causal softmax attention, blockwise.
 
     ``q`` is ``[B, S, Hq, D]``, ``k`` ``[B, S, Hkv, D]`` and ``v``
     ``[B, S, Hkv, Dv]`` with ``Hq`` a multiple of ``Hkv`` (grouped
-    queries); returns ``[B, S, Hq, Dv]`` in ``q``'s dtype.
-    Differentiable: the backward pass is the tiled recomputation, not
-    autodiff through the scan.
+    queries); returns ``[B, S, Hq, Dv]`` in ``q``'s dtype. ``window``:
+    key ``j`` is visible to query ``i`` where ``0 <= i - j < window``
+    (None: every key behind the query, and a window of ``S`` or more is
+    that same program). Differentiable: the backward pass is the tiled
+    recomputation, not autodiff through the scan.
     """
     b, s, hq, _ = q.shape
     hkv, dv = k.shape[2], v.shape[-1]
@@ -349,6 +395,7 @@ def causal_attention(q, k, v, *, scale: float, block: int = DEFAULT_BLOCK):
         raise ValueError(f"{hq} query heads are not whole groups over "
                          f"{hkv} key/value heads")
     groups = hq // hkv
+    window = band(window, s)
     block = min(block, s)
     padded = -(-s // block) * block
     if padded != s:
@@ -356,13 +403,13 @@ def causal_attention(q, k, v, *, scale: float, block: int = DEFAULT_BLOCK):
         q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
     out = _attend(_to_tiles(_fold(q, hkv), block),
                   k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-                  block, groups, float(scale))
+                  block, groups, float(scale), window)
     out = _from_tiles(out, block, groups)  # [B, Hkv, G, S, Dv]
     out = out.transpose(0, 3, 1, 2, 4).reshape(b, padded, hq, dv)
     return out[:, :s]
 
 
-def plain_causal_attention(q, k, v, *, scale: float):
+def plain_causal_attention(q, k, v, *, scale: float, window=None):
     """The same result with the scores materialised: what the blockwise
     one is tested against (and fine at short lengths)."""
     b, s, hq, _ = q.shape
@@ -372,6 +419,9 @@ def plain_causal_attention(q, k, v, *, scale: float):
     vt = v.transpose(0, 2, 1, 3).astype(jnp.float32)
     scores = jnp.einsum("bhgqd,bhkd->bhgqk", qg, kt) * scale
     mask = jnp.tril(jnp.ones((s, s), bool))
+    if window is not None:
+        mask = jnp.logical_and(mask, ~jnp.tril(jnp.ones((s, s), bool),
+                                               -window))
     probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
     out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, vt)
     return out.transpose(0, 3, 1, 2, 4).reshape(

@@ -68,14 +68,21 @@ def row_positions(rows: int, block: int, groups: int, block_q: int):
         np.int32)
 
 
+def _first_key(pos, window, maximum=jnp.maximum):
+    """The first key a row block at ``pos`` sees: the farthest one
+    visible to its first query."""
+    return 0 if window is None else maximum(pos - (window - 1), 0)
+
+
 def _pairs(positions, length: int, block_q: int, block_kv: int,
-           by_key: bool):
+           by_key: bool, window=None):
     """The visible ``(row block, key block)`` pairs: row by row for the
     forward pass, key block by key block for the backward pass. Returns
     int32 arrays ``rows, cols, pos`` (the row block's first position) and
     ``first`` (1 on a key block's first pair)."""
     pairs = [(r, j) for r, p in enumerate(positions)
-             for j in range((int(p) + block_q - 1) // block_kv + 1)]
+             for j in range(_first_key(int(p), window, max) // block_kv,
+                            (int(p) + block_q - 1) // block_kv + 1)]
     assert {j for _, j in pairs} == set(range(length // block_kv))
     if by_key:
         pairs.sort(key=lambda rj: (rj[1], rj[0]))
@@ -85,46 +92,64 @@ def _pairs(positions, length: int, block_q: int, block_kv: int,
     return rows, cols, positions[rows], first
 
 
-def _visible(pos, col, block_kv: int, shape):
-    """Key position <= query position over a ``[block_kv, block_q]``
-    tile (keys down the rows, queries along the lanes)."""
+def _visible(pos, col, block_kv: int, shape, window):
+    """Key position <= query position, and under a window less than
+    ``window`` behind it, over a ``[block_kv, block_q]`` tile (keys down
+    the rows, queries along the lanes)."""
     k_pos = col * block_kv + lax.broadcasted_iota(jnp.int32, shape, 0)
     q_pos = pos + lax.broadcasted_iota(jnp.int32, shape, 1)
-    return k_pos <= q_pos
+    if window is None:
+        return k_pos <= q_pos
+    return jnp.logical_and(k_pos <= q_pos, q_pos - k_pos < window)
+
+
+def _crossed(pos, col, block_q: int, block_kv: int, window):
+    """Whether an edge of what is visible crosses the pair's tile: the
+    diagonal (its last key lies past its first query) or the window's far
+    side (its first key lies ``window`` or more behind its last query)."""
+    diagonal = (col + 1) * block_kv - 1 > pos
+    if window is None:
+        return diagonal
+    return jnp.logical_or(
+        diagonal, pos + block_q - 1 - col * block_kv >= window)
 
 
 def _either(needs_mask, tile):
-    """``tile(True)`` on a pair the diagonal crosses, ``tile(False)`` (no
-    mask computed) under it."""
+    """``tile(True)`` on a pair an edge crosses, ``tile(False)`` (no
+    mask computed) inside."""
     pl.when(needs_mask)(functools.partial(tile, True))
     pl.when(jnp.logical_not(needs_mask))(functools.partial(tile, False))
 
 
-def _scores(k_ref, qt_ref, pos, col, scale, block_kv, masked):
+def _scores(k_ref, qt_ref, pos, col, scale, block_kv, masked, window):
     """The float32 scores of one tile, transposed: ``[block_kv,
     block_q]``."""
     s = jnp.dot(k_ref[...], qt_ref[...],
                 preferred_element_type=jnp.float32) * scale
     if masked:
-        s = jnp.where(_visible(pos, col, block_kv, s.shape), s, _MASKED)
+        s = jnp.where(_visible(pos, col, block_kv, s.shape, window), s,
+                      _MASKED)
     return s
 
 
 def _forward_kernel(rows_ref, cols_ref, pos_ref, qt_ref, k_ref, vt_ref,
                     outt_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-                    scale: float, block_q: int, block_kv: int):
+                    scale: float, block_q: int, block_kv: int, window):
     del rows_ref  # the index maps' alone
     step = pl.program_id(2)
     col, pos = cols_ref[step], pos_ref[step]
 
-    @pl.when(col == 0)
+    # a row block's first pair: key block 0, but for a window
+    @pl.when(col == (0 if window is None else lax.div(
+        _first_key(pos, window), block_kv)))
     def _():
         m_ref[...] = jnp.full_like(m_ref, _MASKED)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     def tile(masked: bool):
-        s = _scores(k_ref, qt_ref, pos, col, scale, block_kv, masked)
+        s = _scores(k_ref, qt_ref, pos, col, scale, block_kv, masked,
+                    window)
         m_prev = m_ref[...]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
         alpha = jnp.exp(m_prev - m_next)
@@ -135,7 +160,7 @@ def _forward_kernel(rows_ref, cols_ref, pos_ref, qt_ref, k_ref, vt_ref,
             vt_ref[...], p.astype(vt_ref.dtype),
             preferred_element_type=jnp.float32)
 
-    _either((col + 1) * block_kv - 1 > pos, tile)
+    _either(_crossed(pos, col, block_q, block_kv, window), tile)
 
     @pl.when(col == lax.div(pos + block_q - 1, block_kv))
     def _():
@@ -147,7 +172,7 @@ def _forward_kernel(rows_ref, cols_ref, pos_ref, qt_ref, k_ref, vt_ref,
 def _backward_kernel(rows_ref, cols_ref, pos_ref, first_ref, qt_ref, k_ref,
                      kt_ref, v_ref, dot_ref, lse_ref, delta_ref, dqt_ref,
                      dkt_ref, dvt_ref, dq_acc, dk_acc, dv_acc, *,
-                     scale: float, block_q: int, block_kv: int):
+                     scale: float, block_q: int, block_kv: int, window):
     step = pl.program_id(2)
     row, col, pos = rows_ref[step], cols_ref[step], pos_ref[step]
 
@@ -162,7 +187,8 @@ def _backward_kernel(rows_ref, cols_ref, pos_ref, first_ref, qt_ref, k_ref,
 
     def tile(masked: bool):
         qt, dot = qt_ref[...], dot_ref[...]
-        s = _scores(k_ref, qt_ref, pos, col, scale, block_kv, masked)
+        s = _scores(k_ref, qt_ref, pos, col, scale, block_kv, masked,
+                    window)
         p = jnp.exp(s - lse_ref[...])
         dv_acc[...] += lax.dot_general(dot, p.astype(dot.dtype), _NT,
                                        preferred_element_type=jnp.float32)
@@ -173,10 +199,17 @@ def _backward_kernel(rows_ref, cols_ref, pos_ref, first_ref, qt_ref, k_ref,
         dq_acc[row] += jnp.dot(kt_ref[...], ds,
                                preferred_element_type=jnp.float32)
 
-    _either((col + 1) * block_kv - 1 > pos, tile)
+    _either(_crossed(pos, col, block_q, block_kv, window), tile)
 
-    # the last row block sees every key block: each column ends on it
-    @pl.when(row == dq_acc.shape[0] - 1)
+    if window is None:
+        # the last row block sees every key block: each column ends on it
+        ends = row == dq_acc.shape[0] - 1
+    else:
+        # a column ends where the next pair starts another, or none follows
+        after = jnp.minimum(step + 1, pl.num_programs(2) - 1)
+        ends = jnp.logical_or(first_ref[after] == 1, after == step)
+
+    @pl.when(ends)
     def _():
         dkt_ref[...] = dk_acc[...].astype(dkt_ref.dtype)
         dvt_ref[...] = dv_acc[...].astype(dvt_ref.dtype)
@@ -216,14 +249,15 @@ def _t(x):
 
 
 def forward(q, k, v, *, block: int, groups: int, scale: float,
-            block_q: int, block_kv: int, interpret: bool = False):
+            block_q: int, block_kv: int, window=None,
+            interpret: bool = False):
     """``attention._forward``: ``(out, lse)`` in tile layout."""
     b, h, rows, d = q.shape
     length, dv = k.shape[2], v.shape[-1]
     positions = row_positions(rows, block, groups, block_q)
     pair_rows, cols, pos, _ = _pairs(positions, length, block_q, block_kv,
-                                     by_key=False)
-    kernel = functools.partial(_forward_kernel, scale=scale,
+                                     by_key=False, window=window)
+    kernel = functools.partial(_forward_kernel, scale=scale, window=window,
                                block_q=block_q, block_kv=block_kv)
     outt, lse = pl.pallas_call(
         kernel,
@@ -244,7 +278,7 @@ def forward(q, k, v, *, block: int, groups: int, scale: float,
 
 
 def backward(q, k, v, out, lse, d_out, *, block: int, groups: int,
-             scale: float, block_q: int, block_kv: int,
+             scale: float, block_q: int, block_kv: int, window=None,
              interpret: bool = False):
     """``attention._backward``: ``(dq, dk, dv)``."""
     b, h, rows, d = q.shape
@@ -254,8 +288,9 @@ def backward(q, k, v, out, lse, d_out, *, block: int, groups: int,
                     axis=-1)
     positions = row_positions(rows, block, groups, block_q)
     pair_rows, cols, pos, first = _pairs(positions, length, block_q,
-                                         block_kv, by_key=True)
-    kernel = functools.partial(_backward_kernel, scale=scale,
+                                         block_kv, by_key=True,
+                                         window=window)
+    kernel = functools.partial(_backward_kernel, scale=scale, window=window,
                                block_q=block_q, block_kv=block_kv)
     blocks = rows // block_q
     # dq whole, a row block a leading index: [blocks, D, block_q]

@@ -60,12 +60,14 @@ class MoeLoad:
     (``STEP_CONSTANTS``) are passed on as they are."""
 
     # the megabytes the model's blocks keep through their
-    # rematerialisation; the attention's calls in one forward pass and
-    # those of them that run as its kernels in this program; the same
-    # of the state-space scan
+    # rematerialisation; the attention's calls in one forward pass,
+    # those of them that run as its kernels in this program and those
+    # with a window, the key tiles they walk and what the causal
+    # triangle would; the state-space scan's calls and kernels
     STEP_CONSTANTS = ("kept_residual_mb", "attention_calls",
-                      "attention_kernel_calls", "ssd_calls",
-                      "ssd_kernel_calls")
+                      "attention_kernel_calls", "attention_window_calls",
+                      "attention_tiles", "attention_tiles_causal",
+                      "ssd_calls", "ssd_kernel_calls")
 
     def __init__(self):
         self.steps = 0

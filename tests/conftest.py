@@ -58,6 +58,12 @@ _FAST_MODULES = {
     # published widths on two layers and 96 tokens in a one-device child,
     # a minute and a half more, in a file of its own
     "test_granite", "test_granite_cell",
+    # Trinity-Mini (ISSUE 43): the attention's window in the scan and in
+    # the interpreted kernels against the plain attention (a minute), the
+    # model and its reference at toy widths (two minutes), and the cell's
+    # rehearsal at the published widths on two layers and 96 tokens in a
+    # one-device child, in a file of its own
+    "test_attention_window", "test_trinity", "test_trinity_cell",
     # fit()'s default train feed (ISSUE 31): ONE module fixture runs
     # fit() four times at the sizes above (resnet18@32 and the tiny
     # token model, 4-8 steps, default and thread mode); the rest drives

@@ -26,7 +26,12 @@ their readers' cases (``test_setup_account.py``, every name with ``setup``
 in it) run here too, all but the one that makes the CPU rehearsal's run.
 PR 42 appended ``expert_compact_share`` (the expert layers' runs that
 fitted the compact buffer): its reader's cases
-(``test_expert_compact_share.py``) run here too."""
+(``test_expert_compact_share.py``) run here too. PR 43 appended
+``trinity-mini-fit-8k-1chip`` (``trinity-mini-ep8-bf16``) behind granite's
+cell with two metrics of the attention's window: the cell has its own
+case below, and its readers' (``test_attention_tile_share.py``,
+``test_attention_kernel_roofline_share.py``) and its limits'
+(``test_trinity_limits.py``) run here too."""
 
 import json
 import os
@@ -75,9 +80,18 @@ from benchmark.tests.test_setup_account import (  # noqa: F401,E402
 # the reader of the expert layers' share in the compact buffer (11 cases)
 from benchmark.tests.test_expert_compact_share import *  # noqa: F401,F403,E402
 
+# the readers of the attention's window: the tiles walked of the causal
+# triangle's (10 cases) and the kernels' share of their roofline (18)
+from benchmark.tests.test_attention_tile_share import *  # noqa: F401,F403,E402
+from benchmark.tests.test_attention_kernel_roofline_share import *  # noqa: F401,F403,E402
+# the Trinity configuration's limits on faulty programs (5 cases)
+from benchmark.tests.test_trinity_limits import *  # noqa: F401,F403,E402
+from benchmark.tests.test_trinity_limits import sound_trinity  # noqa: F401,E402
+
 CELL = "lfm2moe-fit-8k-1chip"
 JOYAI_CELL = "joyai-fit-8k-1chip"
 GRANITE_CELL = "granite-ssm-fit-1chip"
+TRINITY_CELL = "trinity-mini-fit-8k-1chip"
 # the cells the benchmark accepted, in its order: a later one is appended
 ACCEPTED = ["rn50-fit-1chip", "vitb16-fit-1chip", "rn50-ddp-4chip", CELL,
             JOYAI_CELL]
@@ -447,6 +461,143 @@ def test_the_granite_cell_lands_as_files_and_keeps_every_contract():
         1606e6, rel=1e-3)
     assert cell.family.train_flops(model, 1) == pytest.approx(
         39.47e12, rel=1e-3)
+    example = cell.family.example_input(model)
+    assert example.shape == (1, 8192) and example.dtype == np.int32
+
+    # two seeds: other rows, the same number of steps (one step program)
+    assert traffic["dataset_images"] % cell.feed.SEED_ROWS == 0
+    a, b = (cell.feed.epoch_order(drive.dataset_images(traffic, s), 0, 5)
+            for s in (1, 130))
+    assert len(a) == len(b) == traffic["dataset_images"]
+    assert np.array_equal(b - a, np.full(len(a), 1))
+
+
+def test_the_trinity_cell_lands_as_files_and_keeps_every_contract():
+    cell = cells.load_cell(TRINITY_CELL)
+    config, traffic = cell.config, cell.traffic
+    assert cell.chips == 1 and cell.global_batch == 1
+    assert cell.feed.__name__ == "benchmark.feeds.tokens"
+    assert cell.family.__name__ == "benchmark.reference.afmoe"
+    assert cell.optimizer.__name__ \
+        == "benchmark.reference.optimizers.adamw_committed"
+    for kind, module in (("feeds", cell.feed), ("reference", cell.family),
+                         ("reference/optimizers", cell.optimizer)):
+        assert all(hasattr(module, a) for a in cells.CONTRACTS[kind])
+    # every per-layer metric JoyAI's cell reports but the second loss's
+    # share, and the two of the window
+    other = {m["name"] for m in cells.load_cell(JOYAI_CELL).per_layer}
+    mine = {m["name"] for m in cell.per_layer}
+    assert mine == other - {"mtp_loss_share"} | {
+        "attention_tile_share", "attention_kernel_roofline_share"}
+    assert {"kept_residual_mb", "attention_kernel_share", "device_mfu",
+            "expert_compact_share", "expert_dropped_tokens"} <= mine
+    for metric in mine:
+        assert callable(cells.reader(metric).read)
+    assert {m["name"] for m in cell.end_to_end} == {
+        "train_img_s_chip", "step_ms_p95", "setup_s"}
+    # appended: behind the accepted cells and granite's, in every list it
+    # is on, and the two new metrics behind every accepted one
+    bench = cells.manifest()
+    names = [w["name"] for w in bench["workloads"]]
+    assert names == ACCEPTED + [GRANITE_CELL, TRINITY_CELL]
+    assert [c["name"] for c in bench["configs"]][-1] == config["name"]
+    (entry,) = [c for c in bench["configs"] if c["name"] == config["name"]]
+    assert [m["name"] for m in bench["per_layer"]][-2:] == [
+        "attention_tile_share", "attention_kernel_roofline_share"]
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if TRINITY_CELL in m.get("workloads", ()):
+            assert m["workloads"][-1] == TRINITY_CELL
+            assert m["workloads"].count(TRINITY_CELL) == 1
+    (cell_entry,) = [w for w in bench["workloads"]
+                     if w["name"] == TRINITY_CELL]
+    assert (cell_entry["config"], cell_entry["traffic"], cell_entry["chips"]
+            ) == (config["name"], traffic["name"], 1)
+    assert all(len(e["why"]) <= 200 for e in (entry, cell_entry))
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
+
+    # the trainer's arguments: what the traffic file adds is what
+    # create_kwargs mirrors, and the model both build is the `model` group
+    from dptpu.config import parse_config
+    from dptpu.models import create_model, model_task
+    from dptpu.models.registry import token_model_kwargs
+
+    argv = drive.fit_argv(cell, drive.dataset_images(traffic, 2**31 + 130))
+    assert argv[0] == "tokens:8192@2" and argv[argv.index("-b") + 1] == "1"
+    parsed = parse_config(argv, variant="apex")
+    assert model_task(parsed.arch) == "tokens"
+    assert token_model_kwargs(parsed, "tokens") == config["create_kwargs"]
+    assert (parsed.optimizer, parsed.beta1, parsed.beta2, parsed.eps,
+            parsed.weight_decay) == ("adamw", 0.9, 0.95, 1e-8, 0.1)
+    held = create_model(config["arch"], **config["create_kwargs"]).config
+    model = config["model"]
+    for key in ("hidden_size", "intermediate_size", "moe_intermediate_size",
+                "num_attention_heads", "num_key_value_heads", "head_dim",
+                "sliding_window", "num_experts_per_tok",
+                "num_shared_experts", "route_norm", "route_scale",
+                "mup_enabled", "rms_norm_eps", "rope_theta", "vocab_size",
+                "sequence_length"):
+        assert getattr(held, key) == model[key], key
+    assert held.layers_here == (model["layers_first"],
+                                model["layers_held"]) == (1, 5)
+    assert [held.layer_types[i] for i in held.numbers_here] \
+        == model["layer_types"] == config["layer_types"]
+    # three window layers to one full one behind the dense layer, as
+    # published, and the dense layer a window one
+    assert model["layer_types"] == ["sliding_attention"] * 2 + [
+        "full_attention"] + ["sliding_attention"] * 2
+    assert [held.is_dense(i) for i in held.numbers_here] == [
+        True, False, False, False, False]
+    assert held.num_dense_layers == model["first_expert_layer"] == 2
+    assert held.num_experts == model["router_experts"] == 128
+    assert held.experts_here == (model["experts_first"],
+                                 model["experts_held"]) == (0, 16)
+    assert traffic["check_steps"] == 2 <= traffic["warmup_iters"]
+
+    # the file beside the catalog: every published number under its key,
+    # but for the keys `reduced` names; no width, head count or window
+    # among them
+    reduced = set(config["reduced"])
+    assert reduced == {"num_hidden_layers", "layer_types",
+                       "num_dense_layers", "num_experts", "vocab_size"}
+    assert entry["reduced"] == config["reduced"]
+    if os.path.exists(CATALOG):
+        with open(CATALOG) as f:
+            row = next(r for r in map(json.loads, f)
+                       if r["name"] == "Trinity-Mini")
+        assert config["source"] == row["source_url"] == entry["source"]
+        for key, value in row["config"].items():
+            if key in reduced:
+                assert config["published"][key] == value
+            else:
+                assert config[key] == value, key
+        assert config["layer_types"] == row["config"]["layer_types"][1:6]
+    assert (config["sliding_window"], config["num_experts_per_tok"],
+            config["route_scale"], config["head_dim"]) == (2048, 8, 2.826, 128)
+    assert config["num_hidden_layers"] == model["layers_held"]
+    assert config["num_dense_layers"] == 1  # held: layer 1
+    assert config["num_experts"] == model["experts_held"] == 128 // 8
+    assert config["vocab_size"] == model["vocab_size"] == 25024 == 200192 // 8
+    assert len(config["assumed"]) >= 8 and "N = 8" in config["deployment"]
+    assert config["optimizer"]["name"] == "adamw_committed"
+
+    # the family's shapes are the program's, leaf for leaf, and its count
+    # of operations is the issue's arithmetic
+    template = drive.program_template(config)
+    spec = {name: tuple(shape)
+            for name, shape, _, _ in cell.family.weight_spec(model)}
+    from dptpu.models.pretrained import torch_key_map
+
+    assert set(torch_key_map(config["arch"], template)) == set(spec)
+    assert set(cell.family.trainable(model)) == set(spec)  # see its note
+    params = sum(int(np.prod(s)) for n, s in spec.items()
+                 if not n.endswith("expert_bias"))
+    assert params == model["parameters"] == 705_473_792
+    assert params == sum(int(np.prod(leaf.shape)) for leaf in
+                         jax.tree_util.tree_leaves(template["params"]))
+    # 738 MFLOP a token forward, 18.1 TFLOP a step, the scores counted
+    # over the pairs the mask keeps
+    assert cell.family.train_flops(model, 1) == pytest.approx(
+        18.1e12, rel=5e-3)
     example = cell.family.example_input(model)
     assert example.shape == (1, 8192) and example.dtype == np.int32
 

@@ -124,7 +124,8 @@ def test_the_step_counts_the_layers_that_fitted_and_drops_nothing(case):
             x, chosen, weights, *_weights(), 0, EXPERTS)[1:]
             for chosen in chosen_by_layer]
         return token_model.with_counters(
-            {}, loads, TOKENS * K * len(loads), token_model.Kept(), 0, 0)
+            {}, loads, TOKENS * K * len(loads), token_model.Kept(), (),
+            TOKENS, 0)
 
     # the case's layer between two that fit with room
     got = sums([_slots(100), _slots(total), _slots(CAP - 1, seed=3)])

@@ -209,6 +209,11 @@ def test_two_adamw_steps_through_the_step_builder_match_the_reference(share):
     assert (int(metrics["ssd_calls"]), int(metrics["ssd_kernel_calls"]),
             int(metrics["ssd_chunks"])) == (scans, 0, 4)
     assert int(metrics["attention_calls"]) == 1
+    # no call has a window: the tiles walked are the causal triangle's
+    assert int(metrics["attention_window_calls"]) == 0
+    assert int(metrics["attention_tiles"]) \
+        == int(metrics["attention_tiles_causal"]) \
+        == int(metrics["attention_calls"])  # a toy row is one tile
     for key, (_, names, kind) in torch_key_map(ARCH, variables).items():
         delta = _to_torch(np.asarray(leaf_of(state.params, names)), kind) \
             - weights[key]

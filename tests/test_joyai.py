@@ -170,6 +170,11 @@ def test_loss_every_gradient_leaf_and_three_adamw_steps_match_the_reference(
     # every expert layer ran in its buffer (at this size the worst case's)
     assert int(metrics["moe_compact"]) == int(metrics["moe_layers"]) \
         == (2 if share else 3)
+    # no call has a window: the tiles walked are the causal triangle's
+    assert int(metrics["attention_window_calls"]) == 0
+    assert int(metrics["attention_tiles"]) \
+        == int(metrics["attention_tiles_causal"]) \
+        == int(metrics["attention_calls"])  # a toy row is one tile
     checked, idle = 0, []
     for key, (collection, names, kind) in torch_key_map(
             ARCH, variables).items():

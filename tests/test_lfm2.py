@@ -181,6 +181,11 @@ def test_three_adamw_steps_match_the_reference():
     assert losses == pytest.approx(want["loss"], abs=1e-5)
     assert int(metrics["moe_dropped"]) == 0
     assert int(metrics["moe_compact"]) == int(metrics["moe_layers"]) == 2
+    # no call has a window: the tiles walked are the causal triangle's
+    assert int(metrics["attention_window_calls"]) == 0
+    assert int(metrics["attention_tiles"]) \
+        == int(metrics["attention_tiles_causal"]) \
+        == int(metrics["attention_calls"])  # a toy row is one tile
     for key, (collection, names, kind) in torch_key_map(
             ARCH, variables).items():
         leaf = lambda tree: _to_torch(np.asarray(functools.reduce(  # noqa: E731

@@ -204,6 +204,41 @@ def test_attention_with_two_head_sizes_compiles_for_the_chip_at_the_cells_shape(
     assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9
 
 
+@pytest.mark.parametrize("window,tiles", [(None, 136), (2048, 70)])
+def test_eight_heads_of_128_a_key_value_head_compile_for_the_chip(
+        one_chip, window, tiles):
+    """Trinity-Mini's calls at the cell's shape: one row of 8,192 tokens,
+    32 query heads over 4 key/value heads of 128, a window of 2,048 and
+    none. The backward kernel holds the float32 ``dq`` of all 8 query
+    heads of a key/value head (67 MB of the 84 the rule allows): the
+    largest call the kernels have taken."""
+    from dptpu.ops import attention
+
+    assert attention._vmem_bytes(8 * 8192, 128, 128, 2, 512, 512) \
+        == 75_759_616 < attention.KERNEL_VMEM_BYTES
+    q = _shape((1, 8192, 32, 128), jnp.bfloat16, one_chip)
+    kv = _shape((1, 8192, 4, 128), jnp.bfloat16, one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(causal_attention(q, k, v, scale=128 ** -0.5,
+                                        window=window).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    text = compiled.as_text()
+    forward, backward = _attention_calls(text)
+    assert len(forward) == len(backward) == 1 and " while(" not in text
+    # the pairs a kernel walks are its prefetched scalars: 128 row blocks
+    # of 512 (8 heads x 16) over the causal triangle's key blocks, or
+    # over the band's
+    pairs = f"s32[{8 * tiles}]"
+    assert pairs in forward[0] and pairs in backward[0]
+    assert attention.tiles_walked(8192, window) == tiles
+    # dq, a row block a slab, all 8 heads of a key/value head together
+    assert "bf16[1,4,128,128,512]" in backward[0]
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.15e9
+
+
 def test_a_rematerialised_attention_block_keeps_out_and_lse_on_the_chip(
         one_chip):
     """An attention layer at the cell's shape as the model wraps it (a
