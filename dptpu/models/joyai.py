@@ -212,12 +212,14 @@ class JoyaiConfig:
 
 # What a step takes on the device beside the train state and the kept
 # residuals: the temporaries of THIS model's fully rematerialised step
-# (3.339 GB at one row of 8,192 tokens, a share of five layers and the
-# module: ``memory_analysis`` on the chip, PERF.md section 6, PR 36) and
-# 15% of a 16.9 GB chip left to the allocator. Fixed: kept residuals are
-# bounded by the budget, so a longer row or a larger share keeps less and
-# the step fits where it fitted without them.
-STEP_HEADROOM_BYTES = 5_900_000_000
+# (2.96 GB at one row of 8,192 tokens, a share of five layers and the
+# module; 3.339 before PR 37's attention kernels and PR 42's compact
+# buffers: ``memory_analysis`` of the cell's step compiled for a
+# described v5e at budget 0, PERF.md section 6, PR 44) and 15% of a
+# 16.9 GB chip left to the allocator. Fixed: kept residuals are bounded
+# by the budget, so a longer row or a larger share keeps less and the
+# step fits where it fitted without them.
+STEP_HEADROOM_BYTES = 5_500_000_000
 
 
 def residual_classes(config: JoyaiConfig, shape, dtype):
@@ -225,10 +227,12 @@ def residual_classes(config: JoyaiConfig, shape, dtype):
     (``token_model.keep_within``'s), over the main layers held and the
     multi-token-prediction block. The order is the order of keeping:
     milliseconds of re-run forward saved per byte held on the chip,
-    dearest first (490, 20 and 16 ms a GB at one row of 8,192 tokens:
-    PERF.md section 6, PR 36, which also says what was measured and left
-    out: the latents, the expanded queries, keys and values, the shared
-    expert's products)."""
+    dearest first (490, 20, 16 and 10-11 ms a GB at one row of 8,192
+    tokens: PERF.md section 6, PR 36, which also says what was measured
+    and left out, the latents, the expanded queries, keys and values, the
+    shared expert's products; the expert layers' class by PR 44's
+    readings of the layer under a block's rematerialisation,
+    ``scripts/bench_experts.py``)."""
     rows, length = shape
     tokens, item = rows * length, jnp.dtype(dtype).itemsize
     first, count = config.layers_here
@@ -245,6 +249,10 @@ def residual_classes(config: JoyaiConfig, shape, dtype):
          dense * 2 * tokens * config.intermediate_size * item),
         ("attention output projections", ("attention_out_proj",),
          attention * tokens * config.hidden_size * item),
+        # the module's block has an expert layer too
+        token_model.expert_residuals(
+            config.routing, tokens, config.hidden_size,
+            attention - dense, dtype),
     )
 
 

@@ -204,12 +204,15 @@ _dense = token_model.dense
 
 
 # What a step takes on the device beside the train state and the kept
-# residuals: the temporaries of the fully rematerialised step (3.42 GB at
-# 16,384 tokens a step, 2.03 GB of it the gradients of a five-layer
-# share) and 15% of a 16.9 GB chip left to the allocator. Fixed: kept
+# residuals: the temporaries of the fully rematerialised step (3.71 GB at
+# 16,384 tokens a step; 3.42 before PR 42's compact buffers, 4.35 while
+# they stood under the conditional: ``memory_analysis`` of the cell's
+# step compiled for a described v5e at budget 0, PERF.md section 6, PR
+# 44) and 15% of a 16.9 GB chip left to the allocator. Fixed: kept
 # residuals are bounded by the budget, so a longer row or a larger share
-# keeps less and the step fits where it fitted without them.
-STEP_HEADROOM_BYTES = 6_000_000_000
+# keeps less and the step fits where it fitted without them (a kept byte
+# costs the compiled step 0.7 bytes there).
+STEP_HEADROOM_BYTES = 6_300_000_000
 
 
 def residual_classes(config: Lfm2Config, shape, dtype):
@@ -217,10 +220,11 @@ def residual_classes(config: Lfm2Config, shape, dtype):
     it is, the names it keeps, the bytes they hold over all layers)`` for
     a step on token rows of ``shape`` ``(rows, length)``. The order is
     the order of keeping: milliseconds of re-run forward saved per byte
-    held on the chip, dearest first (310, 14, 10-12 and 9 ms a GB at
-    16,384 tokens a step: PERF.md section 6, PR 34, which also says what
-    was measured and left out: the routing, the experts' grouped
-    products)."""
+    held on the chip, dearest first (310, 14, 10-12, 9-11 and 9 ms a GB
+    at 16,384 tokens a step: PERF.md section 6, PR 34, which also says
+    what was measured and left out, the routing; the expert layers'
+    class by PR 44's readings of the layer under a block's
+    rematerialisation, ``scripts/bench_experts.py``)."""
     rows, length = shape
     tokens, item = rows * length, jnp.dtype(dtype).itemsize
     attn = sum(t == "full_attention" for t in config.layer_types)
@@ -237,6 +241,9 @@ def residual_classes(config: Lfm2Config, shape, dtype):
         ("mixer projections",
          ("conv_in_proj", "conv_out_proj", "attention_out_proj"),
          ((3 + 1) * conv + attn) * tokens * config.hidden_size * item),
+        token_model.expert_residuals(
+            config.routing, tokens, config.hidden_size,
+            config.num_hidden_layers - config.num_dense_layers, dtype),
         ("dense feed-forward", ("ffn_gate", "ffn_up"),
          config.num_dense_layers * 2 * tokens * config.intermediate_size
          * item),

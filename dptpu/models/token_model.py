@@ -175,8 +175,15 @@ ROW_TILE = 512
 # what the compact path keeps for its way back beside its inputs: the
 # gathered rows and the three grouped products. Everything else between
 # them is elementwise over ``[cap, *]`` and cheaper to compute again than
-# to write out through the ``cond`` and read back
+# to write out and read back. A block's rematerialisation keeps the same
+# four where its budget holds them (``expert_residuals``), and with them
+# the routing they were made under: a block's re-run makes the router's
+# input again, a rounding apart from the first pass's where the compiler
+# fused it otherwise, and a token whose top k flips between the passes
+# would shift every kept row behind it against the order the way back
+# sorts by
 COMPACT_RESIDUALS = ("expert_rows", "expert_gate", "expert_up", "expert_out")
+ROUTING_RESIDUAL = "expert_chosen"
 
 
 def held_row_cap(tokens: int, k: int, count: int, experts: int) -> int:
@@ -202,40 +209,82 @@ def sorted_slots(chosen, first: int, count: int):
     return jnp.argsort(key, stable=True), sizes
 
 
-def _grouped_swiglu(rows, in_a_run, w1, w3, w2, sizes):
+def _gate_and_up(rows, w1, w3, sizes):
+    """The first two grouped products of ``_grouped_swiglu``."""
+    return lax.ragged_dot(rows, w1, sizes), lax.ragged_dot(rows, w3, sizes)
+
+
+def _down(gate, up, w2, sizes, in_a_run):
+    """Its third, of ``silu(gate) * up`` zeroed behind the last run."""
+    return lax.ragged_dot(
+        jnp.where(in_a_run, nn.silu(gate) * up, 0), w2, sizes)
+
+
+def _grouped_swiglu(rows, in_a_run, w1, w3, w2, sizes, named=True):
     """``W2 (silu(W1 x) * W3 x)`` of each run of ``rows`` under its own
-    expert's matrices: three grouped products (named, with the rows that
-    go in, for a rematerialisation that keeps them:
-    ``COMPACT_RESIDUALS``). Rows behind the last run are in no group: the
-    grouped product on the chip leaves what it does not compute as it
-    finds it (whatever the memory held), in the result and in the
-    cotangents alike, so those rows are zeroed going in and coming out,
-    which zeroes their cotangents too (a token's slot on an absent expert
-    adds nothing to the token's gradient)."""
-    rows = checkpoint_name(jnp.where(in_a_run, rows, 0), "expert_rows")
-    gate = checkpoint_name(lax.ragged_dot(rows, w1, sizes), "expert_gate")
-    up = checkpoint_name(lax.ragged_dot(rows, w3, sizes), "expert_up")
-    out = checkpoint_name(lax.ragged_dot(
-        jnp.where(in_a_run, nn.silu(gate) * up, 0), w2, sizes), "expert_out")
-    return jnp.where(in_a_run, out, 0)
+    expert's matrices: three grouped products (``named``, with the rows
+    that go in, for a rematerialisation that keeps them:
+    ``COMPACT_RESIDUALS``); the result and the four. Rows behind the last
+    run are in no group: the grouped product on the chip leaves what it
+    does not compute as it finds it (whatever the memory held), in the
+    result and in the cotangents alike, so those rows are zeroed going in
+    and coming out, which zeroes their cotangents too (a token's slot on
+    an absent expert adds nothing to the token's gradient)."""
+    rows = jnp.where(in_a_run, rows, 0)
+    gate, up = _gate_and_up(rows, w1, w3, sizes)
+    kept = (rows, gate, up, _down(gate, up, w2, sizes, in_a_run))
+    if named:
+        kept = tuple(map(checkpoint_name, kept, COMPACT_RESIDUALS))
+    return jnp.where(in_a_run, kept[-1], 0), kept
 
 
-def worst_case_outputs(x, weights, w1, w3, w2, order, sizes):
+def worst_case_outputs(x, weights, w1, w3, w2, order, sizes, named=True):
     """The held experts' part of the result over a buffer of all
     ``tokens x k`` slots (``order``: the slots sorted by expert, the
     held ones' runs first; ``sizes``: the runs): no capacity bounds a
     run, and rows behind the last run cost the grouped product nothing,
     but every gather, mask and product beside it moves all the rows. The
     sorted rows go back to their tokens by the inverse permutation and
-    are summed by their weights."""
+    are summed by their weights. ``named``: whether the rows and products
+    carry ``COMPACT_RESIDUALS``' names (a layer whose only buffer this
+    is) or none (the fallback beside a compact buffer)."""
     tokens, k = weights.shape
     in_a_run = jnp.arange(tokens * k)[:, None] < jnp.sum(sizes)
-    out = _grouped_swiglu(x[order // k], in_a_run, w1, w3, w2, sizes)
+    out, _ = _grouped_swiglu(x[order // k], in_a_run, w1, w3, w2, sizes,
+                             named)
     back = jnp.argsort(order)
     out = out[back].reshape(tokens, k, -1)
     mixed = jnp.einsum("tkh,tk->th", out, weights.astype(out.dtype),
                        preferred_element_type=jnp.float32)
     return mixed.astype(x.dtype)
+
+
+def _compact_slots(order, sizes, cap: int, k: int):
+    """The compact buffer's slots, their tokens, and which of its rows
+    lie in a run."""
+    slot = order[:cap]
+    return slot, slot // k, jnp.arange(cap)[:, None] < jnp.sum(sizes)
+
+
+def _added_back(out, weights, slot, token, in_a_run, dtype):
+    """Each row of ``out`` (zeroed behind the last run) times its slot's
+    weight, added into its token: float32 sums, as the worst case's."""
+    out = jnp.where(in_a_run, out, 0)
+    weight = weights.reshape(-1)[slot].astype(out.dtype)
+    mixed = jnp.zeros((weights.shape[0], out.shape[-1]), jnp.float32)
+    return mixed.at[token].add(
+        out.astype(jnp.float32) * weight.astype(jnp.float32)[:, None]
+    ).astype(dtype)
+
+
+def _compact_forward(x, weights, w1, w3, w2, order, sizes, cap: int):
+    """``compact_outputs`` and what its way back reads beside its inputs
+    (``COMPACT_RESIDUALS``, named)."""
+    slot, token, in_a_run = _compact_slots(order, sizes, cap,
+                                           weights.shape[1])
+    _, kept = _grouped_swiglu(x[token], in_a_run, w1, w3, w2, sizes)
+    return _added_back(kept[-1], weights, slot, token, in_a_run,
+                       x.dtype), kept
 
 
 def compact_outputs(x, weights, w1, w3, w2, order, sizes, cap: int):
@@ -246,15 +295,76 @@ def compact_outputs(x, weights, w1, w3, w2, order, sizes, cap: int):
     the inverse permutation nor a ``[tokens x k, hidden]`` gather is
     made; its transpose is a gather of ``cap`` rows, and ``x``'s
     cotangent a scatter-add of ``cap`` rows."""
-    tokens, k = weights.shape
-    slot = order[:cap]
-    token = slot // k
-    in_a_run = jnp.arange(cap)[:, None] < jnp.sum(sizes)
-    out = _grouped_swiglu(x[token], in_a_run, w1, w3, w2, sizes)
-    weight = weights.reshape(-1)[slot].astype(out.dtype)
-    mixed = jnp.zeros((tokens, x.shape[-1]), jnp.float32).at[token].add(
-        out.astype(jnp.float32) * weight.astype(jnp.float32)[:, None])
-    return mixed.astype(x.dtype)
+    return _compact_forward(x, weights, w1, w3, w2, order, sizes, cap)[0]
+
+
+def _compact_way_back(cap: int, kept, x, weights, w1, w3, w2, order, sizes,
+                      ct):
+    """``_compact_forward``'s cotangents for ``x``, the weights and the
+    three matrices from what it kept: its four steps transposed one
+    after another, last first. Everything elementwise between the kept
+    arrays is made again (``jax.vjp`` also traces each step's product
+    forward; nothing reads it and the compiler drops it)."""
+    rows, gate, up, out = kept
+    slot, token, in_a_run = _compact_slots(order, sizes, cap,
+                                           weights.shape[1])
+    d_out, d_weights = jax.vjp(
+        lambda out, weights: _added_back(
+            out, weights, slot, token, in_a_run, x.dtype),
+        out, weights)[1](ct)
+    d_gate, d_up, d_w2 = jax.vjp(
+        lambda gate, up, w2: _down(gate, up, w2, sizes, in_a_run),
+        gate, up, w2)[1](d_out)
+    d_rows, d_w1, d_w3 = jax.vjp(
+        lambda rows, w1, w3: _gate_and_up(rows, w1, w3, sizes),
+        rows, w1, w3)[1]((d_gate, d_up))
+    d_x, = jax.vjp(
+        lambda x: jnp.where(in_a_run, x[token], 0), x)[1](d_rows)
+    return d_x, d_weights, d_w1, d_w3, d_w2
+
+
+def _worst_case_way_back(kept, x, weights, w1, w3, w2, order, sizes, ct):
+    """The fallback's cotangents: its forward made again from its inputs
+    (it kept nothing)."""
+    del kept
+    return jax.vjp(
+        lambda *differentiated: worst_case_outputs(
+            *differentiated, order, sizes, named=False),
+        x, weights, w1, w3, w2)[1](ct)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_outputs(cap: int, x, weights, w1, w3, w2, order, sizes):
+    return _held_forward(cap, x, weights, w1, w3, w2, order, sizes)[0]
+
+
+def _held_forward(cap: int, *operands):
+    """The compact path as straight-line code (its runs emptied in a
+    step that overflows: its rows are then in no group and give zeros)
+    plus the worst case's part under a ``cond`` whose other branch gives
+    zeros; the residuals are the compact path's four and the inputs."""
+    x, sizes = operands[0], operands[-1]
+    fits = jnp.sum(sizes) <= cap
+    out, kept = _compact_forward(
+        *operands[:-1], jnp.where(fits, sizes, 0), cap)
+    rest = lax.cond(
+        fits, lambda *_: jnp.zeros_like(x),
+        functools.partial(worst_case_outputs, named=False), *operands)
+    return out + rest, (kept, *operands)
+
+
+def _held_way_back(cap: int, residuals, ct):
+    """One ``cond``: the compact path's way back from what it kept, or
+    the fallback's from its inputs (the compact path gave zeros then, and
+    has no gradient to give)."""
+    sizes = residuals[-1]
+    grads = lax.cond(
+        jnp.sum(sizes) <= cap, functools.partial(_compact_way_back, cap),
+        _worst_case_way_back, *residuals, ct)
+    return (*grads, None, None)  # the order and the sizes are integers
+
+
+_held_outputs.defvjp(_held_forward, _held_way_back)
 
 
 def held_expert_outputs(x, chosen, weights, w1, w3, w2, first: int,
@@ -268,19 +378,33 @@ def held_expert_outputs(x, chosen, weights, w1, w3, w2, first: int,
     experts behind all others; the held ones form one run per expert, and
     three grouped matrix products (``lax.ragged_dot``) go over the runs.
     The rows that go through them are a buffer of ``held_row_cap`` rows,
-    a static function of the shapes (``compact_outputs``); a step whose
+    a static function of the shapes (``compact_outputs``). A step whose
     routing puts more slots than that on the held experts takes
-    ``worst_case_outputs`` instead, under a ``lax.cond`` on this step's
-    ``sum(sizes)``: every slot on a held expert fits there, so no
-    capacity bounds a run and no token is dropped on either path. Both
-    branches are rematerialised: under reverse mode a ``cond`` returns the
-    union of its branches' residuals, and the compact branch would
-    otherwise write every worst-case residual (as zeros). The fallback's
-    residuals are its inputs, so only a step that overflows pays its
-    forward again; the compact branch keeps ``COMPACT_RESIDUALS`` beside
-    its inputs and computes the elementwise steps between them again. A
-    layer that holds all its experts has no smaller buffer than the worst
-    case and no ``cond``.
+    ``worst_case_outputs`` instead: every slot on a held expert fits
+    there, so no capacity bounds a run and no token is dropped on either
+    path.
+
+    Forward, the two parts are ADDED (``_held_forward``): the compact
+    path is straight-line code, and the conditional holds the fallback
+    alone. Backward there is one ``cond`` that takes the compact path's
+    four arrays (``COMPACT_RESIDUALS``) and the inputs as operands and
+    gives the cotangents (``_held_way_back``): the layer is one
+    ``jax.custom_vjp``. So no ``[cap, *]`` array is ever a conditional's
+    RESULT: results of conditionals are buffers nothing else reuses, and
+    under reverse mode a ``cond``'s residuals (the union of both
+    branches') are copied through every conditional between its forward
+    and its way back; with the compact path under the conditional each
+    further layer's kept arrays cost a step twice their bytes (PERF.md
+    section 6, PR 44). The fallback keeps nothing and names nothing: only
+    a step that overflows pays its forward again, whatever policy a block
+    around this is rematerialised under. The compact path keeps its four
+    arrays beside its inputs and makes the elementwise steps between them
+    again; they carry names, and a block's rematerialisation that keeps
+    those names (``expert_residuals``) finds them where the first pass
+    left them: its re-run makes neither the gather nor a grouped product
+    again, and no conditional. A layer that holds all its experts has no
+    smaller buffer than the worst case and no ``cond``: its one buffer
+    carries the names.
     """
     tokens, k = chosen.shape
     count = w1.shape[0]
@@ -290,13 +414,7 @@ def held_expert_outputs(x, chosen, weights, w1, w3, w2, first: int,
     if cap == tokens * k:
         return worst_case_outputs(*operands), sizes, jnp.ones((), jnp.int32)
     fits = jnp.sum(sizes) <= cap
-    compact = jax.checkpoint(
-        functools.partial(compact_outputs, cap=cap),
-        policy=jax.checkpoint_policies.save_only_these_names(
-            *COMPACT_RESIDUALS))
-    out = lax.cond(fits, compact, jax.checkpoint(worst_case_outputs),
-                   *operands)
-    return out, sizes, fits.astype(jnp.int32)
+    return _held_outputs(cap, *operands), sizes, fits.astype(jnp.int32)
 
 
 class SparseExperts(nn.Module):
@@ -327,6 +445,7 @@ class SparseExperts(nn.Module):
                     (r.experts,), jnp.float32).value
             chosen, weights = route(scores, bias, r.top_k, r.norm_topk,
                                     r.scaling, eps=r.norm_eps)
+            chosen = checkpoint_name(chosen, ROUTING_RESIDUAL)
         with jax.named_scope("experts"):
             w1, w3, w2 = (
                 jnp.stack(ws).astype(self.dtype) for ws in zip(*(
@@ -375,6 +494,28 @@ def keep_within(classes, budget: int) -> Kept:
             names.extend(class_names)
             total += size
     return Kept(tuple(kept), tuple(names), total)
+
+
+def expert_residuals(routing: Routing, tokens: int, hidden: int, layers: int,
+                     dtype):
+    """``keep_within``'s class for ``layers`` expert layers of a step on
+    ``tokens`` tokens: what an expert layer's buffer makes that its way
+    back reads (``COMPACT_RESIDUALS``: the gathered rows and the third
+    product at ``hidden``, the first two at the expert's width), at the
+    buffer's own rows (``held_row_cap``: the compact buffer's where there
+    is one, every slot where the layer holds all its experts), and the
+    experts each token chose (``ROUTING_RESIDUAL``, int32): the sorted
+    order the kept rows stand in is made from it. Kept through a block's
+    rematerialisation, the block's re-run makes neither the gather nor a
+    grouped product again; the fallback beside a compact buffer names
+    nothing and keeps nothing (``held_expert_outputs``)."""
+    _, count = routing.held
+    cap = held_row_cap(tokens, routing.top_k, count, routing.experts)
+    return ("expert rows and products",
+            (*COMPACT_RESIDUALS, ROUTING_RESIDUAL),
+            layers * (cap * 2 * (hidden + routing.width)
+                      * jnp.dtype(dtype).itemsize
+                      + tokens * routing.top_k * 4))
 
 
 class TokenModel(nn.Module):

@@ -209,12 +209,13 @@ class TrinityConfig:
 
 # What a step takes on the device beside the train state and the kept
 # residuals: the temporaries of this model's fully rematerialised step at
-# one row of 8,192 tokens and the cell's share of five layers
-# (``memory_analysis`` of the step compiled for a v5e: PERF.md section 4
-# has the bytes) and 15% of a 16.9 GB chip left to the allocator. Fixed:
-# kept residuals are bounded by the budget, so a longer row or a larger
-# share keeps less and the step fits where it fitted without them.
-STEP_HEADROOM_BYTES = 5_900_000_000
+# one row of 8,192 tokens and the cell's share of five layers (3.57 GB:
+# ``memory_analysis`` of the cell's step compiled for a described v5e at
+# budget 0, PERF.md section 6, PR 44) and 15% of a 16.9 GB chip left to
+# the allocator. Fixed: kept residuals are bounded by the budget, so a
+# longer row or a larger share keeps less and the step fits where it
+# fitted without them.
+STEP_HEADROOM_BYTES = 6_100_000_000
 
 
 def residual_classes(config: TrinityConfig, shape, dtype):
@@ -222,8 +223,11 @@ def residual_classes(config: TrinityConfig, shape, dtype):
     (``token_model.keep_within``'s), over the layers held. The order is
     JoyAI's, whose block this one resembles most (the attention's output
     and log-sum-exp, which only the forward kernel can make again, then
-    the products a matrix makes again): it is not measured for this
-    model, whose cell leaves a budget for the first class alone."""
+    the products a matrix makes again, then the expert layers' rows and
+    products): of this model's own only the expert layers' class is
+    measured (8-10 ms a GB: the layer under a block's rematerialisation
+    at this cell's shape, ``scripts/bench_experts.py``, PERF.md section
+    6, PR 44); its cell's budget holds all four classes, 1,516 MB."""
     rows, length = shape
     tokens, item = rows * length, jnp.dtype(dtype).itemsize
     layers = len(config.numbers_here)
@@ -237,6 +241,9 @@ def residual_classes(config: TrinityConfig, shape, dtype):
          dense * 2 * tokens * config.intermediate_size * item),
         ("attention output projections", ("attention_out_proj",),
          layers * tokens * config.hidden_size * item),
+        token_model.expert_residuals(
+            config.routing, tokens, config.hidden_size, layers - dense,
+            dtype),
     )
 
 

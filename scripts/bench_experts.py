@@ -1,14 +1,22 @@
 """Forward and backward of ``token_model.held_expert_outputs`` alone at the
-two expert cells' shapes on the chip, under seeded uniform routing: the
-function as the step gets it (the compact buffer under its ``cond``), the
-compact rows without the ``cond``, the worst-case path as the parent ran it,
+three expert cells' shapes on the chip, under seeded uniform routing: the
+function as the step gets it (the compact buffer as straight-line code, the
+fallback's part added under a ``cond``), the compact rows with no ``cond``
+anywhere, both paths under one ``cond`` (PR 42's form, and two more ways to
+rematerialise its compact branch), the worst-case path as PR 41 ran it,
 and the worst case as an overflowing step pays for it (every slot on a held
 expert: the rematerialised fallback). Beside them what chose the way back
 and the constant: the compact rows with other ways back (``WAYS_BACK``) and
-at other multiples of the uniform share. Milliseconds (median of fenced
-calls of the gradient for ``x``, the three matrices and the weights) and the
-rows each form moves. Refuses to run off the chip.
-``python scripts/bench_experts.py [lfm2|joyai] ...``
+at other multiples of the uniform share. Then the layer as a block runs it:
+under a rematerialisation (``jax.checkpoint``, as ``token_model.rematerialised``
+wraps a block) that keeps nothing, the expert layer's class
+(``token_model.expert_residuals``) and parts of it, with the milliseconds
+each saves for a GB it holds: what places the class among a model's
+``residual_classes``. Milliseconds (median of fenced calls of the gradient
+for ``x``, the three matrices and the weights) and the rows each form moves.
+Refuses to run off the chip.
+``python scripts/bench_experts.py [lfm2|joyai|trinity] ... [block]``
+(``block``: the rematerialised rows alone)
 """
 
 import functools
@@ -29,7 +37,16 @@ from dptpu.models import token_model
 SHAPES = {  # tokens, experts a token, experts, held, hidden, expert width
     "lfm2": (16384, 4, 32, 8, 2048, 1792),
     "joyai": (8192, 8, 256, 8, 2048, 768),
+    "trinity": (8192, 8, 128, 16, 2048, 1024),
 }
+# what a block's rematerialisation may keep of the layer: nothing, the
+# first two products, those and the third, those and the gathered rows,
+# the class as ``expert_residuals`` names it (all four)
+KEPT = {"nothing": (), "gate_up": ("expert_gate", "expert_up"),
+        "gate_up_out": ("expert_gate", "expert_up", "expert_out"),
+        "rows_gate_up": ("expert_rows", "expert_gate", "expert_up"),
+        "the_class": token_model.COMPACT_RESIDUALS}
+WIDE = {"expert_rows", "expert_out"}  # at the hidden size; the others at the expert's
 MULTIPLES = (1.0, 1.25, 1.5, 2.0, 3.0)
 
 
@@ -67,7 +84,8 @@ def compact_with(way_back, cap, x, weights, w1, w3, w2, order, sizes):
     slot = order[:cap]
     token = slot // k
     in_a_run = jnp.arange(cap)[:, None] < jnp.sum(sizes)
-    out = token_model._grouped_swiglu(x[token], in_a_run, w1, w3, w2, sizes)
+    out, _ = token_model._grouped_swiglu(x[token], in_a_run, w1, w3, w2,
+                                         sizes)
     weight = weights.reshape(-1)[slot].astype(out.dtype)
     scaled = out.astype(jnp.float32) * weight.astype(jnp.float32)[:, None]
     return way_back(scaled, token, tokens).astype(x.dtype)
@@ -82,6 +100,41 @@ def grad_of(form):
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
 
 
+def as_a_block_runs_it(form, names):
+    """``form`` behind an elementwise step (a block's norm stands before
+    the layer: something for the re-run to make again), rematerialised on
+    the way back but for ``names``."""
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *names) if names else None
+
+    def block(x, *rest):
+        return form(jnp.tanh(x), *rest)
+    return jax.checkpoint(block, policy=policy)
+
+
+def block_rows(whole, worst_case, args, crowded, cap, hidden, width):
+    """The layer under a block's rematerialisation for each of ``KEPT``:
+    ms, the bytes kept, ms saved a GB kept against keeping nothing; and
+    the class kept on an overflowing step (the fallback keeps nothing)."""
+    rows = {}
+    want = grad_of(as_a_block_runs_it(worst_case, ()))(*args)
+    for label, names in KEPT.items():
+        fn = grad_of(as_a_block_runs_it(whole, names))
+        kept = 2 * cap * sum(hidden if n in WIDE else width for n in names)
+        rows[label] = {"ms": timed(fn, *args), "kept_bytes": kept,
+                       "gap_to_worst_case": gap(fn(*args), want)}
+        if names:
+            rows[label]["ms_saved_a_gb"] = (
+                rows["nothing"]["ms"] - rows[label]["ms"]) / (kept / 1e9)
+        print("block", label, rows[label], flush=True)
+    for label in ("nothing", "the_class"):
+        rows[label]["ms_overflowing"] = timed(
+            grad_of(as_a_block_runs_it(whole, KEPT[label])), *crowded)
+        print("block", label, "overflowing", rows[label]["ms_overflowing"],
+              flush=True)
+    return rows
+
+
 def gap(got, want):
     return max(float(jnp.max(jnp.abs(a.astype(jnp.float32)
                                      - b.astype(jnp.float32)))
@@ -90,6 +143,8 @@ def gap(got, want):
 
 
 def main(names):
+    only_block = "block" in names
+    names = [n for n in names if n != "block"] or list(SHAPES)
     device = jax.devices()[0]
     if device.platform != "tpu":
         raise SystemExit(f"needs the chip, found {device.platform}")
@@ -130,6 +185,11 @@ def main(names):
         compact = functools.partial(token_model.compact_outputs, cap=cap)
         forms = {
             "held_expert_outputs": whole,
+            "cond_round_both_paths": functools.partial(
+                under_cond, jax.checkpoint(
+                    compact,
+                    policy=jax.checkpoint_policies.save_only_these_names(
+                        *token_model.COMPACT_RESIDUALS))),
             "cond_compact_kept_whole": functools.partial(under_cond, compact),
             "cond_compact_rematerialised_whole": functools.partial(
                 under_cond, jax.checkpoint(compact)),
@@ -138,13 +198,19 @@ def main(names):
                 direct, token_model.worst_case_outputs),
         }
         want = grad_of(forms["worst_case_as_the_parent"])(*args)
+        # an overflowing step: every slot on a held expert
+        crowded = (x, weights, w1, w3, w2, chosen % count)
+        row["under_a_blocks_rematerialisation"] = block_rows(
+            whole, forms["worst_case_as_the_parent"], args, crowded, cap,
+            hidden, width)
+        if only_block:
+            results[name] = row
+            continue
         for label, form in forms.items():
             fn = grad_of(form)
             row[label] = {"ms": timed(fn, *args),
                           "gap_to_worst_case": gap(fn(*args), want)}
             print(name, label, row[label], flush=True)
-        # an overflowing step: every slot on a held expert
-        crowded = (x, weights, w1, w3, w2, chosen % count)
         row["held_expert_outputs_overflowing"] = {
             "ms": timed(grad_of(whole), *crowded), "rows_held": tokens * k}
         print(name, "overflowing", row["held_expert_outputs_overflowing"],
@@ -176,4 +242,4 @@ def main(names):
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:] or list(SHAPES))
+    main(sys.argv[1:])

@@ -15,6 +15,8 @@ holds the layer to its reference. Where the fallback ran the two are the
 same program on the same numbers: exactly equal.
 """
 
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -172,14 +174,24 @@ def _classed_input(total: int, seed=4):
     return jnp.asarray(x.reshape(2, TOKENS // 2, HIDDEN)), jnp.asarray(gate)
 
 
+@pytest.mark.parametrize("experts_class", [True, False],
+                         ids=["experts-kept", "experts-made-again"])
 @pytest.mark.parametrize("case", TOTALS)
 def test_a_rematerialised_expert_layer_under_the_cells_kept_names(
-        case, monkeypatch):
+        case, experts_class, monkeypatch):
     total, fits = TOTALS[case]
     x, gate = _classed_input(total)
-    # every class the cell's blocks keep, as the step names them
+    # every class the cell's blocks keep, as the step names them, with
+    # the expert layer's own and (a budget one byte short of it) without
+    classes = lfm2.residual_classes(CONFIG, (2, TOKENS // 2), jnp.float32)
+    assert classes[3][1][:4] == token_model.COMPACT_RESIDUALS
     kept = lfm2.Lfm2(CONFIG, residual_budget=10 ** 12).kept(2)
-    assert "ffn_gate" in kept.names and len(kept.classes) == 4
+    assert "ffn_gate" in kept.names and len(kept.classes) == 5
+    if not experts_class:
+        kept = lfm2.Lfm2(CONFIG, residual_budget=sum(
+            size for _, _, size in classes[:4]) - 1).kept(2)
+        assert len(kept.classes) == 3 and "conv_in_proj" in kept.names
+    assert ("expert_gate" in kept.names) is experts_class
     w1, w3, w2 = _weights(seed=5)
     params = {"gate": gate,
               **{f"experts_{e}": {"w1": w1[e], "w3": w3[e], "w2": w2[e]}
@@ -208,3 +220,215 @@ def test_a_rematerialised_expert_layer_under_the_cells_kept_names(
     for g, w in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         _close(g, w, exact=False)
+
+
+# ------------------- the expert layer's class, kept through a block's re-run
+
+
+def _equations(jaxpr, branches=True):
+    """``jaxpr``'s equations and those of every jaxpr under it (a
+    rematerialised function's body, a ``custom_vjp``'s rules and, with
+    ``branches``, a ``cond``'s)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "cond" and not branches:
+            continue
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub, branches)
+
+
+def _primitives(jaxpr) -> collections.Counter:
+    """How often each primitive stands in ``jaxpr`` and under it."""
+    return collections.Counter(
+        eqn.primitive.name for eqn in _equations(jaxpr))
+
+
+def test_the_class_reckons_the_buffers_own_rows():
+    """``expert_residuals``: rows and the third product at the hidden
+    size, the first two at the expert's width, at ``held_row_cap``'s rows
+    (the three cells', and every slot where a layer holds all its
+    experts), and each token's chosen experts, over the layers."""
+    def routing(experts, count, k, width):
+        return token_model.Routing(experts, (0, count), k, True, 1e-6, 1.0,
+                                   True, width)
+
+    def size(*args):
+        what, names, size = token_model.expert_residuals(*args)
+        assert what == "expert rows and products"
+        # the four arrays and the routing they were made under
+        assert names == (*token_model.COMPACT_RESIDUALS, "expert_chosen")
+        return size
+
+    assert size(routing(32, 8, 4, 1792), 16384, 2048, 4, jnp.bfloat16) \
+        == 4 * (32768 * 2 * (2048 + 1792) * 2 + 16384 * 4 * 4) \
+        == 2_014_314_496
+    assert size(routing(256, 8, 8, 768), 8192, 2048, 5, jnp.bfloat16) \
+        == 5 * (4096 * 2 * (2048 + 768) * 2 + 8192 * 8 * 4) == 231_997_440
+    assert size(routing(128, 16, 8, 1024), 8192, 2048, 4, jnp.bfloat16) \
+        == 4 * (16384 * 2 * (2048 + 1024) * 2 + 8192 * 8 * 4) \
+        == 806_354_944
+    # all experts held: no compact buffer, the names on the one there is
+    assert size(routing(8, 8, 2, 48), 128, 64, 3, jnp.float32) \
+        == 3 * (256 * 2 * (64 + 48) * 4 + 128 * 2 * 4)
+    assert size(routing(8, 2, 2, 8), 1024, 16, 0, jnp.float32) == 0
+
+
+@pytest.mark.parametrize("case", TOTALS)
+def test_a_block_that_keeps_the_class_makes_no_grouped_product_again(case):
+    """The layer under a block's rematerialisation with the class kept
+    and with nothing kept: the same output and gradients to the bit on
+    either side of the buffer, and the gradient's program holds the
+    compact path's gather and three forward products once, not twice.
+    The fallback names nothing: its products are made again at any
+    policy, and no conditional gives anything but the fallback's result
+    and the cotangents: no ``[tokens x k, *]`` array, no ``[cap, *]``
+    array."""
+    total, fits = TOTALS[case]
+    rng = np.random.RandomState(7)
+    x = jnp.asarray(rng.randn(TOKENS, HIDDEN), jnp.float32)
+    weights = jnp.asarray(rng.rand(TOKENS, K), jnp.float32)
+    chosen = _slots(total)
+    args = (x, *_weights(), weights)
+
+    def layer(x, w1, w3, w2, weights):
+        # the layer closes its block, behind something for the re-run to
+        # make again
+        out, _, compact = token_model.held_expert_outputs(
+            jnp.tanh(x), chosen, weights, w1, w3, w2, 0, EXPERTS)
+        return out, compact
+
+    def grad(names):
+        policy = jax.checkpoint_policies.save_only_these_names(*names) \
+            if names else None
+        block = jax.checkpoint(layer, policy=policy)
+
+        def loss(*args):
+            out, compact = block(*args)
+            return jnp.sum(out ** 2), compact
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
+                                  has_aux=True)
+
+    nothing, kept = grad(()), grad(token_model.COMPACT_RESIDUALS)
+    (want_loss, compact), want = jax.jit(nothing)(*args)
+    (got_loss, _), got = jax.jit(kept)(*args)
+    assert int(compact) == fits and float(got_loss) == float(want_loss)
+    for g, w in zip(got, want):
+        _close(g, w, exact=True)
+    # the compact path's products: 3 forward, 3 in the block's re-run, 6
+    # on the way back and 3 more that nothing reads (its way back takes
+    # ``jax.vjp`` of each of its steps, which traces the step's forward:
+    # the compiler drops them); the fallback's: 3 forward, 3 + 6 on its
+    # way back, which makes its forward again. Kept, the re-run's go
+    made_again = _primitives(jax.make_jaxpr(nothing)(*args).jaxpr)
+    held = _primitives(jax.make_jaxpr(kept)(*args).jaxpr)
+    assert made_again["ragged_dot_general"] == (3 + 3 + 6 + 3) + (3 + 9)
+    assert held["ragged_dot_general"] == (3 + 6 + 3) + (3 + 9)
+    assert held["gather"] == made_again["gather"] - 1  # the rows'
+    # two conditionals, forward and backward, and they hold the fallback
+    # alone: what the first hands out is its result, what the second
+    # gives are the five cotangents; no [cap, *] array is a result
+    for program in (nothing, kept):
+        conds = [eqn for eqn in _equations(
+            jax.make_jaxpr(program)(*args).jaxpr, branches=False)
+            if eqn.primitive.name == "cond"]
+        assert [[v.aval.shape for v in eqn.outvars] for eqn in conds] == [
+            [(TOKENS, HIDDEN)],
+            [(TOKENS, HIDDEN), (TOKENS, K), (HELD, HIDDEN, WIDTH),
+             (HELD, HIDDEN, WIDTH), (HELD, WIDTH, HIDDEN)]]
+
+
+def _both_branches(name):
+    """A one-expert-layer model of each family on 1,024 tokens (2 of 8
+    experts held: a buffer of 1,024 of the 2,048 slots)."""
+    from dptpu.models import joyai, trinity
+
+    if name == "lfm2":
+        return lfm2.Lfm2(lfm2.Lfm2Config(
+            vocab_size=64, hidden_size=HIDDEN, intermediate_size=24,
+            moe_intermediate_size=WIDTH, num_hidden_layers=1,
+            layer_types=("conv",), num_dense_layers=0,
+            num_attention_heads=2, num_key_value_heads=1,
+            num_experts=EXPERTS, num_experts_per_tok=K,
+            sequence_length=TOKENS // 2).held(experts=(0, HELD)))
+    if name == "joyai":
+        return joyai.Joyai(joyai.JoyaiConfig(
+            vocab_size=64, hidden_size=HIDDEN, intermediate_size=24,
+            moe_intermediate_size=WIDTH, num_hidden_layers=2,
+            num_attention_heads=2, num_key_value_heads=2, q_lora_rank=8,
+            kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4,
+            v_head_dim=8, n_routed_experts=EXPERTS, num_experts_per_tok=K,
+            num_nextn_predict_layers=0, sequence_length=TOKENS // 2).held(
+                layers=(1, 1), experts=(0, HELD)))
+    return trinity.Trinity(trinity.TrinityConfig(
+        vocab_size=64, hidden_size=HIDDEN, intermediate_size=24,
+        moe_intermediate_size=WIDTH, num_hidden_layers=1,
+        layer_types=("sliding_attention",), sliding_window=64,
+        num_dense_layers=0, num_attention_heads=2, num_key_value_heads=1,
+        head_dim=8, num_experts=EXPERTS, num_experts_per_tok=K,
+        sequence_length=TOKENS // 2).held(experts=(0, HELD)))
+
+
+@pytest.mark.parametrize("overflows", [False, True],
+                         ids=["fits-the-buffer", "overflows-it"])
+@pytest.mark.parametrize("name", ["lfm2", "joyai", "trinity"])
+def test_each_models_step_is_the_same_with_the_class_kept(
+        name, overflows, capsys):
+    """Loss and every gradient of a model whose one expert layer has a
+    compact buffer, with every class kept and at budget 0, on a routing
+    that fits the buffer and on one that overflows it (the selection
+    bias puts every token on the two held experts)."""
+    net = _both_branches(name)
+    tokens = jnp.asarray(np.random.RandomState(8).randint(
+        0, 64, (2, TOKENS // 2)), jnp.int32)
+    variables = net.init(jax.random.PRNGKey(0), tokens)
+    bias = jnp.where(jnp.arange(EXPERTS) < HELD, 10.0 * overflows, 0.0)
+    buffers = jax.tree_util.tree_map(lambda leaf: bias,
+                                     variables["batch_stats"])
+
+    def objective(net):
+        def of(params):
+            sums = net.apply({"params": params, "batch_stats": buffers},
+                             tokens, labels=tokens,
+                             mask=jnp.ones(tokens.shape, jnp.float32))
+            return sums["loss_sum"] / TOKENS, sums
+        return of
+
+    def loss(net):
+        return jax.value_and_grad(objective(net), has_aux=True)
+
+    every_class = net.clone(residual_budget=2**62)
+    assert "expert rows and products" in every_class.kept(2).classes
+    (want_loss, sums), want = jax.jit(loss(net))(variables["params"])
+    (got_loss, _), got = jax.jit(loss(every_class))(variables["params"])
+    assert int(sums["moe_compact"]) == (not overflows)
+    if overflows:  # every slot of every token on a held expert
+        assert int(sums["moe_counts"].sum()) == TOKENS * K
+    assert float(got_loss) == float(want_loss)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want),
+                            jax.tree_util.tree_leaves(got)):
+        np.testing.assert_allclose(
+            np.asarray(b), np.asarray(a), rtol=0,
+            atol=2e-6 * float(np.abs(a).max()), err_msg=str(path))
+    held = _primitives(jax.make_jaxpr(loss(every_class))(
+        variables["params"]).jaxpr)
+    made_again = _primitives(jax.make_jaxpr(loss(net))(
+        variables["params"]).jaxpr)
+    # the compact path's products: 3 forward, 3 in the block's re-run, 6
+    # on the way back (and 3 that nothing reads); the fallback's: 3
+    # forward, 9 on its way back and, where the block needs the layer's
+    # output on its way back (Trinity's norm behind it), 3 in the re-run.
+    # Kept, the compact path's re-run has none
+    assert made_again["ragged_dot_general"] == 27 + 3 * (name == "trinity")
+    assert held["ragged_dot_general"] == made_again["ragged_dot_general"] - 3
+    # and the routing the kept rows were sorted under is kept with them:
+    # a token whose top k flipped between the first pass and the re-run
+    # would shift every kept row behind it
+    jax.ad_checkpoint.print_saved_residuals(
+        lambda params: objective(every_class)(params)[0],
+        variables["params"])
+    saved = capsys.readouterr().out
+    assert f"i32[{TOKENS},{K}] named 'expert_chosen'" in saved
+    assert f"f32[{CAP},{HIDDEN}] named 'expert_rows'" in saved

@@ -449,17 +449,23 @@ def test_keeping_residuals_changes_no_loss_and_no_gradient():
     classes = joyai.residual_classes(TINY, (2, 64), jnp.float32)
     assert [what for what, _, _ in classes] == [
         "attention out+lse", "dense feed-forward",
-        "attention output projections"]
+        "attention output projections", "expert rows and products"]
     # three main layers and the module: out [2, 2, 64, 12] and lse, float32
     assert classes[0][2] == 4 * 2 * 2 * 64 * (12 * 4 + 4)
     # gate and up [128, 96] of the one dense layer; o_proj's [128, 64] x 4
     assert classes[1][2] == 2 * 128 * 96 * 4
     assert classes[2][2] == 4 * 128 * 64 * 4
+    # two main layers' and the module's experts, all eight held: every
+    # one of the 256 slots' rows and third product [256, 64] and first
+    # two products [256, 32]
+    # and each token's two chosen experts [128, 2] int32
+    assert classes[3][2] == 3 * (256 * 2 * (64 + 32) * 4 + 128 * 2 * 4)
     net = joyai.Joyai(TINY, residual_budget=2**62)
     kept = net.kept(rows=2)
     assert kept.classes == tuple(what for what, _, _ in classes)
     assert kept.names == attention_op.RESIDUAL_NAMES + (
-        "ffn_gate", "ffn_up", "attention_out_proj")
+        "ffn_gate", "ffn_up", "attention_out_proj") \
+        + token_model.COMPACT_RESIDUALS + ("expert_chosen",)
     assert joyai.Joyai(TINY, residual_budget=classes[0][2] - 1).kept(2) \
         == token_model.Kept()
     want_loss, want, nothing_kept = _loss_and_grads(0)
@@ -491,27 +497,36 @@ def test_the_budget_is_the_models_own_headroom_and_lfm2s_does_not_move():
     fitted = net.fitted_to(16_909_336_064, state_bytes)
     assert fitted.residual_budget == 16_909_336_064 - state_bytes \
         - joyai.STEP_HEADROOM_BYTES
-    assert fitted.residual_budget == 5_108_982_528
+    assert fitted.residual_budget == 5_508_982_528
     kept = fitted.kept(rows=1)
     # six layers' out [1, 32, 8192, 128] bf16 and lse [1, 32, 8192] f32;
-    # one dense layer's gate and up [8192, 7168]; six o_proj [8192, 2048]
+    # one dense layer's gate and up [8192, 7168]; six o_proj
+    # [8192, 2048]; five expert layers' compact buffers of 4,096 rows:
+    # rows and third product [4096, 2048], gate and up [4096, 768], the
+    # chosen experts [8192, 8] int32
     sizes = [size for _, _, size in net.residual_classes((1, 8192))]
     assert sizes == [6 * 8192 * 32 * (128 * 2 + 4), 2 * 8192 * 7168 * 2,
-                     6 * 8192 * 2048 * 2] == [
-                         408_944_640, 234_881_024, 201_326_592]
-    assert kept.bytes == sum(sizes) == 845_152_256 and kept.megabytes == 845
-    assert kept.classes == ("attention out+lse", "dense feed-forward",
-                            "attention output projections")
+                     6 * 8192 * 2048 * 2,
+                     5 * (4096 * 2 * (2048 + 768) * 2 + 8192 * 8 * 4)] == [
+                         408_944_640, 234_881_024, 201_326_592, 231_997_440]
+    assert kept.bytes == sum(sizes) == 1_077_149_696
+    assert kept.megabytes == 1077
+    assert kept.classes == (
+        "attention out+lse", "dense feed-forward",
+        "attention output projections", "expert rows and products")
     # a share without the dense layer has nothing of its class to keep
     later = joyai.Joyai(share.held(layers=(1, 4)), dtype=jnp.bfloat16,
                         residual_budget=2**40).kept(rows=1)
     assert later.classes == ("attention out+lse",
-                             "attention output projections")
-    assert later.bytes == (408_944_640 + 201_326_592) * 5 // 6
+                             "attention output projections",
+                             "expert rows and products")
+    assert later.bytes == (408_944_640 + 201_326_592) * 5 // 6 \
+        + 231_997_440
     assert net.fitted_to(0, state_bytes).residual_budget == 0
     # the other token model's budget is its own constant, as it was
     assert lfm2.Lfm2.step_headroom_bytes == lfm2.STEP_HEADROOM_BYTES \
-        == 6_000_000_000
+        == 6_300_000_000
+    assert joyai.STEP_HEADROOM_BYTES == 5_500_000_000
     assert joyai.Joyai.step_headroom_bytes == joyai.STEP_HEADROOM_BYTES
     assert token_model.TokenModel.step_headroom_bytes == 0
 

@@ -435,14 +435,17 @@ def _loss_and_grads(dtype, budget):
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("upto", [0, 1, 3, 4],
-                         ids=["nothing", "attention", "all-but-one",
-                              "unbounded"])
+@pytest.mark.parametrize("upto", [0, 1, 3, 4, 5],
+                         ids=["nothing", "attention", "all-but-two",
+                              "experts", "unbounded"])
 def test_keeping_residuals_changes_no_loss_and_no_gradient(upto, dtype):
-    budget = 2**62 if upto == 4 else sum(
+    budget = 2**62 if upto == 5 else sum(
         _class_bytes(TWO, (2, 64), dtype)[:upto])
     kept = lfm2.kept_residuals(TWO, (2, 64), dtype, budget)
     assert len(kept.classes) == upto
+    # the expert layer's class is fourth: its one layer here holds all
+    # its experts, so the names sit on its one buffer of every slot
+    assert ("expert_gate" in kept.names) == (upto >= 4)
     want_loss, want, nothing_kept = _loss_and_grads(dtype, 0)
     got_loss, got, kept_mb = _loss_and_grads(dtype, budget)
     assert nothing_kept == 0 and kept_mb == kept.megabytes
@@ -539,7 +542,7 @@ def test_the_cells_step_keeps_the_frozen_classes_and_counts_their_bytes():
     classes = lfm2.residual_classes(share, (2, 8192), jnp.bfloat16)
     assert [what for what, _, _ in classes] == [
         "attention out+lse", "q/k/v projections", "mixer projections",
-        "dense feed-forward"]
+        "expert rows and products", "dense feed-forward"]
     sizes = [size for _, _, size in classes]
     # out [2, 8, 32768, 64] bf16 and lse [2, 8, 32768] f32, one layer
     assert sizes[0] == 2 * 8 * 32768 * (64 * 2 + 4) == 69_206_016
@@ -548,22 +551,33 @@ def test_the_cells_step_keeps_the_frozen_classes_and_counts_their_bytes():
     # in_proj [16384, 6144] + out_proj [16384, 2048] on four short
     # convolutions, out_proj on the attention
     assert sizes[2] == (4 * 4 + 1) * 16384 * 2048 * 2 == 1_140_850_688
+    # four expert layers' compact buffers of 32,768 rows: the gathered
+    # rows and the third product [32768, 2048], the gate and up products
+    # [32768, 1792]
+    # and each token's four chosen experts [16384, 4] int32
+    assert classes[3][1] == ("expert_rows", "expert_gate", "expert_up",
+                             "expert_out", "expert_chosen")
+    assert sizes[3] == 4 * (32768 * 2 * (2048 + 1792) * 2
+                            + 16384 * 4 * 4) == 2_014_314_496
     # w1 and w3 [16384, 7168], one dense layer
-    assert sizes[3] == 2 * 16384 * 7168 * 2 == 469_762_048
-    # the chip reports 16.9 GB; at 16 GB the same classes are kept
-    for device_bytes in (16 * 10**9, 16_909_336_064):
+    assert sizes[4] == 2 * 16384 * 7168 * 2 == 469_762_048
+    # the chip reports 16.9 GB: every class; a chip of 16 GB keeps all
+    # but the last
+    for device_bytes, upto in ((16 * 10**9, 4), (16_909_336_064, 5)):
         net = lfm2.Lfm2(share, dtype=jnp.bfloat16).fitted_to(
             device_bytes, CELL_STATE_BYTES)
         assert net.residual_budget == device_bytes - CELL_STATE_BYTES \
             - lfm2.STEP_HEADROOM_BYTES
         kept = net.kept(rows=2)
-        assert kept.classes == tuple(what for what, _, _ in classes)
-        assert kept.names == tuple(n for _, names, _ in classes
+        assert kept.classes == tuple(what for what, _, _ in classes[:upto])
+        assert kept.names == tuple(n for _, names, _ in classes[:upto]
                                    for n in names)
-        assert kept.bytes == sum(sizes) == 1_780_482_048
-        assert kept.megabytes == 1780 and kept.bytes <= net.residual_budget
+        assert kept.bytes == sum(sizes[:upto]) <= net.residual_budget
         assert all(c in kept.notice(net.residual_budget)
                    for c in kept.classes)
+    assert kept.bytes == sum(sizes) == 3_794_796_544
+    assert kept.megabytes == 3795
+    assert lfm2.STEP_HEADROOM_BYTES == 6_300_000_000
     # a device that reports no size, or one the state fills: nothing kept
     assert net.fitted_to(0, CELL_STATE_BYTES).residual_budget == 0
     assert net.fitted_to(8 * 10**9, CELL_STATE_BYTES).kept(2) == lfm2.Kept()
@@ -596,7 +610,7 @@ def test_the_choice_is_monotone_and_stays_inside_the_budget(shape, layers):
     at_cell = lfm2.kept_residuals(cell, (2, 8192), jnp.bfloat16, budget)
     here = lfm2.kept_residuals(share, shape, jnp.bfloat16, budget)
     if shape[0] * shape[1] > 16384 or share.num_hidden_layers > 5:
-        assert len(here.classes) < len(at_cell.classes) == 4
+        assert len(here.classes) < len(at_cell.classes) == 5
     if shape == (1, 50):
         # 50 tokens, not padded (one block of the row's own length)
         assert sizes[0] == 50 * 32 * (64 * 2 + 4)

@@ -120,9 +120,9 @@ def test_fit_trains_validates_and_resumes_a_token_model(
         straight["state"].params))
     assert device_bytes == 0 and 0 <= state_bytes - 12 * params < 4096
     assert ("=> residuals kept through the rematerialisation: attention "
-            "out+lse, q/k/v projections, mixer projections, dense "
-            "feed-forward (0 MB a step of a budget of 1,000 MB)"
-            ) in out  # 0.1 MB at this size
+            "out+lse, q/k/v projections, mixer projections, expert rows "
+            "and products, dense feed-forward (0 MB a step of a budget of "
+            "1,000 MB)") in out  # 0.2 MB at this size
     epoch = straight["history"][0]
     # uniform random ids: the loss cannot pass ln(128), and starts above
     assert np.log(128) - 0.05 < epoch["train_loss"] < 6.0

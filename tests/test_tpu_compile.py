@@ -1,7 +1,7 @@
 """The token step's two heaviest pieces compiled for the real chip at the
 cell's real widths, without a chip (the TPU's compiler is installed and
 compiles for a described v5e): the expert layer's grouped products over
-the compact buffer and, under the same conditional, over the worst-case
+the compact buffer and, under a conditional, over the worst-case
 one (``lax.ragged_dot`` lowers to the chip's own kernel there, not to
 the CPU's dense fallback), at both expert cells' shapes, and the
 blockwise attention, each forward and backward. Lowered for the chip
@@ -16,11 +16,13 @@ The topology is described inside a fixture, never at import: one process
 at a time may load the TPU's library, and a worker that cannot skips.
 """
 
+import dataclasses
 import re
 
 import jax
 import jax.numpy as jnp
 import pytest
+from flax import linen as nn
 from jax.sharding import SingleDeviceSharding
 
 from dptpu.models import lfm2, token_model
@@ -61,10 +63,11 @@ def _shape(shape, dtype, sharding):
 
 # tokens, experts a token, experts, held, expert width; the compact
 # buffer's rows; the bytes of temporaries the layer's forward and backward
-# take alone (the parent's worst-case program took 1.24 and 0.84 GB)
+# take alone
 EXPERT_CELLS = {
-    "lfm2moe-fit-8k-1chip": (TOKENS, TOP_K, 32, HELD, WIDTH, 32768, 2.58e9),
-    "joyai-fit-8k-1chip": (8192, 8, 256, HELD, 768, 4096, 1.28e9),
+    "lfm2moe-fit-8k-1chip": (TOKENS, TOP_K, 32, HELD, WIDTH, 32768, 1.94e9),
+    "joyai-fit-8k-1chip": (8192, 8, 256, HELD, 768, 4096, 1.26e9),
+    "trinity-mini-fit-8k-1chip": (8192, 8, 128, 16, 1024, 16384, 1.35e9),
 }
 
 
@@ -94,6 +97,28 @@ def _reached(computations: dict, root: str) -> list:
     return lines
 
 
+def _conditionals(computations: dict) -> list:
+    """A program's conditionals: ``(what it gives, the lines its false
+    branch reaches, those its true branch reaches)`` each (a cond on a
+    boolean: the false branch comes first)."""
+    found = []
+    for lines in computations.values():
+        for line in lines:
+            if " conditional(" in line:
+                false, true = re.search(
+                    r"branch_computations=\{%([\w.\-]+), %([\w.\-]+)\}",
+                    line).groups()
+                found.append((line.split(" conditional(")[0],
+                              _reached(computations, false),
+                              _reached(computations, true)))
+    return found
+
+
+def _grouped_products(lines) -> int:
+    """The chip's grouped-product kernel among ``lines``."""
+    return sum("ragged" in ln and " custom-call(" in ln for ln in lines)
+
+
 @pytest.mark.parametrize("cell", EXPERT_CELLS)
 def test_the_expert_layer_compiles_for_the_chip_at_the_cells_widths(
         one_chip, cell):
@@ -117,32 +142,132 @@ def test_the_expert_layer_compiles_for_the_chip_at_the_cells_widths(
     # the chip's grouped-product kernel, forward and both backward forms,
     # on the compact rows and on the fallback's
     assert text.count("ragged-dot") >= 18 and "tpu_custom_call" in text
-    # one conditional each way, and the branch a step takes while its held
-    # slots fit moves no array of all 65,536 slots wider than an int32
-    # column (the sort's order and what indexes it): every gather, mask
-    # and product is [cap, *]
+    # one conditional each way. Forward it holds the fallback alone (its
+    # other branch gives zeros); backward it takes what the compact path
+    # kept as operands and gives the cotangents. The branch a step takes
+    # while its held slots fit moves no array of all 65,536 slots a
+    # hundred wide, and no conditional GIVES a [cap, *] array
     computations = _computations(text)
-    conditionals = [line for lines in computations.values()
-                    for line in lines if " conditional(" in line]
+    conditionals = _conditionals(computations)
     assert len(conditionals) == 2
-    worst_rows = re.compile(rf"\w+\[{tokens * top_k},\d+")
-    for line in conditionals:
-        branches = re.search(
-            r"branch_computations=\{%([\w.\-]+), %([\w.\-]+)\}", line)
-        # a cond on a boolean: the false branch (the fallback) comes first
-        fallback, compact = (_reached(computations, name)
-                             for name in branches.groups())
+    worst_rows = re.compile(rf"\w+\[{tokens * top_k},\d{{3,}}")
+    inside = set()
+    for gives, fallback, fits in conditionals:
         assert any(worst_rows.search(ln) for ln in fallback)
-        wide = [ln for ln in compact if worst_rows.search(ln)]
-        assert not wide, wide[:3]
-        assert any(f"[{cap},{HIDDEN}]" in ln for ln in compact)
-    # the chosen form, both branches rematerialised, the compact one
-    # keeping its rows and grouped products: those beside the fallback's
-    # inputs. At a buffer of 1.5 shares it took 2.33 and 1.26 GB, 3.47 and
-    # 1.34 with the compact branch kept whole, and a plain cond's union of
-    # residuals 5.24 and 3.11 (PERF.md section 6, PR 42)
+        assert _grouped_products(fallback)
+        assert not [ln for ln in fits if worst_rows.search(ln)]
+        assert not re.search(rf"\[(?:{cap}|{tokens * top_k}),\d{{3,}}\]",
+                             gives)
+        inside.update(fallback, fits)
+    # the compact path's forward is straight-line code outside them:
+    # every gather, mask and product there is [cap, *]; at the worst
+    # case's rows there are the sort's int32 columns (the order, the keys
+    # against the held experts' numbers), nothing a hundred wide
+    outside = [ln for lines in computations.values() for ln in lines
+               if ln not in inside]
+    wide = [ln for ln in outside if worst_rows.search(ln)]
+    assert not wide, wide[:3]
+    assert any(f"[{cap},{HIDDEN}]" in ln for ln in outside)
+    assert _grouped_products(outside) >= 3
+    # what the compact path keeps for its way back (its rows and grouped
+    # products) beside the inputs. With the compact path under the
+    # conditional too (PR 42's form) the layer took 2.58, 1.28 and 1.62
+    # GB (PERF.md section 6, PR 44)
     assert 0.9 * temp < compiled.memory_analysis().temp_size_in_bytes \
         < 1.1 * temp
+
+
+@dataclasses.dataclass(frozen=True)
+class _Share:
+    """What ``SparseExperts`` reads of a configuration."""
+
+    routing: token_model.Routing
+
+
+class _ExpertBlock(nn.Module):
+    """An expert layer as a block wraps it: behind a norm, on the
+    residual path, the block's last step."""
+
+    share: _Share
+
+    @nn.compact
+    def __call__(self, x):
+        normed = token_model.RMSNorm(1e-5, jnp.bfloat16)(x)
+        out, sizes, compact = token_model.SparseExperts(
+            self.share, jnp.bfloat16)(normed)
+        return x + out, sizes, compact
+
+
+@pytest.mark.parametrize("cell", EXPERT_CELLS)
+def test_a_rematerialised_expert_block_keeps_its_class_on_the_chip(
+        one_chip, cell):
+    """The gradient of one rematerialised expert block at the cell's
+    widths with the expert layer's class kept and with nothing kept:
+    kept, the block's re-run makes neither the gather nor a grouped
+    product again, the step holds no more for it than
+    ``expert_residuals`` reckons, and no conditional gives anything but
+    the fallback's part of the result and the cotangents: nothing of
+    ``[tokens x k, *]`` and nothing of the compact buffer."""
+    tokens, top_k, experts, held, width, cap, _ = EXPERT_CELLS[cell]
+    share = _Share(token_model.Routing(
+        experts, (0, held), top_k, True, 1e-6, 1.0, True, width))
+    what, names, size = token_model.expert_residuals(
+        share.routing, tokens, HIDDEN, 1, jnp.bfloat16)
+    assert size == cap * 2 * (HIDDEN + width) * 2 + tokens * top_k * 4
+    x = _shape((1, tokens, HIDDEN), jnp.bfloat16, one_chip)
+
+    def compiled(kept):
+        block = token_model.rematerialised(_ExpertBlock, kept)(share)
+        variables = jax.tree_util.tree_map(
+            lambda leaf: _shape(leaf.shape, leaf.dtype, one_chip),
+            jax.eval_shape(block.init, jax.random.PRNGKey(0), x))
+        params = variables.pop("params")
+
+        def loss(params, buffers, x):
+            out, *load = block.apply({"params": params, **buffers}, x)
+            return jnp.sum(out.astype(jnp.float32)), load
+
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 2), has_aux=True)).lower(
+                params, variables, x).compile()
+
+    made_again = compiled(token_model.Kept())
+    held_back = compiled(token_model.Kept((what,), names, size))
+    rows = re.compile(rf"\w+\[(?:{tokens * top_k}|{cap}),(\d+)\]")
+    compact_path = {}
+    for program in (made_again, held_back):
+        computations = _computations(program.as_text())
+        conditionals = _conditionals(computations)
+        # the first pass's (the fallback's part of the result) and the
+        # way back's: the re-run, whose result this block does not read,
+        # has none
+        assert len(conditionals) == 2
+        inside = set()
+        for gives, fallback, fits in conditionals:
+            assert not [w for w in rows.findall(gives) if int(w) >= 100], \
+                gives
+            inside.update(fallback, fits)
+        outside = [ln for lines in computations.values() for ln in lines
+                   if ln not in inside]
+        compact_path[program] = (
+            _grouped_products(outside),
+            sum(" gather(" in ln and f"[{cap},{HIDDEN}]" in ln
+                for ln in outside))
+    # the compact path's forward products and its gather of the rows:
+    # made in the first pass and again in the re-run, or once
+    (products, gathers), (kept_products, kept_gathers) = (
+        compact_path[made_again], compact_path[held_back])
+    assert products == 2 * kept_products >= 6, (products, kept_products)
+    assert kept_gathers == gathers - 1 >= 1, (gathers, kept_gathers)
+    # what the step holds more for it: under the class's bytes at LFM2's
+    # shape (+335 MB for 503: part of what is kept the re-run held at the
+    # same point of the step). One block alone says little more: its
+    # peak moves with the schedule (-2 MB for 201 at Trinity's shape,
+    # +200 for 46 at JoyAI's); the whole steps, where a kept byte costs
+    # 0.75 bytes, are in PERF.md section 6, PR 44
+    grew = held_back.memory_analysis().temp_size_in_bytes \
+        - made_again.memory_analysis().temp_size_in_bytes
+    assert grew < 1.1 * size + 0.25e9, (grew, size)
 
 
 def _attention_calls(text: str):
@@ -245,8 +370,6 @@ def test_a_rematerialised_attention_block_keeps_out_and_lse_on_the_chip(
     dense feed-forward behind it: the experts have their own case
     above), ``out`` and ``lse`` kept through the rematerialisation: the
     forward kernel is in the program once, not twice."""
-    from flax import linen as nn
-
     share = lfm2.Lfm2Config().held(layers=(2, 1), sequence_length=8192)
     kept = lfm2.kept_residuals(share, (2, 8192), jnp.bfloat16, 70_000_000)
     assert kept.classes == ("attention out+lse",) and kept.bytes == 69_206_016

@@ -351,18 +351,24 @@ def test_keeping_residuals_changes_nothing_and_the_step_counts_two_kinds():
     classes = trinity.residual_classes(TINY, (2, 64), jnp.float32)
     assert [what for what, _, _ in classes] == [
         "attention out+lse", "dense feed-forward",
-        "attention output projections"]
+        "attention output projections", "expert rows and products"]
     # five layers: out [2, 4, 64, 8] and lse, float32; gate and up
-    # [128, 96] of the two dense layers; five o_proj [128, 64]
+    # [128, 96] of the two dense layers; five o_proj [128, 64]; three
+    # expert layers, all eight held: the 256 slots' rows and third
+    # product [256, 64], first two products [256, 32] and each token's
+    # two chosen experts [128, 2] int32
     assert [size for _, _, size in classes] == [
         5 * 2 * 4 * 64 * (8 * 4 + 4), 2 * 2 * 128 * 96 * 4,
-        5 * 128 * 64 * 4]
+        5 * 128 * 64 * 4, 3 * (256 * 2 * (64 + 32) * 4 + 128 * 2 * 4)]
     kept = trinity.Trinity(TINY, residual_budget=2**62).kept(rows=2)
     assert kept.names == attention_op.RESIDUAL_NAMES + (
-        "ffn_gate", "ffn_up", "attention_out_proj")
+        "ffn_gate", "ffn_up", "attention_out_proj") \
+        + token_model.COMPACT_RESIDUALS + ("expert_chosen",)
     want_loss, want, nothing = _loss_and_grads(0)
     got_loss, got, sums = _loss_and_grads(2**62)
-    assert int(nothing["kept_residual_mb"]) == 0 == kept.megabytes
+    # 947,200 bytes at toy widths: the step says whole megabytes
+    assert int(nothing["kept_residual_mb"]) == 0
+    assert int(sums["kept_residual_mb"]) == kept.megabytes == 1
     assert float(got_loss) == float(want_loss)
     for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want),
                             jax.tree_util.tree_leaves(got)):
@@ -380,6 +386,37 @@ def test_keeping_residuals_changes_nothing_and_the_step_counts_two_kinds():
         "attention_calls": 5, "attention_kernel_calls": 0,
         "attention_window_calls": 4, "attention_tiles": 5,
         "attention_tiles_causal": 5}
+
+
+def test_the_cells_budget_holds_all_four_classes():
+    """The cell's share on the chip's 16.9 GB: the budget this model's
+    own headroom leaves holds every class, the expert layers' among them
+    at the compact buffers' 16,384 rows."""
+    share = trinity.TrinityConfig().held(
+        layers=(1, 5), experts=(0, 16), vocab=(0, 25024),
+        sequence_length=8192)
+    net = trinity.Trinity(share, dtype=jnp.bfloat16)
+    state_bytes = 3 * 4 * 705_473_792
+    fitted = net.fitted_to(16_909_336_064, state_bytes)
+    assert trinity.STEP_HEADROOM_BYTES == 6_100_000_000
+    assert fitted.residual_budget == 16_909_336_064 - state_bytes \
+        - trinity.STEP_HEADROOM_BYTES == 2_343_650_560
+    sizes = [size for _, _, size in net.residual_classes((1, 8192))]
+    # five layers' out [1, 4, 65536, 128] bf16 and lse; one dense
+    # layer's gate and up [8192, 6144]; five o_proj [8192, 2048]; four
+    # expert layers' rows and third product [16384, 2048], gate and up
+    # [16384, 1024], the chosen experts [8192, 8] int32
+    assert sizes == [5 * 8192 * 32 * (128 * 2 + 4), 2 * 8192 * 6144 * 2,
+                     5 * 8192 * 2048 * 2,
+                     4 * (16384 * 2 * (2048 + 1024) * 2 + 8192 * 8 * 4)] == [
+                         340_787_200, 201_326_592, 167_772_160, 806_354_944]
+    kept = fitted.kept(rows=1)
+    assert kept.bytes == sum(sizes) == 1_516_240_896
+    assert kept.megabytes == 1516 and len(kept.classes) == 4
+    # a chip the state fills keeps nothing, one that reports nothing too
+    assert net.fitted_to(14 * 10**9, state_bytes).kept(1) \
+        == token_model.Kept()
+    assert net.fitted_to(0, state_bytes).residual_budget == 0
 
 
 def test_the_cells_step_counts_a_band_of_the_causal_tiles():
